@@ -20,8 +20,4 @@ Layer map (TPU-native analogue of reference SURVEY.md §1):
 
 __version__ = "0.1.0"
 
-from atomo_tpu import compat as _compat
-
-_compat.install()  # jax API drift (shard_map location/kwargs) — see compat.py
-
-from atomo_tpu.codecs import get_codec  # noqa: E402,F401
+from atomo_tpu.codecs import get_codec  # noqa: F401

@@ -131,8 +131,9 @@ class QsgdCodec:
     bucket_size: values per scale (reference --bucket-size, default 512).
     scheme: "qsgd" (L2-norm scale) or "terngrad" (max-norm scale + 2.5-sigma
         clip, qsgd.py:212-216; terngrad implies bits=1 in the reference).
-    use_pallas: None = auto (fused kernels on TPU, jnp elsewhere);
-        True forces the kernels (interpreted off-TPU — slow, tests only);
+    use_pallas: None = the jnp path everywhere (see ``_pallas``);
+        True forces the kernels, compiled for the device they are on
+        (interpreted only under ATOMO_PALLAS_INTERPRET=1 — tests);
         False forces the jnp path. Both paths share one wire format.
     pack_kernel: the PACK/UNPACK stage alone as a fused Pallas kernel
         inside the otherwise-jnp path (ops.qsgd_kernels.pallas_pack_bucketed
@@ -146,9 +147,9 @@ class QsgdCodec:
         each round and the first win graduates it by adding one evidence
         entry), and the jnp oracle everywhere else, with every off-TPU
         backend falling back automatically by construction.
-        True opts in unconditionally: compiled on real TPU, interpreted
-        off-TPU (tests drive it there against the jnp oracle); False
-        forces jnp. Bit-identical wire every way. Moot when the full
+        True opts in unconditionally: compiled for the device it is on
+        (tests drive it in the interpreter, on request, against the jnp
+        oracle); False forces jnp. Bit-identical wire every way. Moot when the full
         ``use_pallas`` kernel runs (that path packs inside its own
         kernel already).
     """
@@ -200,9 +201,9 @@ class QsgdCodec:
         return bool(self.use_pallas)
 
     def _interpret(self) -> bool:
-        from atomo_tpu.ops.qsgd_kernels import is_tpu
+        from atomo_tpu.ops.qsgd_kernels import interpret_requested
 
-        return not is_tpu()
+        return interpret_requested()
 
     def _pack_kernel(self) -> bool:
         """Resolve ``pack_kernel``: None consults the measured-win
@@ -210,7 +211,7 @@ class QsgdCodec:
         use_pallas precedent as a MECHANISM: default-on exactly on TPU
         device kinds with a recorded measured win, the jnp oracle
         everywhere else including every off-TPU backend); True forces
-        the kernel (interpreted off-TPU); False forces jnp."""
+        the kernel; False forces jnp."""
         if self.pack_kernel is None:
             from atomo_tpu.ops.qsgd_kernels import pack_kernel_default
 

@@ -65,10 +65,7 @@ def pack_kernel_record(codec) -> dict:
     )
 
     has_knob = hasattr(codec, "pack_kernel")
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = None
+    kind = jax.devices()[0].device_kind
     rec = {
         "codec_has_knob": bool(has_knob),
         "device_kind": kind,

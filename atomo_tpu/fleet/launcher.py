@@ -29,6 +29,10 @@ Two layers, deliberately separable:
 
 ``python -m atomo_tpu.fleet.launcher`` runs one member and prints one
 ``RESULT {json}`` line (the tests/_mp_worker.py convention).
+
+A CPU drill: several members on one host are several JAX processes, a chip
+belongs to one process at a time, and nothing here assigns chips between
+them. It has only ever run on the CPU backend and stays off chip_smoke.py.
 """
 
 from __future__ import annotations

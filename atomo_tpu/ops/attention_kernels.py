@@ -16,7 +16,8 @@ prefetches — those blocks, so causal saves compute but not bandwidth).
 
 Scope discipline (round-2 lesson: TPU-only code paths must stay testable):
   * forward = Pallas kernel, bit-compared against full_attention in the
-    TPU-semantics interpreter on CPU and on the real chip (tests_tpu);
+    TPU-semantics interpreter on CPU (tests/) and compiled on the chip
+    (tests_tpu/);
   * backward = jax.vjp of the jnp blockwise oracle (identical math), so
     training through ``flash_attention`` is exact and needs no hand-written
     transpose kernel; the fused win applies to the forward pass.
@@ -37,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from atomo_tpu.ops.qsgd_kernels import _interpret_mode, is_tpu
+from atomo_tpu.ops.qsgd_kernels import _interpret_mode, interpret_requested
 
 NEG_INF = float("-inf")
 
@@ -174,8 +175,10 @@ def flash_attention(
 ) -> jax.Array:
     """Fused exact attention (B, H, S, D) -> (B, H, S, D).
 
-    Forward runs the Pallas flash kernel (interpreter on CPU, Mosaic on
-    TPU); backward is the jnp blockwise oracle's VJP. Falls back to
+    Forward runs the Pallas flash kernel, compiled by Mosaic for the
+    device it is on (``interpret=None`` interprets only when
+    ops.qsgd_kernels.interpret_requested says so — tests and CPU dry
+    runs); backward is the jnp blockwise oracle's VJP. Falls back to
     blockwise_attention when S doesn't tile by the blocks — identical
     results either way (tested)."""
     from atomo_tpu.parallel.ring import blockwise_attention
@@ -190,5 +193,5 @@ def flash_attention(
             q, k, v, causal=causal, scale=scale, block_size=block_k
         )
     if interpret is None:
-        interpret = not is_tpu()
+        interpret = interpret_requested()
     return _flash(q, k, v, causal, scale, block_q, block_k, interpret)

@@ -18,9 +18,9 @@ what real-TPU Mosaic can express: packing is a Python loop of middle-axis
 slices over a (block, vpw, n_words) tile — the interleaved layout needed a
 lane-dim-splitting reshape, which Mosaic rejects ("infer-vector-layout:
 unsupported shape cast", hardware-verified this round). ``QsgdCodec`` emits
-and accepts this exact layout from both its jnp path and these kernels, so
-the fused kernels ARE the production encode on TPU; the jnp path is the
-test oracle.
+and accepts this exact layout from both its jnp path and these kernels.
+The jnp path is the default everywhere and the bit-parity oracle; the
+kernels are opt-in (``use_pallas=True`` / ``pack_kernel=True``).
 
 Mosaic dtype discipline (all hardware-verified failures): no uint32
 reductions, no u32<->f32 or bool->u32 casts — the kernels therefore compute
@@ -31,9 +31,10 @@ RNG: passing ``u`` (external jax.random uniforms) makes the kernel
 bit-identical to the jnp oracle; ``u=None`` draws from the on-core PRNG —
 the zero-extra-bandwidth TPU hot path (per-block seeds: the block index is
 folded into the seed so stochastic-rounding noise is independent across
-blocks — round-1 ADVICE finding). Kernels run under the TPU-semantics
-interpreter on CPU for tests (whose prng_random_bits is a zero stub, so
-interpreter tests must pass explicit ``u``).
+blocks — round-1 ADVICE finding). A kernel compiles for the device it is
+on, or raises. The TPU-semantics interpreter runs only on request
+(:func:`interpret_requested`; its prng_random_bits is a zero stub, so
+interpreter runs must pass explicit ``u``).
 
 The grid tiles buckets; bucket_size is padded to the word boundary, so any
 bucket_size works (the default 512 = reference --bucket-size).
@@ -41,6 +42,7 @@ bucket_size works (the default 512 = reference --bucket-size).
 
 from __future__ import annotations
 
+import os
 from functools import partial
 from typing import Optional
 
@@ -49,12 +51,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+INTERPRET_ENV = "ATOMO_PALLAS_INTERPRET"
+
 
 def is_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """A backend that fails to come up raises here; it is never read as
+    "not a TPU"."""
+    return jax.devices()[0].platform == "tpu"
+
+
+def interpret_requested() -> bool:
+    """Interpret mode is for tests and CPU dry runs, and only when asked
+    for: ``ATOMO_PALLAS_INTERPRET=1`` (tests/conftest.py sets it; child
+    processes inherit it). Nothing infers it from the backend — a kernel
+    asked for on a machine whose accelerator did not come up must fail to
+    compile, not run in the interpreter without a word."""
+    return os.environ.get(INTERPRET_ENV) == "1"
 
 
 # ---------------------------------------------------------------------------
@@ -101,10 +113,7 @@ def pack_kernel_default(
     if not on_tpu:
         return False
     if device_kind is None:
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return False
+        device_kind = jax.devices()[0].device_kind
     kind = str(device_kind).lower()
     for tag, rec in PACK_KERNEL_MEASURED_WINS.items():
         if tag in kind and rec.get("win"):
@@ -113,14 +122,10 @@ def pack_kernel_default(
 
 
 def _interpret_mode(interpret: bool):
-    """True → the TPU-semantics interpreter (generic interpret mode has no
-    CPU lowering for pltpu.prng_* primitives). On jax versions without
-    ``pltpu.InterpretParams`` this falls back to plain ``interpret=True`` —
-    fine for the external-uniform kernels the tests use; the on-core-PRNG
-    path needs real hardware there."""
-    from atomo_tpu.compat import pallas_tpu_interpret_mode
-
-    return pallas_tpu_interpret_mode(interpret)
+    """Value for ``pl.pallas_call(interpret=...)``: the TPU-semantics
+    interpreter (generic interpret mode has no CPU lowering for the
+    pltpu.prng_* primitives), or False to compile."""
+    return pltpu.InterpretParams() if interpret else False
 
 
 def _finish_quantize(x, u, words_ref, scales_ref, *, bits, levels, vpw, scheme):

@@ -48,8 +48,8 @@ def initialize(
     seconds) on connection-flavored failures instead of dying into the
     scheduler's next restart round.
 
-    ``init_timeout`` bounds each handshake attempt (seconds) where the
-    jax version supports ``initialization_timeout``. The fleet re-form
+    ``init_timeout`` bounds each handshake attempt (seconds;
+    ``initialization_timeout``). The fleet re-form
     path needs this: a member waiting at the rendezvous for a peer that
     will never arrive must fail into a recorded incident, not sit in the
     default 300 s barrier.
@@ -63,36 +63,30 @@ def initialize(
         process_id = int(env) if env else None
     if coordinator_address is None and num_processes in (None, 1):
         return  # single host
-    try:
-        from jax._src.distributed import global_state as _gs
-
-        if getattr(_gs, "client", None) is not None:
-            return  # already initialized: idempotent no-op — the retry
-            # below must never shut down a HEALTHY coordinator connection
-    except ImportError:
-        pass  # private path moved: jax's own "called once" guard applies
+    if jax.distributed.is_initialized():
+        return  # idempotent no-op — the retry below must never shut down
+        # a HEALTHY coordinator connection
     from atomo_tpu.training.resilience import with_retries
 
     def _attempt(**kw):
         try:
             jax.distributed.initialize(**kw)
         except (RuntimeError, ConnectionError, OSError):
-            # jax sets global_state.client BEFORE client.connect(), so a
-            # failed connect leaves half-initialized state and every
-            # further initialize() dies on the "should only be called
-            # once" guard. Reset it so the retry can actually connect.
-            try:
-                jax.distributed.shutdown()
-            except Exception:
-                pass
-            try:
-                from jax._src.distributed import global_state as _gs
+            # jax 0.9.0 sets global_state.service and .client BEFORE
+            # client.connect(), so a connect that raises leaves
+            # half-initialized state and every further initialize() dies
+            # on the "should only be called once" guard. Reset it so the
+            # retry can actually connect. (A connect that runs into its
+            # DEADLINE does not raise under 0.9.0 — the runtime client
+            # terminates the process, and the scheduler's restart is the
+            # retry.)
+            from jax._src.distributed import global_state
 
-                _gs.client = None
-                _gs.service = None
-                _gs.preemption_sync_manager = None
-            except Exception:
-                pass  # private path moved: shutdown() above is the fallback
+            if global_state.service is not None:
+                global_state.service.shutdown()
+            global_state.client = None
+            global_state.service = None
+            global_state.preemption_sync_manager = None
             raise
 
     kw = dict(
@@ -101,12 +95,7 @@ def initialize(
         process_id=process_id,
     )
     if init_timeout is not None:
-        import inspect
-
-        if "initialization_timeout" in inspect.signature(
-            jax.distributed.initialize
-        ).parameters:
-            kw["initialization_timeout"] = max(1, int(init_timeout))
+        kw["initialization_timeout"] = max(1, int(init_timeout))
     with_retries(
         _attempt,
         attempts=max(attempts, 1),

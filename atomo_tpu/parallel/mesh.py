@@ -15,6 +15,7 @@ Axis taxonomy (forward-looking — the reference is DP-only, SURVEY.md §2.1):
 
 from __future__ import annotations
 
+import json
 from typing import Optional, Sequence
 
 import jax
@@ -52,3 +53,45 @@ def replicated(mesh: Mesh) -> NamedSharding:
 
 def batch_sharded(mesh: Mesh, axis: str = "dp") -> NamedSharding:
     return NamedSharding(mesh, P(axis))
+
+
+def device_line(mesh: Optional[Mesh] = None) -> str:
+    """The start-up line ``train`` and ``lm`` print: the device as JAX
+    reports it and the mesh the run lays over it (``{}`` for the
+    single-device loop). One JSON object after the prefix — chip_smoke.py
+    reads it, and fails unless the platform is ``tpu``."""
+    devs = jax.devices()
+    return "Device: " + json.dumps({
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+        "mesh": {} if mesh is None else {k: int(v) for k, v in mesh.shape.items()},
+    })
+
+
+def shard_devices(tree) -> dict:
+    """id -> device, for every device holding an addressable shard of a
+    ``jax.Array`` leaf of ``tree``."""
+    return {
+        s.device.id: s.device
+        for leaf in jax.tree_util.tree_leaves(tree)
+        if isinstance(leaf, jax.Array)
+        for s in leaf.addressable_shards
+    }
+
+
+def placement_line(state, batch) -> str:
+    """Where a multi-device run's work actually sits after its first
+    dispatch: the ids of the devices holding shards of the training state
+    and of the batch, and the bytes in use on each state device as the
+    runtime counts them (null where the backend keeps no count — the CPU)."""
+    holders = shard_devices(state)
+    in_use = {}
+    for i, dev in sorted(holders.items()):
+        stats = dev.memory_stats()
+        in_use[str(i)] = None if stats is None else int(stats["bytes_in_use"])
+    return "Placement: " + json.dumps({
+        "state_devices": sorted(holders),
+        "batch_devices": sorted(shard_devices(batch)),
+        "bytes_in_use": in_use,
+    })

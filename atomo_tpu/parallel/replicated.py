@@ -74,7 +74,7 @@ from atomo_tpu.parallel.common import (
     unpack_tree_buckets,
 )
 from atomo_tpu.parallel.compile import compile_step
-from atomo_tpu.parallel.mesh import replicated
+from atomo_tpu.parallel.mesh import placement_line, replicated
 from atomo_tpu.utils.tracing import PHASE_METRICS_HINT, named_phase
 from atomo_tpu.training.resilience import (
     grad_ok,
@@ -1093,8 +1093,9 @@ def make_distributed_train_step(
     — at the cost of the fused matmul's MXU efficiency.
 
     DONATION: the returned step donates its state argument (argnum 0) —
-    after the call the caller's reference points at deleted buffers, and
-    on jax 0.4.37 ``replicate_state``/``jax.device_put`` may ALIAS their
+    after the call the caller's reference points at deleted buffers (on
+    the TPU a later read raises "Array has been deleted"), and on the CPU
+    backend ``replicate_state``/``jax.device_put`` may ALIAS their
     source, so even the host tree the state was built from can be
     poisoned. Code that needs pre-step values must copy them out with
     ``training.trainer.snapshot_state`` (a forced ``jax.device_get`` deep
@@ -4175,6 +4176,8 @@ def _distributed_steps(
             prof_ctx = None
         state, metrics = out[0], out[1]
         phases = out[2] if len(out) > 2 else None
+        if step == start_step + 1:
+            log_fn(placement_line(state, si))
         if monitor is not None:
             jax.block_until_ready(metrics["loss"])
             monitor.beat(step)
@@ -4459,6 +4462,8 @@ def _distributed_superstep_steps(
         state, mblk = step_fn(state, key, dev_im, dev_lb)
         feed.start(min(superstep, max_steps - s))  # overlap next transfer
         m = jax.device_get(mblk)  # the block's ONE host sync
+        if block_idx == 1:
+            log_fn(placement_line(state, dev_im))
         if prof_ctx is not None:
             prof_ctx.__exit__(None, None, None)
             prof_ctx = None

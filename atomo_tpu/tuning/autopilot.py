@@ -16,8 +16,9 @@ density and fabric, per model — PAPERS.md):
   2. PROBE: the top of the ladder is measured for real
      (tuning.probe.probe_candidate — the same step builders the train
      path uses, fenced timing, rows written atomically as they land).
-     Compile cost is amortized by ``ATOMO_COMPILE_CACHE``: the winner's
-     program is already warm in the cache when training starts.
+     Compile cost is amortized by the persistent compile cache
+     (utils/compile_cache.py): the winner's program is already warm in
+     the cache when training starts.
   3. DECIDE: :func:`choose_winner` — a PURE function of the probe rows,
      so the same artifact always names the same winner (tested). The
      decision, every candidate's predicted-vs-measured ms/step, and the

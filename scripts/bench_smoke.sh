@@ -2,12 +2,14 @@
 # Bench smoke (~8 min): prove the bench entrypoint still emits parseable
 # evidence without burning the full-ladder window. Nineteen checks:
 #
-#   1. config 7 (shipped-loop superstep) on the CPU backend in fast mode —
-#      the driver's last-line JSON contract, PLUS the partial-artifact
-#      file the row must also land in (PR-3 evidence hardening).
+#   1. the no-fallback contract: config 7 measures the TPU, so on the
+#      CPU backend it must exit NON-zero, say why on stderr, and leave
+#      an error row (never a CPU number) on stdout and in the artifact.
 #   2. config 8 (ring-vs-gather dispatch micro-compare, forced 4-device
-#      CPU mesh) — per-phase encode/exchange/decode timings present and
-#      the aggregation-operator bit-parity contract holds in-row.
+#      CPU mesh) — the last-line JSON contract, the partial-artifact
+#      file the row must also land in, per-phase encode/exchange/decode
+#      timings present and the aggregation-operator bit-parity contract
+#      holding in-row.
 #   3. config 9 (overlap-vs-blocking, forced 4-device CPU mesh) — both
 #      modes' fenced step times present per codec, the per-phase
 #      compute/encode/exchange/decode + hidden/exposed fields present,
@@ -16,7 +18,7 @@
 #      row says so honestly and the smoke does not gate on it).
 #   4. the kill contract: SIGKILL a full-ladder run mid-flight; the JSON
 #      artifact must still parse with whatever rows completed (rc=124
-#      resilience — the three-round zero-valid-TPU-rows failure mode).
+#      resilience).
 #
 #   5. the supervisor contract (<60 s, CPU): a crashloop@2 chaos run
 #      under --max-restarts 2 must exit 0 on the third attempt and
@@ -44,9 +46,10 @@
 #      restart-budget slot — finish at the same step count as an
 #      uninterrupted run, and leave a parseable incidents.jsonl with
 #      membership records (reshard="live" on the shrink epoch) plus a
-#      membership.json epoch history. (No ATOMO_COMPILE_CACHE here:
-#      the re-exec fallback shares cache dirs across different-world
-#      children, which corrupted executions on this backend — measured.)
+#      membership.json epoch history. (No compile cache here: the
+#      re-exec fallback shares cache dirs across different-world
+#      children, which corrupted executions on the CPU backend —
+#      measured.)
 #
 #   9. the stream-encode contract (<60 s, forced 4-device CPU mesh):
 #      bench config 12 must exit 0 with the per-phase encode
@@ -153,44 +156,46 @@ cd "$(dirname "$0")/.." || exit 2
 set -o pipefail
 art=$(mktemp -d)
 trap 'rm -rf "$art"' EXIT
+# Cache-cold by default, like tier-1 (the CPU backend's persistent-cache
+# round-trip is not bit-faithful and the drills assert bit parity). The
+# checks that want compile amortization switch JAX's cache back on and
+# point JAX_COMPILATION_CACHE_DIR at a throw-away directory — the program
+# itself never names a cache dir when that variable is set.
+export JAX_ENABLE_COMPILATION_CACHE=false
 
-# --- 1: config 7, JSON + artifact contract -------------------------------
-out=$(timeout -k 5 90 env JAX_PLATFORMS=cpu ATOMO_BENCH_FAST=1 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=240 \
+# --- 1: config 7 without a TPU fails out loud -----------------------------
+out=$(timeout -k 5 90 env JAX_PLATFORMS=cpu ATOMO_BENCH_DEADLINE_S=240 \
       ATOMO_BENCH_ARTIFACT="$art/c7.json" \
-      python bench.py --config 7 --no-baseline 2>/dev/null)
+      python bench.py --config 7 --no-baseline 2>"$art/c7.err")
 rc=$?
-if [ $rc -ne 0 ]; then
-  echo "bench_smoke FAIL: config 7 exited rc=$rc (timeout or crash)"
+if [ $rc -eq 0 ] || [ $rc -ge 124 ]; then
+  echo "bench_smoke FAIL: config 7 on the CPU backend exited rc=$rc" \
+       "(want a plain non-zero: no TPU, no row)"
   exit 1
 fi
 printf '%s\n' "$out" > "$art/c7.out"
-python - "$art/c7.out" "$art/c7.json" <<'EOF'
+python - "$art/c7.out" "$art/c7.json" "$art/c7.err" <<'EOF'
 import json, sys
 
 lines = [l for l in open(sys.argv[1]) if l.strip().startswith("{")]
 assert lines, "bench_smoke FAIL: no JSON emitted"
-row = json.loads(lines[-1])  # the driver parses the LAST line
-missing = [k for k in
-           ("metric", "value", "unit", "measurement_valid", "platform",
-            "timing", "error") if k not in row]
-assert not missing, f"bench_smoke FAIL: missing keys {missing}: {row}"
-assert row["unit"] == "ms/step", row
+row = json.loads(lines[-1])  # a caller parses the LAST line
 assert row["metric"] == "train_loop_superstep_step_time", row
+assert row["value"] is None and row["platform"] is None, row
+assert row["measurement_valid"] is False, row
+assert "measures the TPU" in (row["error"] or ""), row
 doc = json.load(open(sys.argv[2]))  # the atomic partial artifact
 assert doc["complete"] is True and len(doc["rows"]) == 1, doc
-assert doc["rows"][0]["metric"] == row["metric"]
-state = "valid" if row["measurement_valid"] else \
-    f"invalid ({row.get('invalid_reason')})"
-print(f"bench_smoke OK[1/19]: {row['metric']} = {row['value']} {row['unit']} "
-      f"[{row['platform']}, {state}, K={row.get('superstep')}, "
-      f"amortization={row.get('dispatch_amortization')}] + artifact")
+assert doc["rows"][0]["error"] == row["error"]
+assert "failed" in open(sys.argv[3]).read()
+print("bench_smoke OK[1/19]: config 7 without a TPU exits non-zero with "
+      "an error row, no CPU number under the device metric")
 EOF
 [ $? -ne 0 ] && exit 1
 
 # --- 2: config 8, ring-vs-gather micro-compare ---------------------------
 out=$(timeout -k 5 150 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=3 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=240 \
+      ATOMO_BENCH_DEADLINE_S=240 \
       ATOMO_BENCH_ARTIFACT="$art/c8.json" \
       python bench.py --config 8 --no-baseline 2>/dev/null)
 rc=$?
@@ -199,13 +204,20 @@ if [ $rc -ne 0 ]; then
   exit 1
 fi
 printf '%s\n' "$out" > "$art/c8.out"
-python - "$art/c8.out" <<'EOF'
+python - "$art/c8.out" "$art/c8.json" <<'EOF'
 import json, sys
 
 lines = [l for l in open(sys.argv[1]) if l.strip().startswith("{")]
 assert lines, "bench_smoke FAIL: config 8 emitted no JSON"
-row = json.loads(lines[-1])
+row = json.loads(lines[-1])  # a caller parses the LAST line
+missing = [k for k in
+           ("metric", "value", "unit", "measurement_valid", "platform",
+            "error") if k not in row]
+assert not missing, f"bench_smoke FAIL: missing keys {missing}: {row}"
 assert row["metric"] == "ring_vs_gather_dispatch", row
+doc = json.load(open(sys.argv[2]))  # the atomic partial artifact
+assert doc["complete"] is True and len(doc["rows"]) == 1, doc
+assert doc["rows"][0]["metric"] == row["metric"]
 assert row["measurement_valid"], row.get("invalid_reason")
 for k in ("encode_ms", "gather_exchange_ms", "gather_decode_ms",
           "ring_exchange_decode_ms", "gather_ms_per_step"):
@@ -220,7 +232,7 @@ EOF
 
 # --- 3: config 9, overlap-vs-blocking contract ---------------------------
 out=$(timeout -k 5 360 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=4 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=340 \
+      ATOMO_BENCH_DEADLINE_S=340 \
       ATOMO_BENCH_ARTIFACT="$art/c9.json" \
       python bench.py --config 9 --no-baseline 2>/dev/null)
 rc=$?
@@ -259,11 +271,11 @@ EOF
 [ $? -ne 0 ] && exit 1
 
 # --- 4: kill mid-ladder, artifact still parses ---------------------------
-env JAX_PLATFORMS=cpu ATOMO_BENCH_FAST=1 ATOMO_BENCH_RETRIES=1 \
+env JAX_PLATFORMS=cpu ATOMO_BENCH_FAST=1 \
     ATOMO_BENCH_DEADLINE_S=600 ATOMO_BENCH_ARTIFACT="$art/killed.json" \
     python bench.py --all --no-baseline >/dev/null 2>&1 &
 pid=$!
-# wait for the FIRST atomic write (probe record) before killing — a fixed
+# wait for the FIRST atomic write (ladder start) before killing — a fixed
 # sleep races bench startup on a loaded host and fails spuriously
 for _ in $(seq 1 60); do
   [ -f "$art/killed.json" ] && break
@@ -278,16 +290,15 @@ import json, sys
 doc = json.load(open(sys.argv[1]))  # must parse despite the SIGKILL
 assert doc["complete"] is False
 assert isinstance(doc["rows"], list)  # completed rows (possibly none yet)
-assert doc["tpu_probe"] is not None  # probe diagnostics recorded up front
 print(f"bench_smoke OK[4/19]: killed ladder left a parseable artifact "
-      f"({len(doc['rows'])} completed rows, probe recorded)")
+      f"({len(doc['rows'])} completed rows)")
 EOF
 
 [ $? -ne 0 ] && exit 1
 
 # --- 5: supervisor crashloop budget drill --------------------------------
 sup="$art/sup"
-out=$(timeout -k 5 60 env JAX_PLATFORMS=cpu ATOMO_COMPILE_CACHE="$art/xla" \
+out=$(timeout -k 5 60 env JAX_PLATFORMS=cpu JAX_ENABLE_COMPILATION_CACHE=true JAX_COMPILATION_CACHE_DIR="$art/xla" \
       python -m atomo_tpu.cli train --synthetic --dataset mnist \
       --network lenet --batch-size 8 --max-steps 3 --eval-freq 2 \
       --log-interval 1 --n-devices 1 --train-dir "$sup" \
@@ -313,7 +324,7 @@ EOF
 
 # --- 6: autopilot probe ladder + decision artifact -----------------------
 tune="$art/tune"
-out=$(timeout -k 5 60 env JAX_PLATFORMS=cpu ATOMO_COMPILE_CACHE="$art/xla" \
+out=$(timeout -k 5 60 env JAX_PLATFORMS=cpu JAX_ENABLE_COMPILATION_CACHE=true JAX_COMPILATION_CACHE_DIR="$art/xla" \
       XLA_FLAGS="--xla_force_host_platform_device_count=4" \
       python -m atomo_tpu.cli train --synthetic --dataset mnist \
       --network lenet --batch-size 8 --max-steps 2 --eval-freq 0 \
@@ -348,7 +359,7 @@ EOF
 
 # --- 7: config 11, two-tier planned-schedule contract --------------------
 out=$(timeout -k 5 150 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=3 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=340 \
+      ATOMO_BENCH_DEADLINE_S=340 \
       ATOMO_BENCH_ARTIFACT="$art/c11.json" \
       python bench.py --config 11 --no-baseline 2>/dev/null)
 rc=$?
@@ -397,7 +408,7 @@ EOF
 # process start to finish, no membership_change incident, reshard="live"
 # stamped on the shrink epoch's membership record
 el="$art/elastic"
-out=$(timeout -k 5 60 env JAX_PLATFORMS=cpu ATOMO_COMPILE_CACHE= \
+out=$(timeout -k 5 60 env JAX_PLATFORMS=cpu \
       XLA_FLAGS="--xla_force_host_platform_device_count=4" \
       python -m atomo_tpu.cli train --synthetic --dataset mnist \
       --network lenet --batch-size 12 --max-steps 8 --eval-freq 0 \
@@ -452,7 +463,7 @@ EOF
 
 # --- 9: config 12, stream-encode exposure contract -----------------------
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=3 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
+      ATOMO_BENCH_DEADLINE_S=110 \
       ATOMO_BENCH_ARTIFACT="$art/c12.json" \
       python bench.py --config 12 --no-baseline 2>/dev/null)
 rc=$?
@@ -490,7 +501,7 @@ EOF9
 
 # --- 10: flight recorder + quality probes + report verb ------------------
 obsd="$art/obs"
-out=$(timeout -k 5 60 env JAX_PLATFORMS=cpu ATOMO_COMPILE_CACHE="$art/xla" \
+out=$(timeout -k 5 60 env JAX_PLATFORMS=cpu JAX_ENABLE_COMPILATION_CACHE=true JAX_COMPILATION_CACHE_DIR="$art/xla" \
       XLA_FLAGS="--xla_force_host_platform_device_count=4" \
       python -m atomo_tpu.cli train --synthetic --dataset mnist \
       --network lenet --batch-size 8 --max-steps 6 --eval-freq 0 \
@@ -538,7 +549,7 @@ EOF
 
 # --- 11: config 13, sparse-vs-dense wire contract ------------------------
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=3 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
+      ATOMO_BENCH_DEADLINE_S=110 \
       ATOMO_BENCH_ARTIFACT="$art/c13.json" \
       python bench.py --config 13 --no-baseline 2>/dev/null)
 rc=$?
@@ -580,8 +591,8 @@ EOF11
 
 # --- 12: config 14, fabric probe + measured-fabric parity contract ------
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=3 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
-      ATOMO_COMPILE_CACHE="$art/xla" \
+      ATOMO_BENCH_DEADLINE_S=110 \
+      JAX_ENABLE_COMPILATION_CACHE=true JAX_COMPILATION_CACHE_DIR="$art/xla" \
       ATOMO_BENCH_ARTIFACT="$art/c14.json" \
       python bench.py --config 14 --no-baseline 2>/dev/null)
 rc=$?
@@ -623,8 +634,8 @@ EOF12
 
 # --- 13: config 15, sharded-update memory + bit-parity contract ----------
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=3 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
-      ATOMO_COMPILE_CACHE="$art/xla" \
+      ATOMO_BENCH_DEADLINE_S=110 \
+      JAX_ENABLE_COMPILATION_CACHE=true JAX_COMPILATION_CACHE_DIR="$art/xla" \
       ATOMO_BENCH_ARTIFACT="$art/c15.json" \
       python bench.py --config 15 --no-baseline 2>/dev/null)
 rc=$?
@@ -664,7 +675,7 @@ EOF13
 
 # --- 14: config 16, adaptive-budget Pareto + wire-match contract ---------
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=10 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
+      ATOMO_BENCH_DEADLINE_S=110 \
       ATOMO_BENCH_ARTIFACT="$art/c16.json" \
       python bench.py --config 16 --no-baseline 2>/dev/null)
 rc=$?
@@ -707,8 +718,8 @@ EOF14
 
 # --- 15: config 17, quorum straggler-absorption contract -----------------
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=5 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
-      ATOMO_COMPILE_CACHE="$art/xla" \
+      ATOMO_BENCH_DEADLINE_S=110 \
+      JAX_ENABLE_COMPILATION_CACHE=true JAX_COMPILATION_CACHE_DIR="$art/xla" \
       ATOMO_BENCH_ARTIFACT="$art/c17.json" \
       python bench.py --config 17 --no-baseline 2>/dev/null)
 rc=$?
@@ -752,8 +763,8 @@ EOF15
 # of the 15 prior checks can push it over. If ONLY this check fails,
 # re-run checks 16-19 in isolation before treating it as a regression.
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
-      ATOMO_COMPILE_CACHE="$art/xla" \
+      ATOMO_BENCH_DEADLINE_S=110 \
+      JAX_ENABLE_COMPILATION_CACHE=true JAX_COMPILATION_CACHE_DIR="$art/xla" \
       ATOMO_BENCH_ARTIFACT="$art/c18.json" \
       python bench.py --config 18 --no-baseline 2>/dev/null)
 rc=$?
@@ -797,8 +808,8 @@ EOF16
 
 # --- 17: config 19, model-axis compressed-dp-wire contract ---------------
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=3 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
-      ATOMO_COMPILE_CACHE="$art/xla" \
+      ATOMO_BENCH_DEADLINE_S=110 \
+      JAX_ENABLE_COMPILATION_CACHE=true JAX_COMPILATION_CACHE_DIR="$art/xla" \
       ATOMO_BENCH_ARTIFACT="$art/c19.json" \
       python bench.py --config 19 --no-baseline 2>/dev/null)
 rc=$?
@@ -841,11 +852,10 @@ EOF17
 # persistent-cache round-trip is not bit-faithful (the warm-cache
 # parity hazard tests/conftest.py records) — measured as a
 # deterministic resume-drill divergence with any cache dir set.
-# bench.py strips ATOMO_COMPILE_CACHE from the config-20 child too
+# bench.py switches the cache off for the config-20 child too
 # (CONFIGS[20]["no_compile_cache"]), so this is belt and suspenders.
 out=$(timeout -k 5 120 env ATOMO_BENCH_FAST=1 ATOMO_BENCH_STEPS=3 \
-      ATOMO_BENCH_RETRIES=1 ATOMO_BENCH_DEADLINE_S=110 \
-      ATOMO_COMPILE_CACHE="" \
+      ATOMO_BENCH_DEADLINE_S=110 \
       ATOMO_BENCH_ARTIFACT="$art/c20.json" \
       python bench.py --config 20 --no-baseline 2>/dev/null)
 rc=$?

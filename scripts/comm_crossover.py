@@ -51,8 +51,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 from atomo_tpu.codecs import (  # noqa: E402
     SvdCodec,
     decode_mean_tree,
@@ -235,8 +233,8 @@ def main() -> int:
             "timing": "scan-fenced best-of-rounds",
         },
         "measured": results,
-        # analytic model seeded with round-3 ON-CHIP numbers (config 2,
-        # scan-fenced: dense 6.50 ms, svd3 9.01 ms — BENCH_ONCHIP_r3.md);
+        # analytic model seeded with the config-2 anchors (dense 6.50 ms,
+        # svd3 9.01 ms — unverified figures from before this round);
         # bench.py re-attaches this per config with same-session numbers
         "model_onchip_config2": crossover_report(
             dense_bytes, payload_bytes, 6.50e-3, 9.01e-3

@@ -107,10 +107,6 @@ def main() -> int:
     except ValueError as e:
         ap.error(str(e))
 
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -43,8 +43,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 PEAK_TFLOPS = 197.0  # v5e bf16 MXU
 HBM_GBPS = 819.0  # v5e HBM bandwidth
-# round-3 measured scan-fenced ms/step (artifacts/BENCH_ONCHIP_r3.md) for
-# the efficiency column; configs 4/5 have only superseded-protocol numbers
+# scan-fenced ms/step for the efficiency column: unverified anchors from
+# before this round (a manual v5e record, never reproduced on the stock
+# backend); configs 4/5 have only superseded-protocol numbers
 MEASURED_R3_MS = {1: 1.058, 2: 8.86, 3: 6.155}
 
 
@@ -54,10 +55,6 @@ def main() -> int:
     ap.add_argument("--out", type=str, default="artifacts")
     args = ap.parse_args()
 
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax
     import jax.numpy as jnp
 

@@ -11,9 +11,9 @@ recommendation means):
                       measured-anchor recommendations.
   (default)           model-only: real byte budgets from jax.eval_shape
                       on the CPU backend (cheap — no training, no
-                      device work) + the stated measured anchors from
-                      artifacts/BENCH_ONCHIP_r3.md scaled by gradient
-                      size. Deterministic, so the README table is
+                      device work) + the stated anchors
+                      (utils/comm_model.py — unverified figures from
+                      before this round) scaled by gradient size. Deterministic, so the README table is
                       reproducible by anyone:
                       `python scripts/scenario_table.py`.
 
@@ -517,7 +517,7 @@ def main() -> int:
         f"measured fabric, {args.from_probe} (compute/tax anchors stay "
         "the stated model-only estimates)"
         if fabric_probe is not None
-        else "model-only anchors, artifacts/BENCH_ONCHIP_r3.md; "
+        else "model-only anchors, unverified figures from before this round; "
              "2-tier rows: topology planner over the same anchors + "
              "stated latency estimates — ordering only, measured "
              "evidence is bench config 11"

@@ -34,10 +34,6 @@ def main() -> int:
     ap.add_argument("--out", type=str, default="artifacts")
     args = ap.parse_args()
 
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax
     import jax.numpy as jnp
     import numpy as np

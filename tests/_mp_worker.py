@@ -16,6 +16,10 @@ only monkeypatch (VERDICT r2 next-round #5):
 Prints one `RESULT {json}` line; the parent asserts both processes agree
 bit-for-bit on the post-step state (replicated-PS equivalence, SURVEY.md §7
 hard-part 4).
+
+A CPU drill by construction (it forces the CPU platform below): two JAX
+processes on one host cannot share a chip, and nothing assigns chips
+between them.
 """
 
 import json

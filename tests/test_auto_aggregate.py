@@ -16,8 +16,9 @@ from atomo_tpu.utils.comm_model import (
     estimate_codec_tax_s,
 )
 
-# the measured config-2 regime (artifacts/BENCH_ONCHIP_r3.md): ResNet-18
-# dense gradient 44.7 MB, svd3 byte reduction 71.8x, codec tax ~2.5 ms
+# the config-2 regime (an unverified anchor from before this round):
+# ResNet-18 dense gradient 44.7 MB, svd3 byte reduction 71.8x, codec tax
+# ~2.5 ms
 R18 = dict(dense_bytes=44.7e6, payload_bytes=44.7e6 / 71.8)
 
 

@@ -772,9 +772,10 @@ def _cli_elastic(train_dir, *extra, timeout=300):
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
         "PYTHONPATH": _REPO_ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
     }
-    env.pop("ATOMO_COMPILE_CACHE", None)  # shared-cache re-execs across
-    # world sizes corrupted executions on this backend (measured); the
-    # drills prove semantics, not compile amortization
+    # cache-cold (the conftest default, restated): shared-cache re-execs
+    # across world sizes corrupted executions on the CPU backend
+    # (measured); the drills prove semantics, not compile amortization
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     cmd = [
         sys.executable, "-m", "atomo_tpu.cli", "train",
         "--synthetic", "--dataset", "mnist", "--network", "lenet",

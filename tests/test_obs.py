@@ -635,7 +635,7 @@ def _cli_obs_drill(train_dir, *extra, timeout=240):
         os.environ,
         JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=4",
-        ATOMO_COMPILE_CACHE="",
+        JAX_ENABLE_COMPILATION_CACHE="false",  # (the conftest default)
     )
     cmd = [
         sys.executable, "-m", "atomo_tpu.cli", "train",

@@ -408,43 +408,3 @@ def test_named_phase_is_transparent():
         np.asarray(jax.jit(f)(jnp.arange(4.0))),
         np.asarray(f(jnp.arange(4.0))),
     )
-
-
-def test_compile_cache_env_gated(tmp_path):
-    """ATOMO_COMPILE_CACHE wires the persistent XLA compilation cache and
-    logs entry counts (hit pool at enable, misses at exit). Run in a
-    subprocess: the cache dir is process-global jax config."""
-    import os
-    import subprocess
-    import sys
-
-    code = """
-import os, sys
-import jax
-jax.config.update("jax_platforms", "cpu")
-from atomo_tpu.compat import enable_compile_cache
-logs = []
-assert enable_compile_cache(log_fn=logs.append) == os.environ["ATOMO_COMPILE_CACHE"]
-import jax.numpy as jnp
-jax.jit(lambda a: jnp.sin(a) * 2)(jnp.arange(64.0)).block_until_ready()
-assert any("hits" in l for l in logs), logs
-print("CACHE_OK")
-"""
-    env = {
-        **os.environ,
-        "ATOMO_COMPILE_CACHE": str(tmp_path / "cache"),
-        "JAX_PLATFORMS": "cpu",
-        "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    }
-    p = subprocess.run(
-        [sys.executable, "-c", code], env=env, capture_output=True,
-        text=True, timeout=120,
-    )
-    assert p.returncode == 0 and "CACHE_OK" in p.stdout, p.stderr[-2000:]
-    # entries persisted for the next process (the whole point)
-    assert any((tmp_path / "cache").iterdir())
-    # disabled without the env var: no config touched, returns None
-    if "ATOMO_COMPILE_CACHE" not in os.environ:
-        from atomo_tpu.compat import enable_compile_cache
-
-        assert enable_compile_cache(log_fn=lambda *_: None) is None

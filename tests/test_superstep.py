@@ -72,8 +72,8 @@ def _host_state(model, opt, batches):
 
 
 def _fresh(host_state):
-    # real device copies: the fused step DONATES its carry, and on jax
-    # 0.4.37 device_put can alias a host tree's buffers — asarray from the
+    # real device copies: the fused step DONATES its carry, and on the CPU
+    # backend device_put can alias a host tree's buffers — asarray from the
     # snapshot_state numpy copies is safe to donate repeatedly
     return jax.tree_util.tree_map(jnp.asarray, host_state)
 
