@@ -3136,6 +3136,7 @@ def cmd_train(args: argparse.Namespace) -> int:
                 diverge=diverge, tuner=tuner,
                 track_quality=args.obs_quality,
                 recorder=recorder,
+                profile_dir=args.profile_dir or None,
             )
         except DivergenceError as exc:
             return _diverged_exit(exc)
@@ -3612,40 +3613,64 @@ def cmd_lm(args: argparse.Namespace) -> int:
             ),
         })
 
-    save_freq = args.save_freq
-    for i in range(start + 1, args.max_steps + 1):
-        t0 = time.time()
-        batch = next_batch()
-        state, metrics = step(state, jax.random.fold_in(key, i), batch)
-        loss = float(metrics["loss"])  # device sync: honest step timing
-        if i == start + 1:
-            print(placement_line(state, batch), flush=True)
-        if recorder is not None:
-            recorder.record_block(
-                i, jax.device_get(metrics), wall_s=time.time() - t0
-            )
-        if i % args.log_interval == 0 or i == args.max_steps:
-            print(
-                f"LM: Step: {i}, Layout: {layout}({spec.describe()}), "
-                f"Loss: {loss:.4f}, PPL: {math.exp(min(loss, 30.0)):.2f}, "
-                f"Time Cost: {time.time() - t0:.4f}, "
-                f"Msg(MB): {float(metrics['msg_bytes']) / 1e6:.4f}, "
-                f"Dense(MB): {float(metrics['dense_bytes']) / 1e6:.4f}",
-                flush=True,
-            )
-        if args.eval_freq and i % args.eval_freq == 0:
-            vl, vl_extra = eval_ppl(state)
-            print(
-                f"LM Validation: Step: {i}, Loss: {vl:.4f}, "
-                f"PPL: {math.exp(min(vl, 30.0)):.2f}" + vl_extra,
-                flush=True,
-            )
-        if args.train_dir and (
-            (save_freq and i % save_freq == 0) or i == args.max_steps
-        ):
-            from atomo_tpu.training.checkpoint import save_checkpoint
+    from atomo_tpu.utils.tracing import (
+        BOUNDARY,
+        DISPATCH,
+        FETCH,
+        NEXT_BATCH,
+        PROFILE_STEPS,
+        STEP,
+        ProfileWindow,
+        clear as clear_spans,
+        span,
+    )
 
-            save_checkpoint(args.train_dir, state, compress=args.compress)
+    save_freq = args.save_freq
+    prof = ProfileWindow(args.profile_dir or None, print, recorder)
+    clear_spans()  # the ring holds this loop's iterations
+    for i in range(start + 1, args.max_steps + 1):
+        with span(STEP, i):
+            t0 = time.time()
+            if i == start + 2:  # step 1 is dominated by compilation
+                prof.open(i, i + PROFILE_STEPS - 1)
+            with span(NEXT_BATCH):
+                batch = next_batch()
+            with span(DISPATCH):
+                state, metrics = step(state, jax.random.fold_in(key, i), batch)
+            with span(FETCH):
+                loss = float(metrics["loss"])  # device sync: honest step timing
+            if prof.ends_at(i):
+                prof.close()
+            with span(BOUNDARY):
+                if i == start + 1:
+                    print(placement_line(state, batch), flush=True)
+                if recorder is not None:
+                    recorder.record_block(
+                        i, jax.device_get(metrics), wall_s=time.time() - t0
+                    )
+                if i % args.log_interval == 0 or i == args.max_steps:
+                    print(
+                        f"LM: Step: {i}, Layout: {layout}({spec.describe()}), "
+                        f"Loss: {loss:.4f}, PPL: {math.exp(min(loss, 30.0)):.2f}, "
+                        f"Time Cost: {time.time() - t0:.4f}, "
+                        f"Msg(MB): {float(metrics['msg_bytes']) / 1e6:.4f}, "
+                        f"Dense(MB): {float(metrics['dense_bytes']) / 1e6:.4f}",
+                        flush=True,
+                    )
+                if args.eval_freq and i % args.eval_freq == 0:
+                    vl, vl_extra = eval_ppl(state)
+                    print(
+                        f"LM Validation: Step: {i}, Loss: {vl:.4f}, "
+                        f"PPL: {math.exp(min(vl, 30.0)):.2f}" + vl_extra,
+                        flush=True,
+                    )
+                if args.train_dir and (
+                    (save_freq and i % save_freq == 0) or i == args.max_steps
+                ):
+                    from atomo_tpu.training.checkpoint import save_checkpoint
+
+                    save_checkpoint(args.train_dir, state, compress=args.compress)
+    prof.close()  # a run shorter than the profiled window
     return 0
 
 
@@ -3912,6 +3937,11 @@ def build_parser() -> argparse.ArgumentParser:
                            "the wire (unbiased, ~half the payload bytes)")
     p_lm.add_argument("--quantization-level", type=int, default=2)
     p_lm.add_argument("--bucket-size", type=int, default=512)
+    p_lm.add_argument("--profile-dir", type=str, default="",
+                      help="capture a jax.profiler device trace of steps "
+                           "start+2..start+4 into this dir: what `report "
+                           "timeline --profile-dir` reads (the train "
+                           "subcommand's flag)")
     p_lm.set_defaults(fn=cmd_lm)
 
     p_rep = sub.add_parser(

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from atomo_tpu.data.datasets import ArrayDataset
+from atomo_tpu.utils.tracing import PUT, STACK, span
 
 
 def normalize(images: jax.Array, mean, std) -> jax.Array:
@@ -181,8 +182,10 @@ class SuperstepFeed:
 
     def start(self, k: int) -> None:
         if k > 0:
-            im, lb = self._blocks.take(k)
-            dev_im, dev_lb = self._put(im, lb)
+            with span(STACK):
+                im, lb = self._blocks.take(k)
+            with span(PUT):  # enqueues; the runtime's threads change the layout behind it
+                dev_im, dev_lb = self._put(im, lb)
             self._staged = (k, dev_im, dev_lb)
 
     def take(self):

@@ -23,8 +23,15 @@ timeline ``--phase-metrics`` never could produce:
      its full scope path (``jit(step)/.../encode/...``) — the anchor the
      ``named_phase`` scopes planted (tested: a refactor that drops them
      fails tests/test_fabric_obs.py's scope-presence asserts).
+     The TPU's trace (read on a v5e, PERF.md §6) carries the same plane
+     but its ``XLA Ops`` events carry neither ``program_id`` nor
+     ``hlo_op``: an event's name is the instruction's whole text
+     (``%fusion.12 = f32[...] fusion(...)``, no ``metadata=``), and the
+     program is named by the ``XLA Modules`` event over it
+     (``jit_step(<program id>)``). :func:`device_events` reads both forms.
   3. ATTRIBUTE: op events of the training-step module are segmented into
-     dispatches (executions) by the modal-occurrence boundary op, then
+     dispatches (executions) — the ``XLA Modules`` events where the trace
+     has them, else the modal-occurrence boundary op — then
      every op lands in a phase by its scope path. Per dispatch and per
      phase the timeline reports ``busy`` (summed op time), ``exposed``
      (the phase's interval union MINUS the compute union — time the
@@ -34,6 +41,11 @@ timeline ``--phase-metrics`` never could produce:
      ``ring_exchange_decode`` scope is attributed to ``exchange`` (its
      decode overlaps the transfer BY CONSTRUCTION — the fusion is the
      feature, and no trace can split it).
+     The host spans of the loop (``utils.tracing.span``: block / step >
+     feed_take, dispatch, feed_start > stack, put, next_batch, fetch,
+     boundary) are events of the ``/host:`` planes on the same clock:
+     the timeline lists them, and puts every device idle gap down to the
+     innermost program span over it.
   4. JOIN: with a ``train_dir``, the spans are joined against
      ``metrics.jsonl`` by absolute time (the trace's
      ``profile_start_time`` is unix ns) and cross-checked: the recorded
@@ -50,9 +62,13 @@ never imports jax — safe on a box that cannot reach the accelerator
 
 from __future__ import annotations
 
+import bisect
 import os
+import re
 import struct
 from typing import Iterator, Optional
+
+from atomo_tpu.utils import tracing
 
 TIMELINE_REPORT_NAME = "timeline_report.json"
 
@@ -65,10 +81,30 @@ PHASE_OF_SCOPE = {
     "hybrid_exchange": "exchange",
     "delayed_exchange": "exchange",
     "ring_exchange_decode": "exchange",
+    "decode": "decode",
     "decode_mean": "decode",
     "delayed_decode_mean": "decode",
+    "forward_backward": "forward_backward",
+    "attention": "attention",
+    "update": "update",
 }
+# the codec's and the exchange's phases are measured AGAINST the compute
+# side (exposed / hidden); the model's own phases are the compute side,
+# split by scope, and report their busy time. An op is in ONE phase, the
+# innermost scope's: `forward_backward` is what is left of it outside
+# `attention`.
 PHASES = ("encode", "exchange", "decode")
+MODEL_PHASES = ("forward_backward", "attention", "update")
+# the loop's host spans, as utils.tracing names them
+HOST_SPANS = (
+    tracing.BLOCK, tracing.STEP, tracing.FEED_TAKE, tracing.DISPATCH,
+    tracing.FEED_START, tracing.STACK, tracing.PUT, tracing.NEXT_BATCH,
+    tracing.FETCH, tracing.BOUNDARY,
+)
+TPU_OPS_LINE, TPU_MODULES_LINE = "XLA Ops", "XLA Modules"
+# their time is their bodies', which the trace lists beside them
+CONTAINER_OPS = ("while", "conditional", "call")
+MIN_IDLE_GAP_US = 20.0  # shorter: between two operations of one program
 
 
 # ------------------------------------------------ minimal protobuf walk
@@ -275,11 +311,13 @@ def scope_maps(space: dict) -> dict:
 
 
 def phase_of(op_name: Optional[str]) -> str:
-    """Classify one op's scope path into encode/exchange/decode/compute
-    by its ``named_phase`` path components."""
+    """Classify one op's scope path into a phase by its ``named_phase``
+    path components, innermost first. A scope crossed by autodiff shows
+    inside the transform's brackets (``transpose(jvp(attention))``), so
+    every identifier of the path counts, not only whole components."""
     if op_name:
-        for part in op_name.split("/"):
-            ph = PHASE_OF_SCOPE.get(part)
+        for token in reversed(re.findall(r"[A-Za-z_][A-Za-z0-9_]*", op_name)):
+            ph = PHASE_OF_SCOPE.get(token)
             if ph:
                 return ph
     return "compute"
@@ -344,6 +382,133 @@ def _intersect_len_us(a: list, b: list) -> float:
     return total
 
 
+def device_events(space: dict) -> tuple[dict, dict]:
+    """``({program_id: [op event]}, {program_id: [(start_us, end_us)]})``
+    over every line of every plane: each op execution with its
+    instruction name, its ``(plane, line)`` and its interval, and, where
+    the trace names them, the program's executions.
+
+    Two forms (module docstring). The CPU's and GPU's events carry
+    ``program_id`` and ``hlo_op`` stats and are named for the
+    instruction. A TPU plane has an ``XLA Modules`` line whose events are
+    the executions, named ``<module>(<program id>)``, and an ``XLA Ops``
+    line whose events are named by the instruction's text: an op belongs
+    to the execution it lies in, its instruction name is the text before
+    `` = ``, and container ops (while, conditional, call) are left out,
+    since their bodies' ops are events of their own."""
+    events_by_pid: dict = {}
+    runs_by_pid: dict = {}
+    for plane in space["planes"]:
+        by_name = {line["name"]: line for line in plane["lines"]}
+        if TPU_MODULES_LINE in by_name and TPU_OPS_LINE in by_name:
+            mods = by_name[TPU_MODULES_LINE]
+            base = mods["timestamp_ns"] / 1e3
+            runs = []
+            for ev in mods["events"]:
+                found = re.search(r"\((\d+)\)$", ev["name"] or "")
+                if found:
+                    start = base + ev["offset_ps"] / 1e6
+                    runs.append((start, start + ev["duration_ps"] / 1e6,
+                                 int(found.group(1))))
+            runs.sort()
+            for start, end, pid in runs:
+                runs_by_pid.setdefault(pid, []).append((start, end))
+            ops = by_name[TPU_OPS_LINE]
+            base = ops["timestamp_ns"] / 1e3
+            starts = [r[0] for r in runs]
+            for ev in ops["events"]:
+                start = base + ev["offset_ps"] / 1e6
+                i = bisect.bisect_right(starts, start) - 1
+                if i < 0 or start >= runs[i][1]:
+                    continue  # outside every traced execution
+                instr = (ev["name"] or "").partition(" = ")[0].lstrip("%")
+                if instr.startswith(CONTAINER_OPS):
+                    continue
+                events_by_pid.setdefault(runs[i][2], []).append({
+                    "name": instr,
+                    "line": (plane["name"], TPU_OPS_LINE),
+                    "start_us": start,
+                    "end_us": start + ev["duration_ps"] / 1e6,
+                })
+            continue
+        for line in plane["lines"]:
+            base_us = line["timestamp_ns"] / 1e3
+            for ev in line["events"]:
+                pid = ev["stats"].get("program_id")
+                if pid is None or "hlo_op" not in ev["stats"]:
+                    continue
+                start = base_us + ev["offset_ps"] / 1e6
+                events_by_pid.setdefault(pid, []).append({
+                    "name": ev["name"],
+                    # the (plane, line) identity: _segment_executions
+                    # anchors on ONE device line so concurrent devices
+                    # do not over-split dispatches
+                    "line": (plane["name"], line["name"]),
+                    "start_us": start,
+                    "end_us": start + ev["duration_ps"] / 1e6,
+                })
+    return events_by_pid, runs_by_pid
+
+
+def host_spans(space: dict) -> list[dict]:
+    """The loop's own spans (utils.tracing.span) as the ``/host:`` planes
+    recorded them, in time order: name, the iteration's step, interval."""
+    out = []
+    for plane in space["planes"]:
+        if not plane["name"].startswith("/host:"):
+            continue
+        for line in plane["lines"]:
+            base_us = line["timestamp_ns"] / 1e3
+            for ev in line["events"]:
+                if ev["name"] in HOST_SPANS:
+                    start = base_us + ev["offset_ps"] / 1e6
+                    step = ev["stats"].get("step_num", ev["stats"].get("step"))
+                    out.append({
+                        "name": ev["name"],
+                        "step": int(step) if isinstance(step, int) else None,
+                        "start_us": start,
+                        "end_us": start + ev["duration_ps"] / 1e6,
+                    })
+    return sorted(out, key=lambda sp: sp["start_us"])
+
+
+def idle_by_span(busy: list, spans: list[dict], lo: float, hi: float) -> dict:
+    """``{span name: idle us}`` over ``[lo, hi]``: every stretch of
+    :data:`MIN_IDLE_GAP_US` or more that no interval of ``busy`` covers,
+    cut where a host span opens or closes, each piece put down to the
+    innermost (shortest) span over it; ``(no span)`` where the loop had
+    none open. So the gap between two executions splits into the loss
+    coming back (``fetch``), the loop's own work (``boundary``,
+    ``next_batch``) and the launch (``dispatch``)."""
+    out: dict = {}
+    cur = lo
+    gaps = []
+    for s_us, e_us in sorted(busy):
+        if s_us > cur:
+            gaps.append((cur, min(s_us, hi)))
+        cur = max(cur, e_us)
+        if cur >= hi:
+            break
+    if hi > cur:
+        gaps.append((cur, hi))
+    for g0, g1 in gaps:
+        if g1 - g0 < MIN_IDLE_GAP_US:
+            continue
+        over = [sp for sp in spans if sp["start_us"] < g1 and sp["end_us"] > g0]
+        cuts = sorted({g0, g1} | {
+            t for sp in over for t in (sp["start_us"], sp["end_us"]) if g0 < t < g1
+        })
+        for p0, p1 in zip(cuts, cuts[1:]):
+            mid = (p0 + p1) / 2
+            inner = min(
+                (sp for sp in over if sp["start_us"] <= mid <= sp["end_us"]),
+                key=lambda sp: sp["end_us"] - sp["start_us"], default=None,
+            )
+            name = inner["name"] if inner else "(no span)"
+            out[name] = out.get(name, 0.0) + (p1 - p0)
+    return out
+
+
 def _segment_executions(events: list[dict]) -> list[list[dict]]:
     """Split one module's op events (time-sorted) into dispatches.
 
@@ -380,8 +545,6 @@ def _segment_executions(events: list[dict]) -> list[list[dict]]:
     anchors = [
         ev["start_us"] for ev in ref_events if ev["name"] == boundary
     ]
-    import bisect
-
     execs: list[list[dict]] = [[] for _ in anchors]
     for ev in events:
         # window i covers [anchors[i], anchors[i+1]); pre-anchor events
@@ -445,25 +608,9 @@ def build_timeline(
         doc["consistent"] = False
         return doc
 
-    # collect op events per program id across every line of every plane
-    events_by_pid: dict = {}
-    for plane in space["planes"]:
-        for line in plane["lines"]:
-            base_us = line["timestamp_ns"] / 1e3
-            for ev in line["events"]:
-                pid = ev["stats"].get("program_id")
-                if pid is None or "hlo_op" not in ev["stats"]:
-                    continue
-                start = base_us + ev["offset_ps"] / 1e6
-                events_by_pid.setdefault(pid, []).append({
-                    "name": ev["name"],
-                    # the (plane, line) identity: _segment_executions
-                    # anchors on ONE device line so concurrent devices
-                    # do not over-split dispatches
-                    "line": (plane["name"], line["name"]),
-                    "start_us": start,
-                    "end_us": start + ev["duration_ps"] / 1e6,
-                })
+    # op events per program id across every line of every plane, and the
+    # programs' executions where the trace names them (the TPU's form)
+    events_by_pid, runs_by_pid = device_events(space)
     # Task Environment anchors trace time to unix time
     start_ns = None
     for plane in space["planes"]:
@@ -501,22 +648,35 @@ def build_timeline(
         f"op executions across {len(events)} events",
     )
 
+    if runs_by_pid.get(pid):
+        # the trace names the executions: an op belongs to the one it lies in
+        op_starts = [e["start_us"] for e in events]  # sorted above
+        executions = [
+            events[bisect.bisect_left(op_starts, r0):bisect.bisect_left(op_starts, r1)]
+            for r0, r1 in runs_by_pid[pid]
+        ]
+        executions = [ex for ex in executions if ex]
+    else:
+        executions = _segment_executions(events)
     spans = []
-    for i, ex in enumerate(_segment_executions(events)):
-        ivs: dict = {p: [] for p in PHASES}
-        ivs["compute"] = []
-        busy: dict = {p: 0.0 for p in PHASES}
-        busy["compute"] = 0.0
+    names = PHASES + MODEL_PHASES + ("compute",)
+    for i, ex in enumerate(executions):
+        ivs: dict = {p: [] for p in names}
+        busy: dict = {p: 0.0 for p in names}
         for ev in ex:
             ivs[ev["phase"]].append((ev["start_us"], ev["end_us"]))
             busy[ev["phase"]] += ev["end_us"] - ev["start_us"]
+        # the compute side: everything outside the codec and the exchange
+        compute_ivs = [iv for p in MODEL_PHASES + ("compute",) for iv in ivs[p]]
         t_start = min(e["start_us"] for e in ex)
         t_end = max(e["end_us"] for e in ex)
         span = {
             "dispatch": i,
             "t_start_us": round(t_start, 3),
             "wall_ms": round((t_end - t_start) / 1e3, 4),
-            "compute_ms": round(busy["compute"] / 1e3, 4),
+            "compute_ms": round(
+                sum(busy[p] for p in MODEL_PHASES + ("compute",)) / 1e3, 4
+            ),
             "phases": {},
         }
         if doc["profile_start_unix_s"] is not None:
@@ -525,15 +685,48 @@ def build_timeline(
             )
         for p in PHASES:
             union = _union_len_us(ivs[p])
-            hidden = _intersect_len_us(ivs[p], ivs["compute"])
+            hidden = _intersect_len_us(ivs[p], compute_ivs)
             span["phases"][p] = {
                 "busy_ms": round(busy[p] / 1e3, 4),
                 "exposed_ms": round((union - hidden) / 1e3, 4),
                 "hidden_ms": round(hidden / 1e3, 4),
             }
+        for p in MODEL_PHASES:
+            span["phases"][p] = {"busy_ms": round(busy[p] / 1e3, 4)}
         spans.append(span)
     doc["spans"] = spans
     doc["n_dispatches"] = len(spans)
+
+    # ---- the loop's host spans, and the device's idle time by span ----
+    hspans = host_spans(space)
+    doc["host_spans"] = [
+        {"name": sp["name"], "step": sp["step"],
+         "t_start_us": round(sp["start_us"], 3),
+         "ms": round((sp["end_us"] - sp["start_us"]) / 1e3, 4)}
+        for sp in hspans
+    ]
+    if executions:
+        # idle on the step's reference device line: nothing of ANY program
+        # runs there, first execution's start to the last one's end
+        busy_by_line: dict = {}
+        for e in events:
+            busy_by_line[e["line"]] = (
+                busy_by_line.get(e["line"], 0.0) + e["end_us"] - e["start_us"]
+            )
+        ref = max(busy_by_line, key=busy_by_line.get)
+        busy_all = [
+            (e["start_us"], e["end_us"])
+            for evs in events_by_pid.values() for e in evs if e["line"] == ref
+        ]
+        lo = min(e["start_us"] for e in executions[0])
+        hi = max(e["end_us"] for e in executions[-1])
+        idle = idle_by_span(busy_all, hspans, lo, hi)
+        doc["device_window_ms"] = round((hi - lo) / 1e3, 4)
+        doc["device_idle_ms"] = round(sum(idle.values()) / 1e3, 4)
+        doc["idle_by_span_ms"] = {
+            k: round(v / 1e3, 4)
+            for k, v in sorted(idle.items(), key=lambda kv: -kv[1])
+        }
 
     # ---- join against metrics.jsonl ---------------------------------
     if train_dir:
@@ -666,11 +859,30 @@ def summarize_timeline(doc: dict) -> str:
             f" (exposed {ph[p]['exposed_ms']}, hidden {ph[p]['hidden_ms']})"
             for p in PHASES
             if ph[p]["busy_ms"] > 0
+        ] + [
+            f"{p} {ph[p]['busy_ms']}ms"
+            for p in MODEL_PHASES
+            if ph.get(p, {}).get("busy_ms", 0) > 0
         ]
         lines.append(
             f"  [dispatch {s['dispatch']}] wall {s['wall_ms']} ms, "
             f"compute {s['compute_ms']} ms"
             + (": " + "; ".join(bits) if bits else " (no phase ops)")
+        )
+    by_name: dict = {}
+    for sp in doc.get("host_spans", []):
+        by_name.setdefault(sp["name"], []).append(sp["ms"])
+    if by_name:
+        lines.append("  host spans (count x median ms): " + "; ".join(
+            f"{n} {len(by_name[n])} x {sorted(by_name[n])[len(by_name[n]) // 2]}"
+            for n in HOST_SPANS if n in by_name
+        ))
+    if doc.get("idle_by_span_ms") is not None:
+        lines.append(
+            f"  device idle {doc['device_idle_ms']} ms of "
+            f"{doc['device_window_ms']} ms, by the span over it: "
+            + ("; ".join(f"{k} {v}" for k, v in doc["idle_by_span_ms"].items())
+               or "none")
         )
     bad = [c["name"] for c in doc.get("checks", []) if not c["ok"]]
     ran = [c for c in doc.get("checks", []) if not c.get("skipped")]

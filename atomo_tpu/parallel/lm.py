@@ -86,26 +86,36 @@ def compressed_dp_update(
     factor gather's codec tax loses to the wire saving
     (utils/comm_model.choose_aggregate)."""
     dense_bytes = tree_nbytes(grads)
+    # the same phase scopes as compressed_dp_exchange: `report timeline`
+    # reads this tail's programs too
     if codec is None:
-        mean_grads = jax.lax.pmean(grads, dp_axis)
+        with named_phase("exchange"):
+            mean_grads = jax.lax.pmean(grads, dp_axis)
         msg_bytes = dense_bytes
     elif aggregate == "psum":
-        payloads, _ = encode_tree(codec, k_codec, grads)
-        decoded = decode_tree(codec, payloads, grads)
-        mean_grads = jax.lax.pmean(decoded, dp_axis)
+        with named_phase("encode"):
+            payloads, _ = encode_tree(codec, k_codec, grads)
+        with named_phase("decode"):
+            decoded = decode_tree(codec, payloads, grads)
+        with named_phase("exchange"):
+            mean_grads = jax.lax.pmean(decoded, dp_axis)
         msg_bytes = dense_bytes  # the wire truly carries dense bytes here
     elif aggregate == "gather":
-        payloads, stats = encode_tree(codec, k_codec, grads)
+        with named_phase("encode"):
+            payloads, stats = encode_tree(codec, k_codec, grads)
         msg_bytes = stats.payload_bytes
-        gathered = jax.lax.all_gather(payloads, dp_axis)
+        with named_phase("exchange"):
+            gathered = jax.lax.all_gather(payloads, dp_axis)
         # fused decode_mean where the codec provides it (SVD: one
         # (m, N·k)@(N·k, n) matmul), vmap-decode + mean otherwise
-        mean_grads = decode_mean_tree(codec, gathered, grads, n_dp)
+        with named_phase("decode_mean"):
+            mean_grads = decode_mean_tree(codec, gathered, grads, n_dp)
     else:
         raise ValueError(f"unknown aggregate mode {aggregate!r}")
 
-    updates, new_opt = optimizer.update(mean_grads, state.opt_state, state.params)
-    new_params = optax.apply_updates(state.params, updates)
+    with named_phase("update"):
+        updates, new_opt = optimizer.update(mean_grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
     metrics = {
         "loss": jax.lax.pmean(loss, dp_axis),
         # float32, not int32: byte counts are static Python ints at trace
@@ -270,8 +280,9 @@ def compressed_dp_exchange(
                         bucket_size=exchange.ring_bucket_size,
                     )
 
-    updates, new_opt = optimizer.update(mean_grads, state.opt_state, state.params)
-    new_params = optax.apply_updates(state.params, updates)
+    with named_phase("update"):
+        updates, new_opt = optimizer.update(mean_grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
     metrics = {
         "loss": jax.lax.pmean(loss, dp_axis),
         # float32, not int32 — same overflow rationale as the legacy tail
@@ -370,8 +381,9 @@ def _delayed_consume(
                 axis=dp_axis, n_dev=n_dp, my=my, n_contrib=n_dp,
                 bucket_size=exchange.ring_bucket_size,
             )
-    updates, new_opt = optimizer.update(mean_grads, opt_state, params)
-    new_params = optax.apply_updates(params, updates)
+    with named_phase("update"):
+        updates, new_opt = optimizer.update(mean_grads, opt_state, params)
+        new_params = optax.apply_updates(params, updates)
     consume_ok = valid > 0  # step 0: nothing in flight -> full skip
     new_params = select_state(consume_ok, new_params, params)
     new_opt = select_state(consume_ok, new_opt, opt_state)
@@ -632,7 +644,8 @@ def make_lm_train_step(
             total = jax.lax.psum(jnp.sum(valid), sp_axis)
             return jax.lax.psum(jnp.sum(ce * valid), sp_axis) / total
 
-        loss, grads = jax.value_and_grad(loss_fn)(state.params)
+        with named_phase("forward_backward"):
+            loss, grads = jax.value_and_grad(loss_fn)(state.params)
         # sp-PMEAN completes THIS replica's gradient (intra-replica, dense).
         # Mean, not sum: under shard_map the transpose of the loss psum is
         # itself a psum, so each shard's per-shard grads already carry an

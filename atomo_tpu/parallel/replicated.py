@@ -4073,17 +4073,17 @@ def _make_phased_step_fn(model, optimizer, mesh, codec, *, augment,
     dense_bytes_cache = {}
 
     def step_fn(state, key, si, sl):
-        from atomo_tpu.utils.tracing import annotate
+        from atomo_tpu.utils.tracing import span
 
         ph = {}
         t0 = _time.perf_counter()
-        with annotate("comp"):
+        with span("comp"):
             grads_x, new_stats, stats = fns["comp"](state, key, si, sl)
             _fence(stats["loss"])
         ph["comp"] = _time.perf_counter() - t0
         if codec is not None:
             t0 = _time.perf_counter()
-            with annotate("encode"):
+            with span("encode"):
                 wire, msg_bytes = fns["encode"](state, key, grads_x)
                 # the int() fetch IS the fence (blocking scalar transfer)
                 msg_bytes = int(msg_bytes)
@@ -4095,12 +4095,12 @@ def _make_phased_step_fn(model, optimizer, mesh, codec, *, augment,
             msg_bytes = dense_bytes_cache["dense"]
             ph["encode"] = 0.0
         t0 = _time.perf_counter()
-        with annotate("gather"):
+        with span("gather"):
             gathered = fns["comm"](wire)
             _fence(gathered)
         ph["gather"] = _time.perf_counter() - t0
         t0 = _time.perf_counter()
-        with annotate("decode_update"):
+        with span("decode_update"):
             state = fns["update"](state, gathered, new_stats)
             _fence(state.params)
         ph["decode"] = _time.perf_counter() - t0
@@ -4124,15 +4124,14 @@ def _distributed_steps(
 
     from atomo_tpu.training.resilience import retrying_saver
     from atomo_tpu.utils.metrics import StepMetrics, master_line
-    from atomo_tpu.utils.tracing import profile
+    from atomo_tpu.utils.tracing import ProfileWindow
 
     save_fn = retrying_saver(log_fn, incidents)
     last_saved = start_step
     t_obs = _time.perf_counter()  # the tuner's step-time series anchor
     t_rec = _time.perf_counter()  # the flight recorder's wall anchor
     # trace steady-state steps only: step 1 is dominated by compilation
-    prof_first = start_step + 2 if profile_dir else None
-    prof_ctx = None
+    prof = ProfileWindow(profile_dir, log_fn, recorder)
     step = start_step
     while step < max_steps:
         step += 1
@@ -4145,20 +4144,8 @@ def _distributed_steps(
                 # the whole step — the honest cost --quorum absorbs
                 # (when a rig is armed IT owns the wait instead)
                 chaos.maybe_sleep_replica(step, mesh.shape["dp"])
-        if prof_first is not None and step == prof_first:
-            prof_ctx = profile(profile_dir)
-            prof_ctx.__enter__()
-            log_fn(f"Profiling steps {step}..{step + profile_steps - 1} -> {profile_dir}")
-            if recorder is not None:
-                # the artifact-side join key for `report timeline`: which
-                # recorded steps the trace window covers (an exact step
-                # range beats reconstructing it from wall-clock overlap)
-                recorder.write_meta({
-                    "what": "profile_window",
-                    "first_step": step,
-                    "last_step": step + profile_steps - 1,
-                    "profile_dir": profile_dir,
-                })
+        if step == start_step + 2:
+            prof.open(step, step + profile_steps - 1)
         images, labels = next(stream)
         si, sl = shard_batch(mesh, images, labels, axis=batch_axes)
         if quorum_rig is not None:
@@ -4170,10 +4157,9 @@ def _distributed_steps(
             out = step_fn(state, key, si, sl, arrivals)
         else:
             out = step_fn(state, key, si, sl)
-        if prof_ctx is not None and step >= prof_first + profile_steps - 1:
+        if prof.ends_at(step):
             jax.block_until_ready(out[0].params)
-            prof_ctx.__exit__(None, None, None)
-            prof_ctx = None
+            prof.close()
         state, metrics = out[0], out[1]
         phases = out[2] if len(out) > 2 else None
         if step == start_step + 1:
@@ -4202,12 +4188,9 @@ def _distributed_steps(
             # single fetch)
             alarm_step, reason = rig.observe(step, metrics)
             if reason is not None:
-                if prof_ctx is not None:
-                    # close the in-flight trace before the timeline jumps;
-                    # leaving it open would crash the replay's re-entry
-                    prof_ctx.__exit__(None, None, None)
-                    prof_ctx = None
-                prof_first = None  # don't double-trace the replayed window
+                # close the in-flight trace before the timeline jumps (a
+                # closed window does not open again on the replay)
+                prof.close()
                 state, stream, step_fn, chaos, step = rig.recover(
                     alarm_step, reason, chaos
                 )
@@ -4343,8 +4326,7 @@ def _distributed_steps(
             rig.note_save(max_steps)
         if chaos is not None:  # ckpt faults target autosaves too
             chaos.maybe_corrupt_checkpoint(path, max_steps)
-    if prof_ctx is not None:  # run shorter than the profiled window
-        prof_ctx.__exit__(None, None, None)
+    prof.close()  # run shorter than the profiled window
     return state
 
 
@@ -4415,7 +4397,7 @@ def _distributed_superstep_steps(
         _chaos_corrupt_range,
         _crossed,
     )
-    from atomo_tpu.utils.tracing import profile
+    from atomo_tpu.utils.tracing import ProfileWindow
 
     import time as _time
 
@@ -4428,7 +4410,7 @@ def _distributed_superstep_steps(
     last_saved = start_step
     last_logged = start_step
     block_idx = 0
-    prof_ctx = None
+    prof = ProfileWindow(profile_dir, log_fn, recorder)
     t_obs = _time.perf_counter()  # the tuner's step-time series anchor
     t_rec = _time.perf_counter()  # the flight recorder's wall anchor
     feed.start(min(superstep, max_steps - s))
@@ -4446,27 +4428,14 @@ def _distributed_superstep_steps(
                 # rejects --superstep > 1): a slow@S:R:SEC straggler
                 # gates every step in the block
                 chaos.maybe_sleep_replica(t, mesh.shape["dp"])
-        if profile_dir and block_idx == 2 and prof_ctx is None:
-            # block 1 is dominated by compilation; trace the second block
-            prof_ctx = profile(profile_dir)
-            prof_ctx.__enter__()
-            log_fn(f"Profiling superstep block {b0 + 1}..{s} -> {profile_dir}")
-            if recorder is not None:
-                # the `report timeline` join key (per-step-loop twin)
-                recorder.write_meta({
-                    "what": "profile_window",
-                    "first_step": b0 + 1,
-                    "last_step": s,
-                    "profile_dir": profile_dir,
-                })
+        if block_idx == 2:  # block 1 is dominated by compilation
+            prof.open(b0 + 1, s, "superstep block")
         state, mblk = step_fn(state, key, dev_im, dev_lb)
         feed.start(min(superstep, max_steps - s))  # overlap next transfer
         m = jax.device_get(mblk)  # the block's ONE host sync
         if block_idx == 1:
             log_fn(placement_line(state, dev_im))
-        if prof_ctx is not None:
-            prof_ctx.__exit__(None, None, None)
-            prof_ctx = None
+        prof.close()
         if monitor is not None:
             monitor.beat(s)
         if recorder is not None:

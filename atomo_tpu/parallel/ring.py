@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from atomo_tpu.utils.tracing import named_phase
+
 
 def _online_softmax_block(q, k_blk, v_blk, bias, m_prev, l_prev, o_prev, scale):
     """One streaming-softmax update: fold a new K/V block into (m, l, o).
@@ -50,6 +52,7 @@ def _online_softmax_block(q, k_blk, v_blk, bias, m_prev, l_prev, o_prev, scale):
     return m_new, l_new, o_new
 
 
+@named_phase("attention")  # the device scope `report timeline` reads
 def ring_attention(
     q: jax.Array,
     k: jax.Array,
@@ -101,6 +104,7 @@ def ring_attention(
     return out.astype(q.dtype)
 
 
+@named_phase("attention")
 def full_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
     scale: Optional[float] = None,
@@ -122,6 +126,7 @@ def full_attention(
     ).astype(q.dtype)
 
 
+@named_phase("attention")
 def blockwise_attention(
     q: jax.Array,
     k: jax.Array,

@@ -32,6 +32,21 @@ from atomo_tpu.codecs import decode_tree, encode_tree
 from atomo_tpu.data.pipeline import augment_batch
 from atomo_tpu.obs.recorder import emit_worker_line
 from atomo_tpu.utils.metrics import StepMetrics, Timer, accuracy
+from atomo_tpu.utils.tracing import (
+    BLOCK,
+    BOUNDARY,
+    DISPATCH,
+    FEED_START,
+    FEED_TAKE,
+    FETCH,
+    NEXT_BATCH,
+    PROFILE_STEPS,
+    STEP,
+    ProfileWindow,
+    named_phase,
+    span,
+)
+from atomo_tpu.utils.tracing import clear as clear_spans
 
 
 @dataclasses.dataclass
@@ -216,9 +231,11 @@ def make_train_step(model, optimizer, codec=None, augment: bool = False,
         k_aug, k_drop, k_codec = jax.random.split(jax.random.fold_in(key, state.step), 3)
         if augment:
             images = augment_batch(k_aug, images)
-        (loss, (logits, new_stats)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(state.params, state.batch_stats, images, labels, k_drop)
+        # device scopes (named_phase): metadata only, read by `report timeline`
+        with named_phase("forward_backward"):
+            (loss, (logits, new_stats)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params, state.batch_stats, images, labels, k_drop)
 
         if chaos is not None:
             grads = chaos.inject_grads(grads, state.step + 1)
@@ -239,22 +256,25 @@ def make_train_step(model, optimizer, codec=None, augment: bool = False,
         msg_bytes = 0
         qm = None
         if codec is not None:
-            payloads, stats = encode_tree(codec, k_codec, grads)
+            with named_phase("encode"):
+                payloads, stats = encode_tree(codec, k_codec, grads)
             if track_quality:
                 from atomo_tpu.obs.quality import quality_probe
 
                 # per-layer ||decode(encode(g)) - g||^2 of THIS encode —
                 # the estimator-variance feed; off adds zero ops
                 qm = quality_probe(codec, payloads, grads)
-            grads = decode_tree(codec, payloads, grads)
+            with named_phase("decode"):
+                grads = decode_tree(codec, payloads, grads)
             msg_bytes = stats.payload_bytes
 
         if remedy is not None:
             from atomo_tpu.training.resilience import apply_remedy
 
             grads = apply_remedy(remedy, state.step, grads)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with named_phase("update"):
+            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         skipped = jnp.float32(0.0)
         if ok is not None:
             new_params = select_state(ok, new_params, state.params)
@@ -354,6 +374,7 @@ def train_loop(
     tuner=None,
     track_quality: bool = False,
     recorder=None,
+    profile_dir: Optional[str] = None,
 ) -> TrainState:
     """The reference train_and_validate loop (nn_ops.py:123-169), jitted,
     plus working checkpoint/resume (gap §5.4) and the fault-tolerance
@@ -409,7 +430,14 @@ def train_loop(
     block), pruned in lockstep with the checkpoint timeline on rollback.
     None (default) adds zero device ops — the programs and the stdout
     log are byte-identical. ``track_quality`` arms the in-graph
-    per-layer estimator-quality probes (see make_train_step)."""
+    per-layer estimator-quality probes (see make_train_step).
+
+    ``profile_dir`` captures a jax.profiler trace of the iterations
+    ``distributed_train_loop`` would (steps start+2..start+4, or the
+    second superstep block): the trace ``report timeline`` reads. Every
+    iteration is also recorded as host spans (utils.tracing.span: parent
+    ``step`` or ``block``, children at the boundaries where the host's
+    work happens), with or without a trace."""
     from atomo_tpu.training.checkpoint import latest_step, load_checkpoint
     from atomo_tpu.training.resilience import (
         SUPERVISED_ENV,
@@ -529,6 +557,7 @@ def train_loop(
         )
     n_train = len(train_iter.dataset)
     last_saved = start_step
+    clear_spans()  # the ring holds this loop's iterations
     if superstep > 1:
         # the watchdog beats once per BLOCK: scale its budget by K so a
         # --health-timeout tuned for per-step beats does not falsely fire
@@ -542,117 +571,137 @@ def train_loop(
                 log_fn, eval_freq, save_freq, train_dir, compress_ckpt,
                 save_fn, monitor, guard=guard, chaos=chaos,
                 keep_ckpts=keep_ckpts, rig=rig, tuner=tuner,
-                recorder=recorder,
+                recorder=recorder, profile_dir=profile_dir,
             )
+    prof = ProfileWindow(profile_dir, log_fn, recorder)
     with heartbeat_watchdog(health_timeout, on_health_failure) as monitor:
         step = start_step
         t_obs = time.perf_counter()  # the tuner's step-time series anchor
         t_rec = time.perf_counter()  # the flight recorder's wall anchor
         while step < max_steps:
             step += 1
-            if chaos is not None:
-                chaos.maybe_die(step)
-                chaos.maybe_sleep(step)
-            images, labels = next(stream)
-            state, metrics = step_fn(state, key, jnp.asarray(images), jnp.asarray(labels))
-            if monitor is not None:
-                jax.block_until_ready(metrics["loss"])
-                monitor.beat(step)
-            if recorder is not None:
-                # one fetch per step — the doctor's surveillance-price
-                # precedent; record BEFORE the doctor observes, so a
-                # diverged step lands in the timeline and the rollback's
-                # prune (checkpoint.prune_after -> prune_metrics_after)
-                # cuts it in lockstep with the checkpoint files
-                m_host = jax.device_get(metrics)
-                now_r = time.perf_counter()
-                recorder.record_block(
-                    step, m_host, wall_s=now_r - t_rec,
-                    drift=tuner.state if tuner is not None else None,
-                    generation=(
-                        rig.doctor.generation if rig is not None else None
-                    ),
-                )
-                t_rec = now_r
-            if rig is not None:
-                # one scalar fetch per step: per-step surveillance is the
-                # price of per-step rollback granularity (the superstep
-                # loop amortizes it into the block's single fetch)
-                alarm_step, reason = rig.observe(step, metrics)
-                if reason is not None:
-                    # raises DivergenceError when the budget is spent
-                    state, stream, step_fn, chaos, step = rig.recover(
-                        alarm_step, reason, chaos
-                    )
-                    last_saved = min(last_saved, step)
-                    # recovery wall is not step time: restamp the tuner's
-                    # anchor or it pollutes the next drift observation
-                    t_obs = time.perf_counter()
-                    t_rec = time.perf_counter()
-                    continue
-                new_fn = rig.maybe_end_densify(step)
-                if new_fn is not None:
-                    step_fn = new_fn
-            if tuner is not None:
-                # fence before stamping (async dispatch would time the
-                # enqueue); one fetch per step, only while armed
-                float(metrics["loss"])
-                now = time.perf_counter()
-                tuner.observe(now - t_obs)
-                t_obs = now
-            # guard diagnostics share the log cadence: fetching the skip
-            # flag every step would block host dispatch on every step's
-            # result even when nothing is ever dropped
-            if (
-                guard is not None
-                and log_every and step % log_every == 0
-                and float(metrics["skipped"]) > 0
-            ):
-                log_fn(
-                    f"Guard: Step: {step}, Dropped: 1/1, Action: skip "
-                    "(anomalous gradient; params/opt state held)"
-                )
-            if log_every and step % log_every == 0:
-                rec = StepMetrics(
-                    rank=0,
-                    step=step,
-                    epoch=step * train_iter.batch_size // max(n_train, 1),
-                    samples_seen=(step * train_iter.batch_size) % max(n_train, 1),
-                    dataset_size=n_train,
-                    loss=float(metrics["loss"]),
-                    time_cost=timer.lap(),
-                    msg_bytes=int(metrics["msg_bytes"]),
-                    prec1=float(metrics["prec1"]),
-                    prec5=float(metrics["prec5"]),
-                )
-                emit_worker_line(recorder, rec, log_fn)
-            if eval_freq and test_iter is not None and step % eval_freq == 0:
-                ev = evaluate(model, state, test_iter)
-                log_fn(
-                    "Validation: Step: {}, Loss: {:.4f}, Prec@1: {:.4f}, Prec@5: {:.4f}".format(
-                        step, ev["loss"], ev["prec1"], ev["prec5"]
-                    )
-                )
-            if save_freq and train_dir and step % save_freq == 0:
-                path = save_fn(
-                    train_dir, state, step, compress=compress_ckpt,
-                    keep=keep_ckpts,
-                )
-                last_saved = step
-                if rig is not None:
-                    rig.note_save(step)
+            with span(STEP, step):
                 if chaos is not None:
-                    chaos.maybe_corrupt_checkpoint(path, step)
-                if tuner is not None:
-                    # observe-only on one device: records the drift
-                    # incident at the boundary, keeps the config
-                    tuner.maybe_retune(step, "local")
-            if tuner is not None:
-                # restamp after boundary work (eval/save): cadence costs
-                # must not enter the drift baseline
-                t_obs = time.perf_counter()
-            if recorder is not None:
-                t_rec = time.perf_counter()  # same boundary-work rule
+                    chaos.maybe_die(step)
+                    chaos.maybe_sleep(step)
+                if step == start_step + 2:  # step 1 is dominated by compilation
+                    prof.open(step, step + PROFILE_STEPS - 1)
+                with span(NEXT_BATCH):
+                    images, labels = next(stream)
+                    images, labels = jnp.asarray(images), jnp.asarray(labels)
+                with span(DISPATCH):
+                    state, metrics = step_fn(state, key, images, labels)
+                log_due = bool(log_every) and step % log_every == 0
+                if (
+                    log_due or monitor is not None or recorder is not None
+                    or rig is not None or tuner is not None
+                ):
+                    # the iteration's one wait on the device: whatever reads
+                    # the metrics below finds them ready, and the span's end
+                    # is a fenced stamp with a step number. Nothing armed and
+                    # no line due: no fetch, dispatch runs ahead as before
+                    with span(FETCH):
+                        jax.block_until_ready(metrics["loss"])
+                if prof.ends_at(step):
+                    jax.block_until_ready(metrics["loss"])
+                    prof.close()
+                with span(BOUNDARY):
+                    if monitor is not None:
+                        monitor.beat(step)
+                    if recorder is not None:
+                        # one fetch per step — the doctor's surveillance-price
+                        # precedent; record BEFORE the doctor observes, so a
+                        # diverged step lands in the timeline and the rollback's
+                        # prune (checkpoint.prune_after -> prune_metrics_after)
+                        # cuts it in lockstep with the checkpoint files
+                        m_host = jax.device_get(metrics)
+                        now_r = time.perf_counter()
+                        recorder.record_block(
+                            step, m_host, wall_s=now_r - t_rec,
+                            drift=tuner.state if tuner is not None else None,
+                            generation=(
+                                rig.doctor.generation if rig is not None else None
+                            ),
+                        )
+                        t_rec = now_r
+                    if rig is not None:
+                        # one scalar fetch per step: per-step surveillance is the
+                        # price of per-step rollback granularity (the superstep
+                        # loop amortizes it into the block's single fetch)
+                        alarm_step, reason = rig.observe(step, metrics)
+                        if reason is not None:
+                            # raises DivergenceError when the budget is spent
+                            state, stream, step_fn, chaos, step = rig.recover(
+                                alarm_step, reason, chaos
+                            )
+                            last_saved = min(last_saved, step)
+                            # recovery wall is not step time: restamp the tuner's
+                            # anchor or it pollutes the next drift observation
+                            t_obs = time.perf_counter()
+                            t_rec = time.perf_counter()
+                            continue
+                        new_fn = rig.maybe_end_densify(step)
+                        if new_fn is not None:
+                            step_fn = new_fn
+                    if tuner is not None:
+                        # stamped behind the fetch span's fence (async
+                        # dispatch would time the enqueue)
+                        now = time.perf_counter()
+                        tuner.observe(now - t_obs)
+                        t_obs = now
+                    # guard diagnostics share the log cadence: fetching the skip
+                    # flag every step would block host dispatch on every step's
+                    # result even when nothing is ever dropped
+                    if (
+                        guard is not None and log_due
+                        and float(metrics["skipped"]) > 0
+                    ):
+                        log_fn(
+                            f"Guard: Step: {step}, Dropped: 1/1, Action: skip "
+                            "(anomalous gradient; params/opt state held)"
+                        )
+                    if log_due:
+                        rec = StepMetrics(
+                            rank=0,
+                            step=step,
+                            epoch=step * train_iter.batch_size // max(n_train, 1),
+                            samples_seen=(step * train_iter.batch_size) % max(n_train, 1),
+                            dataset_size=n_train,
+                            loss=float(metrics["loss"]),
+                            time_cost=timer.lap(),
+                            msg_bytes=int(metrics["msg_bytes"]),
+                            prec1=float(metrics["prec1"]),
+                            prec5=float(metrics["prec5"]),
+                        )
+                        emit_worker_line(recorder, rec, log_fn)
+                    if eval_freq and test_iter is not None and step % eval_freq == 0:
+                        ev = evaluate(model, state, test_iter)
+                        log_fn(
+                            "Validation: Step: {}, Loss: {:.4f}, Prec@1: {:.4f}, Prec@5: {:.4f}".format(
+                                step, ev["loss"], ev["prec1"], ev["prec5"]
+                            )
+                        )
+                    if save_freq and train_dir and step % save_freq == 0:
+                        path = save_fn(
+                            train_dir, state, step, compress=compress_ckpt,
+                            keep=keep_ckpts,
+                        )
+                        last_saved = step
+                        if rig is not None:
+                            rig.note_save(step)
+                        if chaos is not None:
+                            chaos.maybe_corrupt_checkpoint(path, step)
+                        if tuner is not None:
+                            # observe-only on one device: records the drift
+                            # incident at the boundary, keeps the config
+                            tuner.maybe_retune(step, "local")
+                    if tuner is not None:
+                        # restamp after boundary work (eval/save): cadence costs
+                        # must not enter the drift baseline
+                        t_obs = time.perf_counter()
+                    if recorder is not None:
+                        t_rec = time.perf_counter()  # same boundary-work rule
+        prof.close()  # a run shorter than the profiled window
         # autosave the final state so a restart never replays the tail
         # (strictly `<`: a resume past max_steps runs no steps and must not
         # write a file whose name disagrees with the state's step field)
@@ -713,7 +762,7 @@ def _superstep_steps(
     n_train, start_step, max_steps, superstep, log_every, log_fn,
     eval_freq, save_freq, train_dir, compress_ckpt, save_fn, monitor,
     guard=None, chaos=None, keep_ckpts=0, rig=None, tuner=None,
-    recorder=None,
+    recorder=None, profile_dir=None,
 ):
     """train_loop's fused block path: one dispatch per K steps, one metric
     fetch per block (the fetch is also the fence the watchdog beats on),
@@ -737,101 +786,113 @@ def _superstep_steps(
     t_obs = time.perf_counter()  # the tuner's step-time series anchor
     t_rec = time.perf_counter()  # the flight recorder's wall anchor
     feed.start(min(superstep, max_steps - s))
+    prof = ProfileWindow(profile_dir, log_fn, recorder)
+    block_idx = 0
     while s < max_steps:
-        kb, dev_im, dev_lb = feed.take()
-        b0, s = s, s + kb
-        if chaos is not None:
-            # host faults resolve at the block boundary: the block is ONE
-            # dispatch, so a kill/sleep aimed at any step it covers fires
-            # before the block runs (none of its steps have executed yet
-            # — the checkpoint/resume contract is preserved)
-            for t in range(b0 + 1, s + 1):
-                chaos.maybe_die(t)
-                chaos.maybe_sleep(t)
-        state, mblk = step_fn(state, key, dev_im, dev_lb)
-        # enqueue the NEXT block's host->device transfer while the current
-        # superstep executes (async dispatch above returns immediately)
-        feed.start(min(superstep, max_steps - s))
-        m = jax.device_get(mblk)  # the block's ONE host sync
-        if monitor is not None:
-            monitor.beat(s)
-        if recorder is not None:
-            # rides the block's one fetch (zero extra device ops); the
-            # block wall becomes kb equal per-step shares — the drift
-            # detector's partition-consistency convention. Recorded
-            # BEFORE the doctor observes: a diverged block lands in the
-            # timeline and the rollback prune cuts it in lockstep.
-            now_r = time.perf_counter()
-            recorder.record_block(
-                b0 + 1, m, wall_s=now_r - t_rec,
-                drift=tuner.state if tuner is not None else None,
-                generation=(
-                    rig.doctor.generation if rig is not None else None
-                ),
-            )
-            t_rec = now_r
-        if rig is not None:
-            alarm_step, reason = rig.observe(b0 + 1, m)
-            if reason is not None:
-                state, stream, step_fn, chaos, s = rig.recover(
-                    alarm_step, reason, chaos
-                )
-                last_saved = min(last_saved, s)
-                last_logged = min(last_logged, s)
-                # drop the feed's staged lookahead block: it belongs to
-                # the discarded timeline
-                feed = SuperstepFeed(BlockStream(stream), put_fn)
+        with span(BLOCK, s + min(superstep, max_steps - s)):
+            with span(FEED_TAKE):
+                kb, dev_im, dev_lb = feed.take()
+            b0, s = s, s + kb
+            if chaos is not None:
+                # host faults resolve at the block boundary: the block is ONE
+                # dispatch, so a kill/sleep aimed at any step it covers fires
+                # before the block runs (none of its steps have executed yet
+                # — the checkpoint/resume contract is preserved)
+                for t in range(b0 + 1, s + 1):
+                    chaos.maybe_die(t)
+                    chaos.maybe_sleep(t)
+            block_idx += 1
+            if block_idx == 2:  # block 1 is dominated by compilation
+                prof.open(b0 + 1, s, "superstep block")
+            with span(DISPATCH):
+                state, mblk = step_fn(state, key, dev_im, dev_lb)
+            # enqueue the NEXT block's host->device transfer while the current
+            # superstep executes (async dispatch above returns immediately)
+            with span(FEED_START):
                 feed.start(min(superstep, max_steps - s))
-                # recovery wall is not step time: restamp the tuner anchor
-                t_obs = time.perf_counter()
-                t_rec = time.perf_counter()
-                continue
-            new_fn = rig.maybe_end_densify(s)
-            if new_fn is not None:
-                step_fn = new_fn
-        if tuner is not None:
-            # the block's wall as kb equal per-step shares (the
-            # device_get above already fenced the dispatch): one mean
-            # per block would make the detector K-times less sensitive
-            # than the per-step loop — partition consistency
-            kb_n = max(kb, 1)
-            tuner.observe([(time.perf_counter() - t_obs) / kb_n] * kb_n)
-        n_skipped = float(np.sum(m["skipped"])) if guard is not None else 0.0
-        if guard is not None and _crossed(log_every, b0, s) and n_skipped > 0:
-            log_fn(
-                f"Guard: Step: {s}, Dropped: {int(n_skipped)}/{kb}, "
-                "Action: skip (anomalous gradient inside the superstep; "
-                "params/opt state held for those steps)"
-            )
-        if _crossed(log_every, b0, s):
-            rec = _block_log_record(
-                s, m, train_iter, n_train, timer.lap(), last_logged
-            )
-            last_logged = s
-            emit_worker_line(recorder, rec, log_fn)
-        if eval_freq and test_iter is not None and _crossed(eval_freq, b0, s):
-            ev = evaluate(model, state, test_iter)
-            log_fn(
-                "Validation: Step: {}, Loss: {:.4f}, Prec@1: {:.4f}, Prec@5: {:.4f}".format(
-                    s, ev["loss"], ev["prec1"], ev["prec5"]
-                )
-            )
-        if save_freq and train_dir and _crossed(save_freq, b0, s):
-            path = save_fn(
-                train_dir, state, s, compress=compress_ckpt, keep=keep_ckpts
-            )
-            last_saved = s
-            if rig is not None:
-                rig.note_save(s)
-            # ckpt faults snap like kill/sleep: a fault aimed anywhere in
-            # this block corrupts the boundary file
-            _chaos_corrupt_range(chaos, path, b0, s)
-            if tuner is not None:
-                tuner.maybe_retune(s, "local")  # observe-only on 1 device
-        if tuner is not None:
-            t_obs = time.perf_counter()  # boundary work is not step time
-        if recorder is not None:
-            t_rec = time.perf_counter()  # same boundary-work rule
+            with span(FETCH):
+                m = jax.device_get(mblk)  # the block's ONE host sync
+            prof.close()
+            with span(BOUNDARY):
+                if monitor is not None:
+                    monitor.beat(s)
+                if recorder is not None:
+                    # rides the block's one fetch (zero extra device ops); the
+                    # block wall becomes kb equal per-step shares — the drift
+                    # detector's partition-consistency convention. Recorded
+                    # BEFORE the doctor observes: a diverged block lands in the
+                    # timeline and the rollback prune cuts it in lockstep.
+                    now_r = time.perf_counter()
+                    recorder.record_block(
+                        b0 + 1, m, wall_s=now_r - t_rec,
+                        drift=tuner.state if tuner is not None else None,
+                        generation=(
+                            rig.doctor.generation if rig is not None else None
+                        ),
+                    )
+                    t_rec = now_r
+                if rig is not None:
+                    alarm_step, reason = rig.observe(b0 + 1, m)
+                    if reason is not None:
+                        state, stream, step_fn, chaos, s = rig.recover(
+                            alarm_step, reason, chaos
+                        )
+                        last_saved = min(last_saved, s)
+                        last_logged = min(last_logged, s)
+                        # drop the feed's staged lookahead block: it belongs to
+                        # the discarded timeline
+                        feed = SuperstepFeed(BlockStream(stream), put_fn)
+                        feed.start(min(superstep, max_steps - s))
+                        # recovery wall is not step time: restamp the tuner anchor
+                        t_obs = time.perf_counter()
+                        t_rec = time.perf_counter()
+                        continue
+                    new_fn = rig.maybe_end_densify(s)
+                    if new_fn is not None:
+                        step_fn = new_fn
+                if tuner is not None:
+                    # the block's wall as kb equal per-step shares (the
+                    # device_get above already fenced the dispatch): one mean
+                    # per block would make the detector K-times less sensitive
+                    # than the per-step loop — partition consistency
+                    kb_n = max(kb, 1)
+                    tuner.observe([(time.perf_counter() - t_obs) / kb_n] * kb_n)
+                n_skipped = float(np.sum(m["skipped"])) if guard is not None else 0.0
+                if guard is not None and _crossed(log_every, b0, s) and n_skipped > 0:
+                    log_fn(
+                        f"Guard: Step: {s}, Dropped: {int(n_skipped)}/{kb}, "
+                        "Action: skip (anomalous gradient inside the superstep; "
+                        "params/opt state held for those steps)"
+                    )
+                if _crossed(log_every, b0, s):
+                    rec = _block_log_record(
+                        s, m, train_iter, n_train, timer.lap(), last_logged
+                    )
+                    last_logged = s
+                    emit_worker_line(recorder, rec, log_fn)
+                if eval_freq and test_iter is not None and _crossed(eval_freq, b0, s):
+                    ev = evaluate(model, state, test_iter)
+                    log_fn(
+                        "Validation: Step: {}, Loss: {:.4f}, Prec@1: {:.4f}, Prec@5: {:.4f}".format(
+                            s, ev["loss"], ev["prec1"], ev["prec5"]
+                        )
+                    )
+                if save_freq and train_dir and _crossed(save_freq, b0, s):
+                    path = save_fn(
+                        train_dir, state, s, compress=compress_ckpt, keep=keep_ckpts
+                    )
+                    last_saved = s
+                    if rig is not None:
+                        rig.note_save(s)
+                    # ckpt faults snap like kill/sleep: a fault aimed anywhere in
+                    # this block corrupts the boundary file
+                    _chaos_corrupt_range(chaos, path, b0, s)
+                    if tuner is not None:
+                        tuner.maybe_retune(s, "local")  # observe-only on 1 device
+                if tuner is not None:
+                    t_obs = time.perf_counter()  # boundary work is not step time
+                if recorder is not None:
+                    t_rec = time.perf_counter()  # same boundary-work rule
     # autosave the final state so a restart never replays the tail (same
     # strictly-< contract as the per-step loop)
     if save_freq and train_dir and last_saved < max_steps:

@@ -6,14 +6,21 @@ master prints Gather/Decode (src/sync_replicas_master_nn.py:197-221), and the
 log line is the metrics API. Under XLA those phases fuse into one compiled
 program, so wall-clock phase spans are replaced by:
 
-  * ``span(name)``        — host-side wall spans (dispatch+block), kept for
-                            the loop-level phases that still exist on host
-                            (data load, checkpoint IO).
+  * ``span(name, step)``  — the ONE host-span primitive: a
+                            jax.profiler TraceAnnotation (so the span sits
+                            on the device trace's clock whenever a profiler
+                            session runs) plus one flat record on
+                            time.perf_counter in a bounded in-process ring
+                            (``spans()``), always on. The loops' vocabulary
+                            (block / step > feed_take, dispatch, feed_start
+                            > stack, put, next_batch, fetch, boundary) is
+                            the constants below.
+  * ``named_phase(name)`` — jax.named_scope: the device-side half, labels
+                            the ops traced under it inside the compiled
+                            step.
   * ``profile(dir)``      — a jax.profiler trace capturing device timelines
                             (the honest way to see encode/decode cost inside
                             the fused step).
-  * ``annotate(name)``    — TraceAnnotation so named regions show up inside
-                            profiler timelines.
   * ``StepTimer``         — per-step host timing with a trailing-window
                             summary, feeding StepMetrics.time_cost.
   * ``IncidentLog``       — the robustness stack's machine-readable
@@ -57,41 +64,112 @@ PHASE_METRICS_HINT = (
 )
 
 
-@contextlib.contextmanager
-def span(name: str, sink: Optional[dict] = None) -> Iterator[None]:
-    """Wall-clock span; records seconds into ``sink[name]`` if given."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        if sink is not None:
-            sink[name] = sink.get(name, 0.0) + dt
+# The span vocabulary of the training loops (PERF.md §3). A parent span is
+# one iteration: a superstep block, or one step of a per-step loop; its
+# children are the boundaries where the host's work happens, and nothing
+# finer. Every loop takes these names, so one reader serves them all.
+BLOCK = "block"  # parent: one iteration of the superstep loop (K optimizer steps)
+STEP = "step"  # parent: one iteration of a per-step loop
+FEED_TAKE = "feed_take"  # taking the block the feed staged
+DISPATCH = "dispatch"  # the jitted step's call, until it returns to the host
+FEED_START = "feed_start"  # staging the next block; children STACK and PUT
+STACK = "stack"  # stacking K host batches into one block
+PUT = "put"  # jnp.asarray + device_put of the block (returns once enqueued)
+NEXT_BATCH = "next_batch"  # producing and placing one batch (per-step loops)
+FETCH = "fetch"  # the host waiting on the device, and the result coming back
+BOUNDARY = "boundary"  # everything after the fetch: recorder, doctor, log, eval, save
+PARENT_SPANS = (BLOCK, STEP)
+
+# (name, step, parent, t0, t1) on time.perf_counter; str/int/float only, so
+# the garbage collector untracks each record on its first pass and the ring
+# adds nothing to walk. A few thousand iterations of at most 8 spans.
+RING_RECORDS = 32768
+_ring: collections.deque = collections.deque(maxlen=RING_RECORDS)
+_open: list = []  # (name, step) of the spans now open; the loops run on one host thread
+_profiler = None  # jax.profiler once a span has looked for it; False where there is none
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region inside jax.profiler device traces (no-op without jax)."""
-    try:
-        import jax.profiler
+def _annotation(name: str, step):
+    global _profiler
+    if _profiler is None:
+        try:
+            import jax.profiler as _profiler
+        except Exception:  # no jax: the ring alone
+            _profiler = False
+    if not _profiler:
+        return contextlib.nullcontext()
+    if step is None:
+        return _profiler.TraceAnnotation(name)
+    if name in PARENT_SPANS:  # XProf's step tools key on this one
+        return _profiler.StepTraceAnnotation(name, step_num=step)
+    return _profiler.TraceAnnotation(name, step=step)
 
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    except Exception:
-        yield
+
+class span:
+    """Host span ``name`` of iteration ``step`` (the optimizer step the
+    iteration's dispatch ends on; a child without one takes its parent's),
+    recorded on two clocks at once: as a jax.profiler annotation, which
+    costs a flag check while no profiler session runs and lands on the
+    device trace's clock while one does, and as one flat record in the
+    module's bounded ring on time.perf_counter (:func:`spans`). There is
+    no switch: two clock reads and one append per span. The record is
+    written on the way out whatever the body raised (a BaseException
+    too: the benchmark closes its window by raising through the log
+    line), and the exception goes on."""
+
+    __slots__ = ("name", "step", "_parent", "_annotation", "_t0")
+
+    def __init__(self, name: str, step: Optional[int] = None):
+        self.name, self.step = name, step
+
+    def __enter__(self):
+        parent, parent_step = _open[-1] if _open else (None, None)
+        if self.step is None:
+            self.step = parent_step
+        self._parent = parent
+        self._annotation = _annotation(self.name, self.step)
+        self._annotation.__enter__()
+        _open.append((self.name, self.step))
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        try:
+            self._annotation.__exit__(exc_type, exc, tb)
+        finally:
+            _open.pop()
+            _ring.append((self.name, self.step, self._parent, self._t0, t1))
+        return False
+
+
+def spans() -> list[tuple]:
+    """The ring's records, oldest first: ``(name, step, parent, t0, t1)``
+    with the times on time.perf_counter. A child closes before its parent,
+    so it comes first."""
+    return list(_ring)
+
+
+def clear() -> None:
+    """Empty the ring (a loop's start, tests)."""
+    _ring.clear()
 
 
 @contextlib.contextmanager
 def named_phase(name: str) -> Iterator[None]:
-    """Name a TRACED region (jax.named_scope): unlike :func:`span`/
-    :func:`annotate`, which mark host wall-time, this labels the ops traced
+    """Name a TRACED region (jax.named_scope): unlike :func:`span`,
+    which marks host wall-time, this labels the ops traced
     under it so the phase survives INTO the compiled program — XLA HLO op
     names and jax.profiler device timelines show ``encode``/``exchange``/
     ``decode_mean``/``ring_exchange_decode`` regions inside the fused step,
     which is the only place the fused step's phase costs are visible
-    (host spans cannot cut a single XLA program). Used by the aggregation
-    paths in parallel/replicated.py and reported per-phase by bench.py's
-    ring-vs-gather comparison row. No-op when jax lacks named_scope.
+    (host spans cannot cut a single XLA program). Planted in the
+    aggregation paths (parallel/replicated.py, parallel/lm.py), around
+    ``forward_backward`` / ``encode`` / ``decode`` / ``update`` in the
+    single-device step (training/trainer.py) and the lm step, and around
+    ``attention`` (parallel/ring.py); read by ``report timeline``
+    (obs/timeline.py PHASE_OF_SCOPE). Metadata only: the compiled program
+    does not change. No-op when jax lacks named_scope.
 
     The scope ACQUISITION alone is guarded; the body's ``yield`` stays
     outside any try/except — a bare ``except: yield`` would swallow
@@ -133,14 +211,67 @@ def fence_tree(tree) -> float:
 
 @contextlib.contextmanager
 def profile(log_dir: str) -> Iterator[None]:
-    """Capture a jax.profiler trace (TensorBoard-loadable) around a block."""
+    """Capture a jax.profiler trace (TensorBoard-loadable) around a block.
+    Python's own tracer is off: it slows the host's work inside the
+    capture (a dispatch of GPT-2 medium read 4.8 ms under it on the v5e),
+    and the loops' spans (:class:`span`) already name the host side."""
     import jax.profiler
 
-    jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+PROFILE_STEPS = 3  # steps a per-step loop captures under --profile-dir
+
+
+class ProfileWindow:
+    """``--profile-dir`` for the one-device loops: one :func:`profile`
+    capture of a few steady-state iterations, opened and closed by the
+    loop at the iterations ``distributed_train_loop`` captures (steps
+    start+2..start+4 of a per-step loop, the second block of a superstep
+    loop), with its log line and its ``profile_window`` record (the
+    artifact-side join key of ``report timeline``). Without a directory
+    every method returns at once."""
+
+    def __init__(self, profile_dir: Optional[str], log_fn=print, recorder=None):
+        self.profile_dir, self.log_fn, self.recorder = profile_dir, log_fn, recorder
+        self.last_step: Optional[int] = None  # of the open window
+        self._ctx = None
+        self._done = False
+
+    def ends_at(self, step: int) -> bool:
+        """True while a capture is open whose last step ``step`` reaches:
+        the caller fences that step's dispatch, then calls :meth:`close`."""
+        return self._ctx is not None and step >= self.last_step
+
+    def open(self, first_step: int, last_step: int, what: str = "steps") -> None:
+        if not self.profile_dir or self._done or self._ctx is not None:
+            return
+        self._ctx = profile(self.profile_dir)
+        self._ctx.__enter__()
+        self.last_step = last_step
+        self.log_fn(
+            f"Profiling {what} {first_step}..{last_step} -> {self.profile_dir}"
+        )
+        if self.recorder is not None:
+            self.recorder.write_meta({
+                "what": "profile_window",
+                "first_step": first_step,
+                "last_step": last_step,
+                "profile_dir": self.profile_dir,
+            })
+
+    def close(self) -> None:
+        """Stop the capture; the caller has fenced the window's last
+        dispatch, so the trace holds all of it."""
+        if self._ctx is not None:
+            ctx, self._ctx, self._done = self._ctx, None, True
+            ctx.__exit__(None, None, None)
 
 
 def write_json_atomic(path: str, obj) -> None:

@@ -9,19 +9,25 @@ import pytest
 
 from atomo_tpu.parallel.launch import HealthMonitor, global_mesh, initialize
 from atomo_tpu.training import make_optimizer, stepwise_shrink
-from atomo_tpu.utils.tracing import StepTimer, annotate, span
+from atomo_tpu.utils.tracing import StepTimer, clear, span, spans
 
 
-def test_span_records_into_sink():
-    sink = {}
-    with span("io", sink):
+def test_span_records_into_the_ring():
+    clear()
+    with span("io", 7):
         time.sleep(0.01)
-    assert sink["io"] >= 0.01
+    (name, step, parent, t0, t1), = spans()
+    assert (name, step, parent) == ("io", 7, None) and t1 - t0 >= 0.01
 
 
-def test_annotate_is_safe_anywhere():
-    with annotate("region"):
-        pass
+def test_span_is_safe_anywhere():
+    """Outside any loop and any profiler session, with or without a step:
+    the annotation half is a flag check, the ring half always records."""
+    clear()
+    with span("region"):
+        with span("inner", 2):
+            pass
+    assert [(r[0], r[1], r[2]) for r in spans()] == [("inner", 2, "region"), ("region", None, None)]
 
 
 def test_step_timer_stats():
