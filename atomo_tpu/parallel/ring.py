@@ -16,6 +16,12 @@ per-chip HBM.
 
 All shapes are static; the rotation loop is a ``lax.fori_loop`` (compiler-
 friendly control flow, no Python unrolling at large n).
+
+Precision follows the dtype q, k and v arrive in: the contractions, forward
+and backward, take operands of that dtype and accumulate in float32 (one MXU
+pass for bfloat16, ``Precision.HIGHEST`` for float32); scores, row maximum,
+exponentials and row sums are float32 always. Of score size the backward
+pass keeps the exponentials alone, rounded to that dtype (``_softmax_block``).
 """
 
 from __future__ import annotations
@@ -30,26 +36,92 @@ from jax.sharding import Mesh, PartitionSpec as P
 from atomo_tpu.utils.tracing import named_phase
 
 
-def _online_softmax_block(q, k_blk, v_blk, bias, m_prev, l_prev, o_prev, scale):
-    """One streaming-softmax update: fold a new K/V block into (m, l, o).
-
-    q: (B, H, Sq, D); k_blk/v_blk: (B, H, Sk, D); bias: (Sq, Sk) additive
-    mask (-inf for masked); m/l: (B, H, Sq); o: (B, H, Sq, D).
-    """
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k_blk, precision=jax.lax.Precision.HIGHEST)
-    s = s * scale + bias[None, None, :, :]
-    m_cur = jnp.max(s, axis=-1)
-    m_new = jnp.maximum(m_prev, m_cur)
-    # guard -inf (fully masked rows) against NaN in exp(m_prev - m_new)
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe[..., None])
-    p = jnp.where(jnp.isfinite(s), p, 0.0)
-    alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-    o_new = o_prev * alpha[..., None] + jnp.einsum(
-        "bhqk,bhkd->bhqd", p, v_blk, precision=jax.lax.Precision.HIGHEST
+def _dot(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """One contraction of the core, operands as they are, float32 result:
+    a single MXU pass for bfloat16, ``Precision.HIGHEST`` for float32."""
+    precision = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jnp.einsum(
+        spec, a, b, precision=precision, preferred_element_type=jnp.float32
     )
-    return m_new, l_new, o_new
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _softmax_block(q, k_blk, v_blk, bias, m_prev, scale):
+    """Scores, row maximum and exponentials of one K/V block, and the
+    exponentials times the values: returns (m, l, o), unnormalised.
+
+    q: (B, H, Sq, D); k_blk/v_blk: (B, H, Sk, D), all of one dtype; bias:
+    (Sq, Sk) additive mask (-inf for masked) or None; m_prev: (B, H, Sq)
+    running maximum to take the exponentials against (online form, with the
+    guards for rows that are fully masked so far), or None for a first and
+    only block. m and l are float32, o is float32 of shape (B, H, Sq, D).
+
+    The maximum is a constant to autodiff (the attention built from
+    (m, l, o) does not depend on it), so the backward pass needs q, k, v
+    and the exponentials alone, kept in the operands' dtype.
+    """
+    return _softmax_block_fwd(q, k_blk, v_blk, bias, m_prev, scale)[0]
+
+
+def _softmax_block_fwd(q, k_blk, v_blk, bias, m_prev, scale):
+    s = _dot("bhqd,bhkd->bhqk", q, k_blk) * scale
+    if bias is not None:
+        s = s + bias[None, None, :, :]
+    m = jnp.max(s, axis=-1)
+    if m_prev is None:
+        p = jnp.exp(s - m[..., None])
+    else:
+        m = jnp.maximum(m_prev, m)
+        # guard -inf (fully masked rows) against NaN in exp(-inf - -inf)
+        m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
+        p = jnp.exp(s - m_safe[..., None])
+        p = jnp.where(jnp.isfinite(s), p, 0.0)
+    l = jnp.sum(p, axis=-1)
+    p = p.astype(v_blk.dtype)  # rounded once, where it becomes an operand
+    o = _dot("bhqk,bhkd->bhqd", p, v_blk)
+    return (m, l, o), (q, k_blk, v_blk, p)
+
+
+def _softmax_block_bwd(scale, res, cts):
+    q, k_blk, v_blk, p = res
+    _, dl, do = cts  # m carries no gradient
+    do = do.astype(v_blk.dtype)
+    dv = _dot("bhqk,bhqd->bhkd", p, do)
+    dp = _dot("bhqd,bhkd->bhqk", do, v_blk)
+    ds = (p * (dp + dl[..., None]) * scale).astype(q.dtype)
+    dq = _dot("bhqk,bhkd->bhqd", ds, k_blk)
+    dk = _dot("bhqk,bhqd->bhkd", ds, q)
+    return (
+        dq.astype(q.dtype), dk.astype(k_blk.dtype), dv.astype(v_blk.dtype),
+        None, None,
+    )
+
+
+_softmax_block.defvjp(_softmax_block_fwd, _softmax_block_bwd)
+
+
+def _online_softmax_block(q, k_blk, v_blk, bias, m_prev, l_prev, o_prev, scale):
+    """One streaming-softmax update: fold a new K/V block into (m, l, o),
+    all three float32."""
+    m_new, l_blk, o_blk = _softmax_block(q, k_blk, v_blk, bias, m_prev, scale)
+    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
+    return m_new, l_prev * alpha + l_blk, o_prev * alpha[..., None] + o_blk
+
+
+def _one_block_attention(q, k, v, bias, scale):
+    """Plain masked softmax attention: one block, nothing to rescale."""
+    _, l, o = _softmax_block(q, k, v, bias, None, scale)
+    return (o / l[..., None]).astype(q.dtype)
+
+
+def _causal_bias(q_pos, k_pos):
+    return jnp.where(q_pos[:, None] >= k_pos[None, :], 0.0, jnp.float32(-jnp.inf))
+
+
+def _common_dtype(q, k, v):
+    dtype = jnp.result_type(q, k, v)
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype)
 
 
 @named_phase("attention")  # the device scope `report timeline` reads
@@ -72,6 +144,12 @@ def ring_attention(
     b, h, s_local, d = q.shape
     if scale is None:
         scale = 1.0 / (d**0.5)
+    q, k, v = _common_dtype(q, k, v)
+    if axis_size == 1:  # one K/V block: no rotation, nothing to rescale
+        pos = jnp.arange(s_local)
+        return _one_block_attention(
+            q, k, v, _causal_bias(pos, pos) if causal else None, scale
+        )
     my = jax.lax.axis_index(axis_name)
 
     neg = jnp.float32(-jnp.inf)
@@ -83,7 +161,7 @@ def ring_attention(
         src = (my + t) % axis_size
         k_pos = src * s_local + jnp.arange(s_local)
         if causal:
-            bias = jnp.where(q_pos[:, None] >= k_pos[None, :], 0.0, neg)
+            bias = _causal_bias(q_pos, k_pos)
         else:
             bias = jnp.zeros((s_local, s_local), jnp.float32)
         m, l, o = _online_softmax_block(q, k_blk, v_blk, bias, m, l, o, scale)
@@ -97,9 +175,7 @@ def ring_attention(
     m0 = jnp.full((b, h, s_local), neg, jnp.float32)
     l0 = jnp.zeros((b, h, s_local), jnp.float32)
     o0 = jnp.zeros((b, h, s_local, d), jnp.float32)
-    _, _, m, l, o = jax.lax.fori_loop(
-        0, axis_size, body, (k.astype(jnp.float32), v.astype(jnp.float32), m0, l0, o0)
-    )
+    _, _, m, l, o = jax.lax.fori_loop(0, axis_size, body, (k, v, m0, l0, o0))
     out = o / jnp.maximum(l, jnp.finfo(jnp.float32).tiny)[..., None]
     return out.astype(q.dtype)
 
@@ -114,16 +190,11 @@ def full_attention(
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision=jax.lax.Precision.HIGHEST) * scale
+    q, k, v = _common_dtype(q, k, v)
+    bias = None
     if causal:
-        sq, sk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
-        s = jnp.where(mask[None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-    return jnp.einsum(
-        "bhqk,bhkd->bhqd", p, v.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
-    ).astype(q.dtype)
+        bias = _causal_bias(jnp.arange(q.shape[-2]), jnp.arange(k.shape[-2]))
+    return _one_block_attention(q, k, v, bias, scale)
 
 
 @named_phase("attention")
@@ -142,20 +213,20 @@ def blockwise_attention(
     b, h, s, d = q.shape
     if scale is None:
         scale = 1.0 / (d**0.5)
+    q, k, v = _common_dtype(q, k, v)
     blk = min(block_size, s)
     n_blocks = -(-s // blk)
     pad = n_blocks * blk - s
     neg = jnp.float32(-jnp.inf)
-    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
     if pad:  # pad keys with fully-masked positions
-        kf = jnp.pad(kf, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     q_pos = jnp.arange(s)
 
     def body(t, carry):
         m, l, o = carry
-        k_blk = jax.lax.dynamic_slice_in_dim(kf, t * blk, blk, axis=2)
-        v_blk = jax.lax.dynamic_slice_in_dim(vf, t * blk, blk, axis=2)
+        k_blk = jax.lax.dynamic_slice_in_dim(k, t * blk, blk, axis=2)
+        v_blk = jax.lax.dynamic_slice_in_dim(v, t * blk, blk, axis=2)
         k_pos = t * blk + jnp.arange(blk)
         valid = k_pos[None, :] < s
         if causal:

@@ -1,9 +1,12 @@
 """Ring attention + sequence-parallel LM tests on the CPU-simulated mesh."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from atomo_tpu.codecs import SvdCodec
 from atomo_tpu.models.transformer import TransformerLM, lm_loss
@@ -17,9 +20,10 @@ from atomo_tpu.parallel.ring import (
 from atomo_tpu.training import create_state, make_optimizer
 
 
-pytestmark = pytest.mark.slow  # heavy multi-device compile/parity runs; deselect with -m "not slow"
+slow = pytest.mark.slow  # heavy multi-device compile/parity runs; deselect with -m "not slow"
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_matches_full_attention(causal):
     """Exactness: ring attention over 4 sequence shards == full attention."""
@@ -37,6 +41,7 @@ def test_ring_matches_full_attention(causal):
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
 
 
+@slow
 def test_ring_attention_single_shard_degenerates():
     """axis_size=1: ring == full attention trivially (no ppermute traffic)."""
     mesh = make_mesh(1, axes=(("sp", 1),))
@@ -51,6 +56,7 @@ def _lm_cfg(max_len=64):
     return dict(vocab_size=32, max_len=max_len, width=32, depth=2, num_heads=2)
 
 
+@slow
 def test_transformer_forward_shapes():
     model = TransformerLM(**_lm_cfg())
     tokens = jnp.zeros((2, 16), jnp.int32)
@@ -60,6 +66,7 @@ def test_transformer_forward_shapes():
     assert np.isfinite(float(lm_loss(logits, tokens)))
 
 
+@slow
 def test_lm_dp_sp_step_runs_and_compresses():
     """2x4 mesh: dp-compressed + sp-ring training step executes and the
     payload bytes beat dense."""
@@ -78,6 +85,7 @@ def test_lm_dp_sp_step_runs_and_compresses():
     assert int(metrics["msg_bytes"]) < int(metrics["dense_bytes"])
 
 
+@slow
 def test_lm_sharded_loss_matches_unsharded():
     """The dp x sp dense step computes the same loss as a single-device
     forward on the full batch (boundary-token handling is exact)."""
@@ -99,6 +107,7 @@ def test_lm_sharded_loss_matches_unsharded():
     )
 
 
+@slow
 def test_lm_training_learns():
     """A few compressed dp x sp steps reduce loss on a repeating pattern."""
     mesh = make_mesh(8, axes=(("dp", 2), ("sp", 4)))
@@ -117,6 +126,7 @@ def test_lm_training_learns():
     assert losses[-1] < losses[0] * 0.8, losses
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_ulysses_matches_full_attention(causal):
     """Exactness of the all-to-all strategy: ulysses over 4 sequence shards
@@ -134,6 +144,7 @@ def test_ulysses_matches_full_attention(causal):
     np.testing.assert_allclose(np.asarray(uly(q, k, v)), np.asarray(expected), atol=2e-5)
 
 
+@slow
 def test_ulysses_rejects_indivisible_heads():
     from atomo_tpu.parallel.ring import ulysses_attention
 
@@ -144,6 +155,7 @@ def test_ulysses_rejects_indivisible_heads():
         fn(q, q, q)
 
 
+@slow
 def test_lm_ulysses_step_matches_ring_loss():
     """The dp x sp LM step computes the same loss under either
     sequence-parallel strategy (both are exact attention)."""
@@ -163,6 +175,7 @@ def test_lm_ulysses_step_matches_ring_loss():
     assert abs(losses["ring"] - losses["ulysses"]) < 2e-4, losses
 
 
+@slow
 def test_blockwise_matches_full_attention():
     """The local blockwise kernel (ulysses' inner loop) never builds the
     S x S matrix yet must equal full attention, incl. causal + a block
@@ -178,6 +191,7 @@ def test_blockwise_matches_full_attention():
         np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
 
 
+@slow
 def test_lm_ulysses_gradients_match_ring():
     """GRADIENT parity between the strategies: one real (lr > 0) training
     step from identical state must land on (numerically) identical params —
@@ -200,6 +214,7 @@ def test_lm_ulysses_gradients_match_ring():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
 
 
+@slow
 def test_make_lm_train_step_rejects_unknown_impl():
     mesh = make_mesh(8, axes=(("dp", 2), ("sp", 4)))
     with pytest.raises(ValueError, match="attn_impl"):
@@ -207,6 +222,7 @@ def test_make_lm_train_step_rejects_unknown_impl():
                            attn_impl="ulises")
 
 
+@slow
 def test_lm_bf16_step_runs_and_keeps_f32_state():
     """Mixed precision on the dp x sp LM path: bf16 compute, f32 master."""
     mesh = make_mesh(8, axes=(("dp", 2), ("sp", 4)))
@@ -224,6 +240,7 @@ def test_lm_bf16_step_runs_and_keeps_f32_state():
         assert leaf.dtype == jnp.float32
 
 
+@slow
 def test_lm_sharded_grads_match_unsharded_oracle():
     """Regression: one dense dp=1 x sp=4 update step lands on the same params
     as single-device AD + SGD. Catches the sp-axis gradient inflation class
@@ -258,3 +275,132 @@ def test_lm_sharded_grads_match_unsharded_oracle():
         got,
         want,
     )
+
+
+# --- bfloat16 inputs: operands in bf16, softmax and accumulation in float32
+
+
+def _attention_impl(impl, causal, scale):
+    """(q, k, v) -> out for one of the five ways into the shared block."""
+    from atomo_tpu.parallel.ring import ATTENTION_IMPLS, blockwise_attention
+
+    if impl == "full":
+        return partial(full_attention, causal=causal, scale=scale)
+    if impl == "blockwise":  # 32 positions in blocks of 12: a padded last block
+        return partial(blockwise_attention, causal=causal, scale=scale, block_size=12)
+    name, n = {"ring1": ("ring", 1), "ring4": ("ring", 4), "ulysses": ("ulysses", 4)}[impl]
+    mesh = make_mesh(n, axes=(("sp", n),))
+    fn = partial(ATTENTION_IMPLS[name], axis_name="sp", axis_size=n, causal=causal, scale=scale)
+    spec = P(None, None, "sp", None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
+
+
+def _forward_and_grads(fn, q, k, v, w):
+    out = jax.jit(fn)(q, k, v)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2)))(q, k, v)
+    return {"forward": [out], "grads": list(grads)}
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+# Readings on the CPU over three seeds at this shape, relative to the norm of
+# the float32 answer: forward 1.7e-3 to 1.8e-3 (the output's own rounding to
+# bf16), gradients 2.8e-3 to 4.8e-3; a scale 1.25x off reads 0.14 to 0.33.
+_BF16_TOL = {"forward": 1e-2, "grads": 2e-2}
+
+
+@pytest.mark.parametrize("what", ["forward", "grads"])
+@pytest.mark.parametrize("impl", ["full", "ring1", "ring4", "blockwise", "ulysses"])
+def test_bf16_attention_matches_float32_oracle(impl, what):
+    """bfloat16 q, k, v through every entry point against full_attention on
+    the same values in float32, forward and the gradients of a scalar loss;
+    a deliberately wrong scale must fail the same comparison."""
+    b, h, s, d = 2, 4, 32, 8
+    q, k, v, w = (
+        jax.random.normal(key, (b, h, s, d), jnp.float32)
+        for key in jax.random.split(jax.random.PRNGKey(0), 4)
+    )
+    lo = [x.astype(jnp.bfloat16) for x in (q, k, v)]
+    want = _forward_and_grads(
+        _attention_impl("full", True, None), *(x.astype(jnp.float32) for x in lo), w
+    )[what]
+    got = _forward_and_grads(_attention_impl(impl, True, None), *lo, w)[what]
+    wrong = _forward_and_grads(_attention_impl(impl, True, 1.25 / d**0.5), *lo, w)[what]
+    for g, x, ref in zip(got, wrong, want):
+        assert g.dtype == jnp.bfloat16 and g.shape == ref.shape
+        assert _rel(g, ref) < _BF16_TOL[what], (impl, what, _rel(g, ref))
+        assert _rel(x, ref) > 5 * _BF16_TOL[what], (impl, what, _rel(x, ref))
+
+
+def _kept_for_backward(jaxpr, shape, dtype):
+    """Variables of ``shape`` and ``dtype`` that an equation of the forward
+    pass writes and an equation of the backward pass (autodiff brackets it
+    ``transpose(...)`` in the name stack) reads: the residuals of that size.
+    Walks into sub-jaxprs (pjit, shard_map, scan)."""
+    kept, forward = [], {}
+    for eqn in jaxpr.eqns:
+        backward = "transpose(" in str(eqn.source_info.name_stack)
+        for var in eqn.invars:
+            if backward and id(var) in forward:
+                kept.append((forward[id(var)], str(eqn.source_info.name_stack)))
+        for var in eqn.outvars:
+            aval = var.aval
+            if not backward and getattr(aval, "shape", None) == shape and aval.dtype == dtype:
+                forward[id(var)] = eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            kept += _kept_for_backward(sub, shape, dtype)
+    return kept
+
+
+def _attention_dots(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and "attention" in str(eqn.source_info.name_stack):
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _attention_dots(sub)
+
+
+def _lm_step_jaxpr(compute_dtype, attention_fn=None):
+    """The dp=1 x sp=1 lm step (the one-chip cell's path) at a tiny size
+    whose head size (8) differs from its sequence (16)."""
+    cfg = dict(vocab_size=32, max_len=16, width=16, depth=2, num_heads=2)
+    mesh = make_mesh(1, axes=(("dp", 1), ("sp", 1)))
+    opt = make_optimizer("sgd", lr=0.1)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (3, 16), 0, 32)
+    state = create_state(TransformerLM(**cfg), opt, jax.random.PRNGKey(1), tokens)
+    step = make_lm_train_step(cfg, opt, mesh, codec=None, compute_dtype=compute_dtype)
+    jaxpr = jax.make_jaxpr(step)(state, jax.random.PRNGKey(2), shard_tokens(mesh, tokens))
+    return jaxpr.jaxpr, (3, 2, 16, 16)
+
+
+def test_bf16_lm_step_attention_engages():
+    """What stands in for a counter: in the lowered --bf16 step every
+    contraction inside the attention scope, forward and backward, takes
+    bfloat16 operands and accumulates in float32, and the backward pass
+    reads no float32 (B, H, S, S) array that the forward pass wrote."""
+    jaxpr, scores = _lm_step_jaxpr(jnp.bfloat16)
+    dots = list(_attention_dots(jaxpr))
+    assert len(dots) == 2 * 6  # two layers: two contractions, four in the backward pass
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [jnp.bfloat16] * 2, eqn
+        assert eqn.params["preferred_element_type"] == jnp.float32, eqn
+        assert eqn.params["precision"] is None, eqn  # one MXU pass
+    assert _kept_for_backward(jaxpr, scores, jnp.float32) == []
+    kept = _kept_for_backward(jaxpr, scores, jnp.bfloat16)
+    assert kept and all(name == "convert_element_type" for name, _ in kept), kept
+
+
+def test_float32_lm_step_keeps_float32_attention():
+    """The rule reads the dtype: without --bf16 the same step contracts
+    float32 operands at the highest precision and keeps float32
+    exponentials, so the check above has something to tell apart."""
+    jaxpr, scores = _lm_step_jaxpr(None)
+    dots = list(_attention_dots(jaxpr))
+    assert len(dots) == 2 * 6
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [jnp.float32] * 2, eqn
+        assert eqn.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2, eqn
+    assert _kept_for_backward(jaxpr, scores, jnp.float32) != []

@@ -46,3 +46,55 @@ def test_flash_grad_compiles_on_tpu():
     grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     for g in grads:
         assert np.all(np.isfinite(np.asarray(g)))
+
+
+# --- the jnp core at the one-chip cell's shape (PR 27)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def test_bf16_core_matches_float32_oracle_at_cell_shape_on_tpu():
+    """(4, 16, 1024, 64) bfloat16, causal: the one-block core, as
+    full_attention and as ring_attention with one shard (the path of
+    `lm --layout dp --n-devices 1 --bf16`), forward and the gradients of a
+    scalar loss, against the same values through float32 operands at
+    Precision.HIGHEST. Read on the v5e (PR 27): forward 2.0e-3 (the
+    output's own rounding), gradients 3.4e-3 to 4.0e-3 of the oracle's norm;
+    a scale 1.25x off has to fail the same limits."""
+    from functools import partial
+
+    from jax.sharding import PartitionSpec as P
+
+    from atomo_tpu.parallel.ring import full_attention, ring_attention
+
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(2, b=4, h=16, s=1024, d=64))
+    w = jax.random.normal(jax.random.PRNGKey(3), q.shape, jnp.float32)
+
+    def both(fn, *args):
+        out = jax.jit(fn)(*args)
+        grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2)
+        ))(*args)
+        return [out, *grads]
+
+    def ring1(scale=None):
+        mesh = jax.make_mesh((1,), ("sp",))
+        spec = P(None, None, "sp", None)
+        return jax.shard_map(
+            partial(ring_attention, axis_name="sp", axis_size=1, causal=True, scale=scale),
+            mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
+        )
+
+    want = both(partial(full_attention, causal=True), *(x.astype(jnp.float32) for x in (q, k, v)))
+    limits = [1e-2, 2e-2, 2e-2, 2e-2]
+    for fn in (partial(full_attention, causal=True), ring1()):
+        got = both(fn, q, k, v)
+        for g, ref, limit in zip(got, want, limits):
+            assert g.dtype == jnp.bfloat16
+            assert _rel(g, ref) < limit, (_rel(g, ref), limit)
+    wrong = both(ring1(scale=1.25 / 8.0), q, k, v)
+    for g, ref, limit in zip(wrong, want, limits):
+        assert _rel(g, ref) > 2 * limit, (_rel(g, ref), limit)
