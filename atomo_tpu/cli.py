@@ -3143,6 +3143,55 @@ def cmd_train(args: argparse.Namespace) -> int:
     return 0
 
 
+def _lm_block_config(args: argparse.Namespace) -> dict:
+    """The TransformerLM fields that `lm --block`, `--layer-pattern` and the
+    sizes beside them set; empty for GPT-2's block, so that the layouts whose
+    blocks are written by hand see the configuration they always saw."""
+    from atomo_tpu.models.linear_attention import CHUNK
+    from atomo_tpu.models.transformer import BLOCK_RECIPES, MIXERS
+
+    pattern = tuple(kind.strip() for kind in args.layer_pattern.split(","))
+    for kind in pattern:
+        if kind not in MIXERS:
+            raise SystemExit(
+                f"--layer-pattern: unknown layer kind {kind!r}; expected a "
+                f"comma-separated list of {' | '.join(MIXERS)}"
+            )
+    block = dict(BLOCK_RECIPES[args.block])
+    if args.ffn_width:
+        block["ffn_width"] = args.ffn_width
+    if args.remat != "none":
+        block["remat"] = args.remat
+    if pattern != ("full",):
+        block["layer_pattern"] = pattern
+    if "linear" in pattern:
+        if args.linear_key_dim <= 0 or args.linear_value_dim <= 0:
+            raise SystemExit(
+                "--layer-pattern with a linear layer needs its head sizes: "
+                "--linear-key-dim and --linear-value-dim"
+            )
+        if args.seq_len % CHUNK:
+            raise SystemExit(
+                f"--seq-len {args.seq_len} is no multiple of {CHUNK}: the "
+                "linear layers of --layer-pattern run in whole chunks"
+            )
+        block.update(
+            linear_key_dim=args.linear_key_dim,
+            linear_value_dim=args.linear_value_dim,
+            linear_conv_width=args.linear_conv_width,
+        )
+    if block and args.layout != "dp":
+        flag = ("--block" if args.block != "gpt2" else
+                "--ffn-width" if args.ffn_width else
+                "--remat" if args.remat != "none" else "--layer-pattern")
+        raise SystemExit(
+            f"{flag} needs --layout dp: --layout {args.layout} writes GPT-2's "
+            "block by hand (tp, ep, pp) or rings the sequence (sp), which a "
+            "linear layer's state does not cross"
+        )
+    return block
+
+
 def cmd_lm(args: argparse.Namespace) -> int:
     """Long-context / model-sharded LM training: every parallelism layout
     the framework supports, drivable from the CLI (no reference analogue —
@@ -3267,6 +3316,7 @@ def cmd_lm(args: argparse.Namespace) -> int:
         vocab_size=args.vocab_size, max_len=args.seq_len, width=args.width,
         depth=args.depth, num_heads=args.num_heads,
     )
+    cfg.update(_lm_block_config(args))  # nothing, for GPT-2's block
     key = jax.random.PRNGKey(args.seed)
     compute_dtype = jax.numpy.bfloat16 if args.bf16 else None
 
@@ -3850,6 +3900,34 @@ def build_parser() -> argparse.ArgumentParser:
     p_lm.add_argument("--width", type=int, default=128)
     p_lm.add_argument("--depth", type=int, default=4)
     p_lm.add_argument("--num-heads", type=int, default=4)
+    p_lm.add_argument("--block", type=str, default="gpt2",
+                      choices=["gpt2", "olmo"],
+                      help="the block's recipe (models/transformer.py "
+                           "BLOCK_RECIPES): gpt2 = pre-LayerNorm, learned "
+                           "positions, GELU MLP; olmo = RMSNorm on each "
+                           "sublayer's output, q/k norm, SiLU-gated FFN, no "
+                           "positional embedding. --layout dp only")
+    p_lm.add_argument("--layer-pattern", type=str, default="full",
+                      metavar="KIND[,KIND...]",
+                      help="mixer of each layer, repeated over --depth: full "
+                           "(softmax attention) | linear (gated delta rule, "
+                           "models/linear_attention.py), e.g. "
+                           "linear,linear,linear,full")
+    p_lm.add_argument("--ffn-width", type=int, default=0, metavar="N",
+                      help="hidden width of the FFN (0 = 4 x --width)")
+    p_lm.add_argument("--linear-key-dim", type=int, default=0, metavar="N",
+                      help="per-head key (and query) size of the linear layers")
+    p_lm.add_argument("--linear-value-dim", type=int, default=0, metavar="N",
+                      help="per-head value size of the linear layers")
+    p_lm.add_argument("--linear-conv-width", type=int, default=4, metavar="W",
+                      help="taps of the causal depthwise convolution on the "
+                           "linear layers' q, k and v")
+    p_lm.add_argument("--remat", type=str, default="none",
+                      choices=["none", "dots"],
+                      help="dots = each block keeps its matmuls against "
+                           "weights for the backward pass and rebuilds the "
+                           "rest there (less memory, about a forward pass of "
+                           "the cheap operations more)")
     p_lm.add_argument("--num-experts", type=int, default=8)
     p_lm.add_argument("--microbatches", type=int, default=2)
     p_lm.add_argument("--batch-size", type=int, default=8)

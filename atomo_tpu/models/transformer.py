@@ -3,8 +3,15 @@
 The reference's zoo is CV-only (SURVEY.md §2 model row); this family extends
 the framework to sequence models so the sequence/context-parallel machinery
 (atomo_tpu.parallel.ring) has a first-class consumer. Design is TPU-first:
-pre-LN blocks, bias-free linears feeding the MXU, GELU MLP at 4x width,
-learned positional embeddings, all static shapes.
+bias-free linears feeding the MXU, all static shapes. The defaults are GPT-2's
+block: pre-LN, GELU MLP at 4x width, learned positional embeddings, full
+attention in every layer. The block's other choices are fields of
+:class:`TransformerLM` (``BLOCK_RECIPES`` names the sets that go together):
+RMSNorm, the norm on each sublayer's output, no positional embedding, a
+SiLU-gated FFN of its own width, an RMSNorm over the projected queries and
+keys, a per-layer mixer from ``layer_pattern`` (``full`` attention or the
+``linear`` gated delta rule of models/linear_attention.py), and ``remat``,
+which has a block keep only its weight matmuls for the backward pass.
 
 The attention callable is injectable: ``attention_fn(q, k, v)`` receives
 (B, H, S, D). Default is the single-device exact softmax
@@ -21,15 +28,40 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from atomo_tpu.models.linear_attention import GatedDeltaNet
 from atomo_tpu.parallel.ring import full_attention
+from atomo_tpu.utils.tracing import named_phase
 
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
+MIXERS = ("full", "linear")
+# the block choices that go together, as `lm --block` names them; "olmo" is
+# the OLMo 2/3 family's: the norm reordered onto the sublayers' outputs, q/k
+# norm, SwiGLU, and (Olmo-Hybrid's `rope_theta: null`) no positions at all
+BLOCK_RECIPES = {
+    "gpt2": {},
+    "olmo": dict(norm="rmsnorm", norm_placement="post", positions="none",
+                 ffn="swiglu", qk_norm=True),
+}
+
+
+# the choices a TransformerLM hands to every one of its blocks unchanged
+BLOCK_FIELDS = ("dropout", "attention_fn", "norm", "norm_placement", "ffn", "ffn_width",
+                "qk_norm", "linear_key_dim", "linear_value_dim", "linear_conv_width")
+
+
+def _norm(kind: str, name: str) -> nn.Module:
+    if kind == "layernorm":
+        return nn.LayerNorm(use_bias=False, name=name)
+    if kind == "rmsnorm":
+        return nn.RMSNorm(name=name)
+    raise ValueError(f"unknown norm {kind!r}; expected layernorm | rmsnorm")
 
 
 class MultiHeadAttention(nn.Module):
     num_heads: int
     head_dim: int
     attention_fn: Optional[AttentionFn] = None
+    qk_norm: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -37,6 +69,8 @@ class MultiHeadAttention(nn.Module):
         h, d = self.num_heads, self.head_dim
         qkv = nn.Dense(3 * h * d, use_bias=False, name="qkv")(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
+        if self.qk_norm:  # over the whole projection, before the split into heads
+            q, k = nn.RMSNorm(name="q_norm")(q), nn.RMSNorm(name="k_norm")(k)
 
         def heads(t):  # (B, S, H*D) -> (B, H, S, D)
             return t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
@@ -53,19 +87,54 @@ class Block(nn.Module):
     mlp_ratio: int = 4
     dropout: float = 0.0
     attention_fn: Optional[AttentionFn] = None
+    mixer: str = "full"
+    norm: str = "layernorm"
+    norm_placement: str = "pre"  # pre: x + f(norm(x)); post: x + norm(f(x))
+    ffn: str = "gelu"
+    ffn_width: int = 0  # 0: mlp_ratio x width
+    qk_norm: bool = False
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_width: int = 4
+
+    def _ffn(self, y: jax.Array) -> jax.Array:
+        width = y.shape[-1]
+        hidden = self.ffn_width or self.mlp_ratio * width
+        if self.ffn == "gelu":
+            y = nn.Dense(hidden, use_bias=False, name="up")(y)
+            y = nn.gelu(y)
+            return nn.Dense(width, use_bias=False, name="down")(y)
+        if self.ffn != "swiglu":
+            raise ValueError(f"unknown ffn {self.ffn!r}; expected gelu | swiglu")
+        with named_phase("ffn"):
+            gate = nn.Dense(hidden, use_bias=False, name="gate")(y)
+            y = nn.silu(gate) * nn.Dense(hidden, use_bias=False, name="up")(y)
+            return nn.Dense(width, use_bias=False, name="down")(y)
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
-        width = x.shape[-1]
-        y = nn.LayerNorm(use_bias=False, name="ln1")(x)
-        y = MultiHeadAttention(self.num_heads, self.head_dim, self.attention_fn)(y)
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(
+                f"unknown norm_placement {self.norm_placement!r}; expected pre | post"
+            )
+        pre = self.norm_placement == "pre"
+        if self.mixer == "full":
+            mixer = MultiHeadAttention(
+                self.num_heads, self.head_dim, self.attention_fn, self.qk_norm
+            )
+        elif self.mixer == "linear":
+            mixer = GatedDeltaNet(
+                self.num_heads, self.linear_key_dim, self.linear_value_dim,
+                self.linear_conv_width,
+            )
+        else:
+            raise ValueError(f"unknown mixer {self.mixer!r}; expected one of {MIXERS}")
+        ln1, ln2 = _norm(self.norm, "ln1"), _norm(self.norm, "ln2")
+        y = mixer(ln1(x)) if pre else ln1(mixer(x))
         if self.dropout:
             y = nn.Dropout(self.dropout, deterministic=not train)(y)
         x = x + y
-        y = nn.LayerNorm(use_bias=False, name="ln2")(x)
-        y = nn.Dense(self.mlp_ratio * width, use_bias=False, name="up")(y)
-        y = nn.gelu(y)
-        y = nn.Dense(width, use_bias=False, name="down")(y)
+        y = self._ffn(ln2(x)) if pre else ln2(self._ffn(x))
         if self.dropout:
             y = nn.Dropout(self.dropout, deterministic=not train)(y)
         return x + y
@@ -80,7 +149,18 @@ class TransformerLM(nn.Module):
     depth: int = 4
     num_heads: int = 4
     dropout: float = 0.0
-    attention_fn: Optional[AttentionFn] = None
+    attention_fn: Optional[AttentionFn] = None  # of the `full` layers
+    norm: str = "layernorm"  # layernorm | rmsnorm
+    norm_placement: str = "pre"  # pre | post
+    positions: str = "learned"  # learned | none
+    ffn: str = "gelu"  # gelu | swiglu
+    ffn_width: int = 0  # 0: 4 x width
+    qk_norm: bool = False
+    layer_pattern: tuple = ("full",)  # mixer kinds, repeated over the depth
+    linear_key_dim: int = 0  # per head, of the `linear` layers
+    linear_value_dim: int = 0
+    linear_conv_width: int = 4
+    remat: str = "none"  # none | dots: what a block keeps for the backward pass of a training step
 
     @nn.compact
     def __call__(
@@ -92,19 +172,40 @@ class TransformerLM(nn.Module):
         b, s = tokens.shape
         head_dim = self.width // self.num_heads
         x = nn.Embed(self.vocab_size, self.width, name="tok_emb")(tokens)
-        pos = nn.Embed(self.max_len, self.width, name="pos_emb")(
-            pos_offset + jnp.arange(s)
-        )
-        x = x + pos[None, :, :]
+        if self.positions == "learned":
+            pos = nn.Embed(self.max_len, self.width, name="pos_emb")(
+                pos_offset + jnp.arange(s)
+            )
+            x = x + pos[None, :, :]
+        elif self.positions != "none":
+            raise ValueError(
+                f"unknown positions {self.positions!r}; expected learned | none"
+            )
+        if self.remat not in ("none", "dots"):
+            raise ValueError(f"unknown remat {self.remat!r}; expected none | dots")
+        if self.remat == "none" or not train:
+            # also where nothing is trained (initialisation, evaluation):
+            # flax's lifted remat keeps the scope of a call on concrete
+            # arrays alive, and with it a copy of the parameters
+            block = Block
+        else:
+            # keep the matmuls against weights, rebuild the rest of a block
+            # (elementwise passes, attention, the chunks and their scan)
+            # inside the backward pass
+            block = nn.remat(
+                Block, static_argnums=(2,),
+                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            )
+        shared = {f: getattr(self, f) for f in BLOCK_FIELDS}
         for i in range(self.depth):
-            x = Block(
+            x = block(
                 self.num_heads,
                 head_dim,
-                dropout=self.dropout,
-                attention_fn=self.attention_fn,
+                mixer=self.layer_pattern[i % len(self.layer_pattern)],
                 name=f"block{i}",
-            )(x, train=train)
-        x = nn.LayerNorm(use_bias=False, name="ln_f")(x)
+                **shared,
+            )(x, train)
+        x = _norm(self.norm, "ln_f")(x)
         return nn.Dense(self.vocab_size, use_bias=False, name="head")(x)
 
 

@@ -86,15 +86,24 @@ PHASE_OF_SCOPE = {
     "delayed_decode_mean": "decode",
     "forward_backward": "forward_backward",
     "attention": "attention",
+    "linear_attention": "linear_attention",
+    "delta_chunk": "delta_chunk",
+    "delta_scan": "delta_scan",
+    "ffn": "ffn",
     "update": "update",
 }
 # the codec's and the exchange's phases are measured AGAINST the compute
 # side (exposed / hidden); the model's own phases are the compute side,
 # split by scope, and report their busy time. An op is in ONE phase, the
 # innermost scope's: `forward_backward` is what is left of it outside
-# `attention`.
+# `attention`, `ffn` and the linear layers' mixer core, and
+# `linear_attention` what is left of that core (convolution, normalisation,
+# gates) outside `delta_chunk` and `delta_scan`: the core is the three.
 PHASES = ("encode", "exchange", "decode")
-MODEL_PHASES = ("forward_backward", "attention", "update")
+MODEL_PHASES = (
+    "forward_backward", "attention", "linear_attention", "delta_chunk",
+    "delta_scan", "ffn", "update",
+)
 # the loop's host spans, as utils.tracing names them
 HOST_SPANS = (
     tracing.BLOCK, tracing.STEP, tracing.FEED_TAKE, tracing.DISPATCH,

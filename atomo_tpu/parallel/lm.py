@@ -611,8 +611,14 @@ def make_lm_train_step(
 
     n_sp = mesh.shape[sp_axis]
     n_dp = mesh.shape[dp_axis]
+    if n_sp > 1 and "linear" in lm_config.get("layer_pattern", ()):
+        raise ValueError(
+            f"a linear-attention layer carries its state along the sequence, "
+            f"which the {sp_axis!r} ring does not pass on: {sp_axis}={n_sp} "
+            "needs every layer of layer_pattern to be 'full'"
+        )
 
-    def grads_fn(state: TrainState, key, tokens):
+    def grads_and_counters(state: TrainState, key, tokens):
         model = TransformerLM(
             **lm_config,
             attention_fn=partial(
@@ -631,21 +637,26 @@ def make_lm_train_step(
                 # inputs, so only the params need the cast
                 params = cast_params(params, compute_dtype)
             s_local = tokens.shape[1]
-            logits = model.apply(
+            # `counters`: what the layers count from their shapes as they
+            # are traced (constants; none in a model of full attention)
+            logits, sown = model.apply(
                 {"params": params},
                 tokens,
                 train=True,
                 pos_offset=jax.lax.axis_index(sp_axis) * s_local,
+                mutable=["counters"],
             )
             if compute_dtype is not None:
                 logits = logits.astype(jnp.float32)
             targets, valid = sp_boundary_targets_and_mask(tokens, sp_axis, n_sp)
             ce = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
             total = jax.lax.psum(jnp.sum(valid), sp_axis)
-            return jax.lax.psum(jnp.sum(ce * valid), sp_axis) / total
+            return jax.lax.psum(jnp.sum(ce * valid), sp_axis) / total, sown
 
         with named_phase("forward_backward"):
-            loss, grads = jax.value_and_grad(loss_fn)(state.params)
+            (loss, sown), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                state.params
+            )
         # sp-PMEAN completes THIS replica's gradient (intra-replica, dense).
         # Mean, not sum: under shard_map the transpose of the loss psum is
         # itself a psum, so each shard's per-shard grads already carry an
@@ -653,15 +664,24 @@ def make_lm_train_step(
         # them again would scale the gradient by n_sp — a silent effective-LR
         # inflation verified empirically (tests/test_ring.py oracle parity).
         grads = jax.lax.pmean(grads, sp_axis)
-        return k_codec, grads, loss
+        counters: dict = {}
+        for path, value in jax.tree_util.tree_leaves_with_path(sown):
+            name = path[-2].key  # .../<module>/<counter>/<index in its tuple>
+            counters[name] = counters.get(name, 0.0) + value
+        return k_codec, grads, loss, counters
+
+    def grads_fn(state: TrainState, key, tokens):
+        # the delayed builder's closure, shared with tp/pp/ep: no counters
+        return grads_and_counters(state, key, tokens)[:3]
 
     def spmd_step(state: TrainState, key, tokens):
-        k_codec, grads, loss = grads_fn(state, key, tokens)
-        return dp_exchange_tail(
+        k_codec, grads, loss, counters = grads_and_counters(state, key, tokens)
+        new_state, metrics = dp_exchange_tail(
             optimizer, codec, state, k_codec, grads, loss,
             dp_axis=dp_axis, n_dp=n_dp, aggregate=aggregate,
             exchange=exchange,
         )
+        return new_state, {**metrics, **counters}
 
     if exchange is not None and exchange.overlap == "delayed":
         return make_delayed_model_axis_step(

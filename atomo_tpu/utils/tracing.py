@@ -167,7 +167,10 @@ def named_phase(name: str) -> Iterator[None]:
     aggregation paths (parallel/replicated.py, parallel/lm.py), around
     ``forward_backward`` / ``encode`` / ``decode`` / ``update`` in the
     single-device step (training/trainer.py) and the lm step, and around
-    ``attention`` (parallel/ring.py); read by ``report timeline``
+    ``attention`` (parallel/ring.py), ``linear_attention`` with
+    ``delta_chunk`` and ``delta_scan`` inside it
+    (models/linear_attention.py) and ``ffn`` around the gated FFN
+    (models/transformer.py); read by ``report timeline``
     (obs/timeline.py PHASE_OF_SCOPE). Metadata only: the compiled program
     does not change. No-op when jax lacks named_scope.
 
