@@ -22,6 +22,7 @@ same module runs with the sequence dimension sharded.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, Optional
 
 import flax.linen as nn
@@ -29,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from atomo_tpu.models.linear_attention import GatedDeltaNet
-from atomo_tpu.parallel.ring import full_attention
+from atomo_tpu.parallel.ring import full_attention, kept_score_bytes
 from atomo_tpu.utils.tracing import named_phase
 
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
@@ -75,8 +76,12 @@ class MultiHeadAttention(nn.Module):
         def heads(t):  # (B, S, H*D) -> (B, H, S, D)
             return t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
-        fn = self.attention_fn or (lambda q, k, v: full_attention(q, k, v, causal=True))
-        out = fn(heads(q), heads(k), heads(v))  # (B, H, S, D)
+        fn = self.attention_fn or partial(full_attention, causal=True)
+        q, k, v = heads(q), heads(k), heads(v)
+        out = fn(q, k, v)  # (B, H, S, D)
+        if kept := kept_score_bytes(fn, q):
+            # read by the lm step into its metrics, summed over the full layers
+            self.sow("counters", "attn_score_bytes", jnp.float32(kept))
         out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
         return nn.Dense(x.shape[-1], use_bias=False, name="proj")(out)
 

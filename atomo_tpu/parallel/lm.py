@@ -638,7 +638,7 @@ def make_lm_train_step(
                 params = cast_params(params, compute_dtype)
             s_local = tokens.shape[1]
             # `counters`: what the layers count from their shapes as they
-            # are traced (constants; none in a model of full attention)
+            # are traced (constants: `lin_state_bytes`, `attn_score_bytes`)
             logits, sown = model.apply(
                 {"params": params},
                 tokens,

@@ -22,6 +22,17 @@ and backward, take operands of that dtype and accumulate in float32 (one MXU
 pass for bfloat16, ``Precision.HIGHEST`` for float32); scores, row maximum,
 exponentials and row sums are float32 always. Of score size the backward
 pass keeps the exponentials alone, rounded to that dtype (``_softmax_block``).
+
+Causal attention over one block (``full_attention`` and a ring of one shard)
+skips most of the masked half: the queries are cut into at most
+``MAX_QUERY_BLOCKS`` equal blocks (``causal_query_blocks``), and each block
+runs against the keys up to its own end only, its softmax taken once over
+exactly the keys it saw before, so nothing is rescaled or merged. With n
+blocks (n+1)/2n of the score square is computed and kept. The number of
+blocks is capped whatever the sequence length: every block is a set of
+contractions of shapes no other block has, and the step program's set-up
+pays for each distinct shape (PERF.md, PR 30). ``kept_score_bytes`` counts
+what is left of the square, for the step's ``attn_score_bytes``.
 """
 
 from __future__ import annotations
@@ -36,12 +47,25 @@ from jax.sharding import Mesh, PartitionSpec as P
 from atomo_tpu.utils.tracing import named_phase
 
 
+# The attention core's three contractions as ``dot_general`` takes them: the
+# contracted axis of each operand, over the batch axes (0, 1). ``jnp.einsum``
+# builds the same operation, but parses the spec and searches a contraction
+# path on every trace, and the query blocks trace each one once per block.
+_CONTRACTED = {"bhqd,bhkd->bhqk": (3, 3), "bhqk,bhkd->bhqd": (3, 2), "bhqk,bhqd->bhkd": (2, 2)}
+
+
 def _dot(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
     """One contraction of the core, operands as they are, float32 result:
     a single MXU pass for bfloat16, ``Precision.HIGHEST`` for float32."""
     precision = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
-    return jnp.einsum(
-        spec, a, b, precision=precision, preferred_element_type=jnp.float32
+    if spec not in _CONTRACTED:  # the linear layers' contractions
+        return jnp.einsum(
+            spec, a, b, precision=precision, preferred_element_type=jnp.float32
+        )
+    in_a, in_b = _CONTRACTED[spec]
+    return jax.lax.dot_general(
+        a, b, (((in_a,), (in_b,)), ((0, 1), (0, 1))),
+        precision=precision, preferred_element_type=jnp.float32,
     )
 
 
@@ -82,15 +106,23 @@ def _softmax_block_fwd(q, k_blk, v_blk, bias, m_prev, scale):
     return (m, l, o), (q, k_blk, v_blk, p)
 
 
-def _softmax_block_bwd(scale, res, cts):
+def _softmax_block_grads(scale, res, dl, do):
+    """The gradients of q, k_blk and v_blk from the cotangents of l and o,
+    in float32 as the contractions accumulate them."""
     q, k_blk, v_blk, p = res
-    _, dl, do = cts  # m carries no gradient
     do = do.astype(v_blk.dtype)
     dv = _dot("bhqk,bhqd->bhkd", p, do)
     dp = _dot("bhqd,bhkd->bhqk", do, v_blk)
     ds = (p * (dp + dl[..., None]) * scale).astype(q.dtype)
     dq = _dot("bhqk,bhkd->bhqd", ds, k_blk)
     dk = _dot("bhqk,bhqd->bhkd", ds, q)
+    return dq, dk, dv
+
+
+def _softmax_block_bwd(scale, res, cts):
+    q, k_blk, v_blk, _ = res
+    _, dl, do = cts  # m carries no gradient
+    dq, dk, dv = _softmax_block_grads(scale, res, dl, do)
     return (
         dq.astype(q.dtype), dk.astype(k_blk.dtype), dv.astype(v_blk.dtype),
         None, None,
@@ -117,6 +149,74 @@ def _one_block_attention(q, k, v, bias, scale):
 
 def _causal_bias(q_pos, k_pos):
     return jnp.where(q_pos[:, None] >= k_pos[None, :], 0.0, jnp.float32(-jnp.inf))
+
+
+QUERY_BLOCK_MULTIPLE = 128  # a block's keys end on a lane boundary of the scores
+MAX_QUERY_BLOCKS = 8  # a constant: the program is as large at 4096 as at 1024
+
+
+def causal_query_blocks(sq: int, sk: int) -> int:
+    """How many query blocks one-block causal attention is cut into, from the
+    static shape alone: the most, up to ``MAX_QUERY_BLOCKS``, equal blocks
+    whose size is a multiple of 128 (8 of 128 at 1024, 8 of 512 at 4096);
+    1, the uncut program, where the sequence is shorter than 256, is no
+    multiple of 128, or queries and keys differ in length."""
+    if sq != sk:
+        return 1
+    return max(
+        (n for n in range(1, MAX_QUERY_BLOCKS + 1) if sq % (n * QUERY_BLOCK_MULTIPLE) == 0),
+        default=1,
+    )
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _causal_blocks(q, k, v, n, scale):
+    """Causal softmax of S queries over the same S keys in n query blocks,
+    each against its own key prefix with the triangle on the prefix's last
+    columns: (l, o) as ``_softmax_block`` returns them, rows concatenated.
+    A row's softmax is the one-block path's (the keys left out contributed
+    exp(-inf) = 0). One differentiation rule over all blocks, so that dK and
+    dV are summed over the blocks in float32 and rounded once, as the one
+    contraction over all queries rounds them."""
+    return _causal_blocks_fwd(q, k, v, n, scale)[0]
+
+
+def _causal_blocks_fwd(q, k, v, n, scale):
+    blk = q.shape[-2] // n
+    ls, os, ps = [], [], []
+    for end in range(blk, q.shape[-2] + 1, blk):
+        bias = _causal_bias(jnp.arange(end - blk, end), jnp.arange(end))
+        (_, l, o), (_, _, _, p) = _softmax_block_fwd(
+            q[:, :, end - blk:end], k[:, :, :end], v[:, :, :end], bias, None, scale
+        )
+        ls.append(l), os.append(o), ps.append(p)
+    return (jnp.concatenate(ls, axis=2), jnp.concatenate(os, axis=2)), (q, k, v, ps)
+
+
+def _causal_blocks_bwd(n, scale, res, cts):
+    q, k, v, ps = res
+    dl, do = cts
+    blk = q.shape[-2] // n
+    dqs = []
+    dk, dv = jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)
+    for end, p in zip(range(blk, q.shape[-2] + 1, blk), ps, strict=True):
+        rows = slice(end - blk, end)
+        dq_blk, dk_blk, dv_blk = _softmax_block_grads(
+            scale, (q[:, :, rows], k[:, :, :end], v[:, :, :end], p),
+            dl[:, :, rows], do[:, :, rows],
+        )
+        dqs.append(dq_blk.astype(q.dtype))
+        dk, dv = dk.at[:, :, :end].add(dk_blk), dv.at[:, :, :end].add(dv_blk)
+    return jnp.concatenate(dqs, axis=2), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_causal_blocks.defvjp(_causal_blocks_fwd, _causal_blocks_bwd)
+
+
+@partial(jax.jit, static_argnums=(3, 4))  # traced once per shape, not once per layer
+def _causal_blocks_attention(q, k, v, n, scale):
+    l, o = _causal_blocks(q, k, v, n, scale)
+    return (o / l[..., None]).astype(q.dtype)
 
 
 def _common_dtype(q, k, v):
@@ -146,6 +246,8 @@ def ring_attention(
         scale = 1.0 / (d**0.5)
     q, k, v = _common_dtype(q, k, v)
     if axis_size == 1:  # one K/V block: no rotation, nothing to rescale
+        if causal and (n := causal_query_blocks(s_local, s_local)) > 1:
+            return _causal_blocks_attention(q, k, v, n, scale)
         pos = jnp.arange(s_local)
         return _one_block_attention(
             q, k, v, _causal_bias(pos, pos) if causal else None, scale
@@ -191,10 +293,29 @@ def full_attention(
     if scale is None:
         scale = 1.0 / (d**0.5)
     q, k, v = _common_dtype(q, k, v)
+    if causal and (n := causal_query_blocks(q.shape[-2], k.shape[-2])) > 1:
+        return _causal_blocks_attention(q, k, v, n, scale)
     bias = None
     if causal:
         bias = _causal_bias(jnp.arange(q.shape[-2]), jnp.arange(k.shape[-2]))
     return _one_block_attention(q, k, v, bias, scale)
+
+
+def kept_score_bytes(attention_fn, q: jax.Array) -> int:
+    """Bytes of exponentials ``attention_fn(q, k, v)`` keeps for the backward
+    pass, from shapes, where it is this module's one-block path over keys as
+    long as the queries: a ``partial`` of :func:`full_attention`, or of
+    :func:`ring_attention` over an axis of one. 0 for any other callable,
+    whose residuals are not counted here."""
+    func = getattr(attention_fn, "func", attention_fn)
+    given = getattr(attention_fn, "keywords", {})
+    if func is not full_attention and not (
+        func is ring_attention and given.get("axis_size") == 1
+    ):
+        return 0
+    b, h, s, _ = q.shape
+    n = causal_query_blocks(s, s) if given.get("causal") else 1
+    return b * h * (s // n) ** 2 * (n * (n + 1) // 2) * q.dtype.itemsize
 
 
 @named_phase("attention")

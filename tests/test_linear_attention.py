@@ -211,6 +211,8 @@ class LegacyAttention(nn.Module):
         heads = lambda t: t.reshape(b, s, h, d).transpose(0, 2, 1, 3)  # noqa: E731
         fn = self.attention_fn or (lambda q, k, v: full_attention(q, k, v, causal=True))
         out = fn(heads(q), heads(k), heads(v)).transpose(0, 2, 1, 3).reshape(b, s, h * d)
+        # the one line since PR 27: the step counts the exponentials it keeps (PR 30)
+        self.sow("counters", "attn_score_bytes", jnp.float32(b * h * s * s * q.dtype.itemsize))
         return nn.Dense(x.shape[-1], use_bias=False, name="proj")(out)
 
 

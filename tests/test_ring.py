@@ -404,3 +404,176 @@ def test_float32_lm_step_keeps_float32_attention():
         assert [v.aval.dtype for v in eqn.invars] == [jnp.float32] * 2, eqn
         assert eqn.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2, eqn
     assert _kept_for_backward(jaxpr, scores, jnp.float32) != []
+
+
+# --- causal attention in query blocks: each block against its own key prefix
+
+
+def _parent_attention(impl, causal):
+    """PR 27's one-block entry points, frozen: what the cut path must equal in
+    value, and what it must still lower to where nothing is cut."""
+    from atomo_tpu.parallel import ring
+
+    def attention(q, k, v):
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+        q, k, v = ring._common_dtype(q, k, v)
+        if impl == "ring1":
+            pos = jnp.arange(q.shape[-2])
+            bias = ring._causal_bias(pos, pos) if causal else None
+        else:
+            bias = ring._causal_bias(jnp.arange(q.shape[-2]), jnp.arange(k.shape[-2])) if causal else None
+        return ring._one_block_attention(q, k, v, bias, scale)
+
+    return attention
+
+
+def _this_attention(impl, causal):
+    def attention(q, k, v):
+        if impl == "ring1":
+            return ring_attention(q, k, v, axis_name="sp", axis_size=1, causal=causal)
+        return full_attention(q, k, v, causal=causal)
+
+    return attention
+
+
+@pytest.mark.parametrize("seq,blocks", [
+    (64, 1), (128, 1), (200, 1), (255, 1), (256, 2), (384, 3), (512, 4), (1000, 1),
+    (1024, 8), (1152, 3), (2048, 8), (4096, 8), (65536, 8),
+])
+def test_the_number_of_query_blocks_is_capped_whatever_the_length(seq, blocks):
+    from atomo_tpu.parallel.ring import MAX_QUERY_BLOCKS, causal_query_blocks
+
+    n = causal_query_blocks(seq, seq)
+    assert n == blocks <= MAX_QUERY_BLOCKS == 8
+    assert n == 1 or (seq // n) % 128 == 0
+    assert causal_query_blocks(seq, 2 * seq) == 1  # queries against a longer memory: not cut
+
+
+@pytest.mark.parametrize("impl", ["full", "ring1"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("seq", [256, 1024, 4096])
+def test_causal_blocks_match_the_one_block_oracle(seq, dtype, impl):
+    """2 blocks of 128 at 256, 8 of 128 at 1024, 8 of 512 at 4096, forward
+    and the three gradients, against the one-block program on the same
+    values in float32: float32 differs by the order of float32 sums alone,
+    bfloat16 within the tolerances of the one-block bfloat16 path."""
+    from atomo_tpu.parallel.ring import causal_query_blocks
+
+    assert causal_query_blocks(seq, seq) > 1
+    q, k, v, w = (
+        jax.random.normal(key, (1, 2, seq, 8), jnp.float32)
+        for key in jax.random.split(jax.random.PRNGKey(seq), 4)
+    )
+    lo = [x.astype(dtype) for x in (q, k, v)]
+    want = _forward_and_grads(_parent_attention(impl, True), *(x.astype(jnp.float32) for x in lo), w)
+    got = _forward_and_grads(_this_attention(impl, True), *lo, w)
+    tol = {"forward": 1e-5, "grads": 1e-5} if dtype == jnp.float32 else _BF16_TOL
+    for what in ("forward", "grads"):
+        for g, ref in zip(got[what], want[what], strict=True):
+            assert g.dtype == dtype and g.shape == ref.shape
+            assert _rel(g, ref) < tol[what], (seq, impl, what, _rel(g, ref))
+
+
+def _lowered(fn, *shapes, dtype=jnp.bfloat16):
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape in shapes]
+    return jax.jit(fn).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("impl,causal,sq,sk", [
+    ("full", True, 128, 128), ("ring1", True, 128, 128),  # shorter than two blocks
+    ("full", True, 200, 200), ("ring1", True, 1000, 1000),  # no multiple of 128
+    ("full", False, 1024, 1024), ("ring1", False, 1024, 1024),  # nothing masked
+    ("full", True, 256, 512), ("full", True, 1024, 256),  # queries and keys of different lengths
+])
+def test_what_is_not_cut_lowers_to_the_parents_text(impl, causal, sq, sk):
+    shapes = ((2, 2, sq, 8), (2, 2, sk, 8), (2, 2, sk, 8))
+    assert _lowered(_this_attention(impl, causal), *shapes) == _lowered(_parent_attention(impl, causal), *shapes)
+    # and the comparison can tell: the same entry point where it is cut
+    cut = ((2, 2, 256, 8),) * 3
+    assert _lowered(_this_attention(impl, True), *cut) != _lowered(_parent_attention(impl, True), *cut)
+
+
+@pytest.mark.parametrize("impl", ["full", "ring1"])
+def test_the_attention_program_is_as_large_at_4096_as_at_1024(impl):
+    """Guards the step's set-up off the chip: every query block is a set of
+    contractions of its own shapes, so their number must not follow the
+    sequence (one block with its gradient: 6 dot_general)."""
+
+    def dots(fn, seq):
+        grad = jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)), argnums=(0, 1, 2))
+        return _lowered(grad, *((1, 2, seq, 8),) * 3).count("stablehlo.dot_general")
+
+    assert dots(_parent_attention(impl, True), 1024) == 6
+    at_1024, at_4096 = (dots(_this_attention(impl, True), seq) for seq in (1024, 4096))
+    assert at_1024 == at_4096 == 6 * 8 <= 48
+
+
+# --- attn_score_bytes: what the blocks left of the score square, counted by the step
+
+
+def _cell_step_metrics(cell, **overrides):
+    """The lm step of a benchmark cell traced on shapes alone (no array of
+    the model's size is made): the names of its metrics, and the value of
+    the constant ones, read from the jaxpr pruned to that output."""
+    from pathlib import Path
+
+    from jax.interpreters import partial_eval as pe
+
+    from atomo_tpu.cli import _lm_block_config, build_parser
+    from benchmarks.run import Data, program_argv
+
+    data = Data(Path(__file__).resolve().parents[1] / "BENCHMARK.json")
+    entry = data.cell(cell)
+    config, traffic = data.config(entry["config"]), data.json("traffic", entry["traffic"])
+    args = build_parser().parse_args(program_argv({**config, **overrides}, traffic, 0)[0])
+    cfg = dict(vocab_size=args.vocab_size, max_len=args.seq_len, width=args.width,
+               depth=args.depth, num_heads=args.num_heads, **_lm_block_config(args))
+    mesh = make_mesh(1, axes=(("dp", 1), ("sp", 1)))
+    opt = make_optimizer("sgd", lr=args.lr, momentum=args.momentum)
+    step = make_lm_train_step(cfg, opt, mesh, compute_dtype=jnp.bfloat16 if args.bf16 else None)
+    sample = jnp.zeros((1, args.seq_len), jnp.int32)
+    state = jax.eval_shape(
+        lambda key: create_state(TransformerLM(**cfg), opt, key, sample), jax.random.PRNGKey(0)
+    )
+    tokens = jax.ShapeDtypeStruct((args.batch_size, args.seq_len), jnp.int32)
+    closed, out = jax.make_jaxpr(step, return_shape=True)(state, jax.random.PRNGKey(0), tokens)
+    names = [jax.tree_util.keystr(path) for path, _ in jax.tree_util.tree_flatten_with_path(out)[0]]
+
+    def constant(name):
+        pruned, used = pe.dce_jaxpr(closed.jaxpr, [n == f"[1]['{name}']" for n in names])
+        assert not any(used), f"{name} depends on the step's inputs"
+        return float(jax.core.eval_jaxpr(pruned, closed.consts)[0])
+
+    return {n[5:-2] for n in names if n.startswith("[1]")}, constant
+
+
+@pytest.mark.parametrize("cell,mib", [("gpt2m-1chip-dense", 1728.0), ("olmohybrid-1chip-dense", 540.0)])
+def test_attn_score_bytes_at_the_cells_shapes(cell, mib):
+    """24 layers x 4 x 16 x 1024 x 1024 x 2 B x 9/16 = 1728 MiB, and one layer
+    of 30 x 4096 x 4096 x 2 B x 9/16 = 540 MiB: 8 blocks keep (8+1)/16 of
+    the square in both cells."""
+    names, constant = _cell_step_metrics(cell)
+    assert "attn_score_bytes" in names
+    assert constant("attn_score_bytes") == mib * 2**20
+
+
+def test_attn_score_bytes_is_absent_where_no_full_layer_runs():
+    names, _ = _cell_step_metrics("olmohybrid-1chip-dense", layer_pattern="linear,linear,linear,linear")
+    assert "lin_state_bytes" in names and "attn_score_bytes" not in names
+
+
+@pytest.mark.parametrize("fn,want", [
+    (partial(full_attention, causal=True), 2 * 3 * 128 * 128 * 3 * 2),
+    (partial(ring_attention, axis_name="sp", axis_size=1, causal=True), 2 * 3 * 128 * 128 * 3 * 2),
+    (partial(full_attention, causal=False), 2 * 3 * 256 * 256 * 2),
+    (partial(ring_attention, axis_name="sp", axis_size=2, causal=True), 0),  # the ring's loop: not counted here
+    (lambda q, k, v: full_attention(q, k, v, causal=True), 0),  # a callable it cannot read
+])
+def test_kept_score_bytes_counts_the_one_block_paths_it_knows(fn, want):
+    from atomo_tpu.parallel.ring import kept_score_bytes
+
+    q = jax.ShapeDtypeStruct((2, 3, 256, 8), jnp.bfloat16)
+    assert kept_score_bytes(fn, q) == want
+    if want:  # and that is what autodiff keeps: the residuals of score size, from the vjp's own closure
+        kept = jax.tree_util.tree_leaves(jax.eval_shape(lambda *a: jax.vjp(fn, *a)[1], q, q, q))
+        assert sum(x.size * x.dtype.itemsize for x in kept if x.shape[-1] >= 128) == want
