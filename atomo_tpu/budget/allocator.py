@@ -29,7 +29,7 @@ Byte pricing is the codec's OWN static accounting
 (``SvdCodec.leaf_payload_bytes`` — the clamped actual, pinned equal to
 ``jax.eval_shape`` over the real encode in tests/test_comm_model.py),
 so a predicted allocation total and the executed program's
-``msg_bytes`` agree to the byte: the bench config 16 wire-match gate.
+``msg_bytes`` agree to the byte (tests/test_budget.py's wire-match).
 
 THE QSGD BIT LAW (the second water-filling target, same machinery,
 different pricing/variance pair): stochastic rounding of |x|/s onto
@@ -352,7 +352,7 @@ def allocation_payload_bytes(
     codec, spectra: Sequence[LayerSpectrum], ks: Sequence[int]
 ) -> int:
     """Predicted total wire bytes of an allocation — the clamped-actual
-    per-leaf pricing summed (what bench config 16's wire-match gate
+    per-leaf pricing summed (what tests/test_budget.py's wire-match
     compares against the executed program's msg_bytes)."""
     return int(
         sum(_leaf_bytes(codec, l, ks[l.index]) for l in spectra)
@@ -385,8 +385,8 @@ def solve_allocation(
     spectra and budget always yield the same allocation (tested).
 
     ``budget_bytes=None`` (or <= 0) spends exactly the uniform
-    allocation's total — the equal-total-wire-bytes comparison bench
-    config 16 publishes. ``mode="uniform"`` skips the solve and returns
+    allocation's total — the equal-total-wire-bytes comparison.
+    ``mode="uniform"`` skips the solve and returns
     the degenerate point. A budget at or past every layer's dense cost
     returns the spend-everything point (all-dense fallback — the
     densify remedy as the dial's limit)."""

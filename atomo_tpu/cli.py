@@ -121,7 +121,7 @@ def _add_fit_args(parser: argparse.ArgumentParser) -> None:
                    help="autopilot: steps per timed probe dispatch loop")
     t.add_argument("--tune-reps", type=int, default=2, metavar="N",
                    help="autopilot: best-of-N probe repeats (shared-host "
-                        "contention estimator, the bench discipline)")
+                        "contention estimator)")
     t.add_argument("--tune-top", type=int, default=4, metavar="N",
                    help="autopilot: how many top-ranked candidates get a "
                         "measured probe (the rest are recorded "
@@ -226,8 +226,8 @@ def _add_fit_args(parser: argparse.ArgumentParser) -> None:
                         "mesh, records train_dir/fabric_probe.json, and "
                         "every prediction prices from it. PRICING ONLY: "
                         "the resolved knobs being equal, measured trains "
-                        "bit-identical to any pinned fabric (bench "
-                        "config 14's parity gate)")
+                        "bit-identical to any pinned fabric "
+                        "(tests/test_fabric_obs.py)")
     t.add_argument("--codec-tax-ms", type=float, default=None, metavar="MS",
                    help="measured single-chip codec tax for --aggregate "
                         "auto's advisory; default scales the ResNet-18 "
@@ -300,8 +300,8 @@ def _add_fit_args(parser: argparse.ArgumentParser) -> None:
                    help="global wire-byte budget per replica for "
                         "--budget-alloc variance (bytes; 0 = spend exactly "
                         "the uniform allocation's total, the "
-                        "equal-wire-bytes comparison bench config 16 "
-                        "publishes). Large enough and every layer reaches "
+                        "equal-wire-bytes comparison). Large enough "
+                        "and every layer reaches "
                         "the exact dense fallback — the --on-diverge "
                         "densify remedy as the dial's spend-everything "
                         "limit")
@@ -418,7 +418,7 @@ def _add_fit_args(parser: argparse.ArgumentParser) -> None:
                         "from the same checkpoint (tested). Flat "
                         "gather/ring/psum meshes only; conflicts with "
                         "--zero1, --overlap delayed, --aggregate "
-                        "hierarchical, --phase-metrics")
+                        "hierarchical")
     t.add_argument("--elastic-reshard", choices=("live", "reexec"),
                    default="live",
                    help="how a committed membership epoch reshapes the "
@@ -522,11 +522,6 @@ def _add_fit_args(parser: argparse.ArgumentParser) -> None:
                         "= bit-identical trajectories (the probe only "
                         "adds metric outputs). Costs one extra decode + "
                         "one f32 reduction per layer per step")
-    t.add_argument("--phase-metrics", action="store_true", default=False,
-                   help="split the step into separately-jitted phases and "
-                        "log real Comp/Encode/Comm (+ master Gather/Decode) "
-                        "seconds — the reference's per-phase observability; "
-                        "costs fusion, so default off")
     t.add_argument("--profile-dir", type=str, default="",
                    help="capture a jax.profiler device trace of a few "
                         "steady-state steps into this dir (TensorBoard/XProf "
@@ -841,12 +836,6 @@ def _membership_exit(exc: Exception) -> int:
     return MEMBERSHIP_EXIT_CODE
 
 
-# the one pointer every --phase-metrics conflict reject carries (shared
-# with both train loops and the doctor's matrix via utils.tracing, so
-# the surfaces cannot drift)
-from atomo_tpu.utils.tracing import PHASE_METRICS_HINT as _TIMELINE_HINT
-
-
 def _partition(args: argparse.Namespace) -> str:
     """Resolve the weight-update partition knob to one of
     {'replicated', 'zero1', 'sharded_update'} — ``--zero1`` is the legacy
@@ -900,12 +889,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
     if partition == "sharded_update":
         # the sharded-update compatibility matrix, argv-knowable half
         # (the loop re-checks with the resolved mesh)
-        if args.phase_metrics:
-            raise SystemExit(
-                "--partition sharded-update is not supported with "
-                "--phase-metrics (the phased update program assumes a "
-                "replicated optimizer state)"
-            )
         if getattr(args, "elastic", False):
             raise SystemExit(
                 "--elastic runs the replicated update for now (the live "
@@ -965,13 +948,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
                 f"and conflicts with the pinned {', '.join(pinned)}; drop "
                 "the pinned flag(s) to let it choose, or drop "
                 f"--auto {args.auto} to keep your explicit config"
-            )
-        if args.phase_metrics:
-            raise SystemExit(
-                f"--auto {args.auto} cannot compose with --phase-metrics "
-                "(the phased observability mode forces superstep 1 + "
-                "gather — there is nothing left to tune); drop one"
-                + _TIMELINE_HINT
             )
         if not args.train_dir:
             raise SystemExit(
@@ -1036,12 +1012,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
                 f"{plan_flag}: no two-level topology plan — legacy or "
                 "re-encoded — has a delayed form; drop one"
             )
-        if args.phase_metrics:
-            raise SystemExit(
-                "--phase-metrics times blocking phase programs and cannot "
-                "describe the overlapped step; drop one of the flags"
-                + _TIMELINE_HINT
-            )
         if (
             _partition(args) == "zero1"
             and args.max_restarts > 0
@@ -1089,13 +1059,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
                 "at the fabric boundary, which is not bucket-aware yet; "
                 "drop one"
             )
-        if args.phase_metrics:
-            raise SystemExit(
-                "--phase-metrics times a monolithic encode phase program "
-                "and cannot describe the bucket-streamed schedule; drop "
-                "one of the flags"
-                + _TIMELINE_HINT
-            )
     if getattr(args, "sparse_rows", "off") != "off":
         if args.n_devices == 1 and args.sparse_rows == "on":
             # "auto" degrades gracefully in cmd_train (single device ->
@@ -1130,13 +1093,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
                 "--sparse-rows does not compose with --stream-encode: "
                 "the layer-bucket encode pipeline is not "
                 "assignment-aware yet; drop one"
-            )
-        if args.phase_metrics:
-            raise SystemExit(
-                "--sparse-rows is not supported with --phase-metrics "
-                "(the phased programs assume one whole-tree codec "
-                "exchange; there is no row-aware phase split)"
-                + _TIMELINE_HINT
             )
         if (
             args.grad_guard or args.max_grad_norm > 0
@@ -1175,12 +1131,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
             raise SystemExit(
                 "--obs-quality probes the codec's estimator error; dense "
                 "training (--code sgd) has no estimator to probe"
-            )
-        if args.phase_metrics:
-            raise SystemExit(
-                "--obs-quality probes the fused step's encode in-graph; "
-                "--phase-metrics has no fused step — drop one"
-                + _TIMELINE_HINT
             )
         if args.overlap == "delayed":
             raise SystemExit(
@@ -1249,12 +1199,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
                 "its default. --auto controller prices and probes "
                 "exactly that cross term (the +sp+ab candidates) — use "
                 "it; the static pairing stays rejected"
-            )
-        if args.phase_metrics:
-            raise SystemExit(
-                "--budget-alloc variance shapes the fused step's per-leaf "
-                "payloads; --phase-metrics has no fused step"
-                + _TIMELINE_HINT
             )
         if (
             args.on_diverge != "off"
@@ -1328,12 +1272,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
                 "--error-feedback does not compose with --zero1 / "
                 "--partition sharded-update yet: the residual carry is "
                 "untested against the sharded state templates"
-            )
-        if args.phase_metrics:
-            raise SystemExit(
-                "--error-feedback needs the fused step (the residual "
-                "rides its carry); --phase-metrics has no fused step"
-                + _TIMELINE_HINT
             )
         # --auto tune/controller DOES compose with EF now (ISSUE-17
         # satellite): the probe harness builds the residual-carry step,
@@ -1454,12 +1392,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
                 f"--superstep {args.superstep} does not compose with "
                 "--quorum: the host feeds a fresh arrival vector every "
                 "step, which a fused K-step scan cannot consume"
-            )
-        if args.phase_metrics:
-            raise SystemExit(
-                "--quorum needs the fused step (the staleness ring "
-                "rides its carry); --phase-metrics has no fused step"
-                + _TIMELINE_HINT
             )
         if getattr(args, "obs_quality", False):
             raise SystemExit(
@@ -1608,12 +1540,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
                 "membership tracks single replicas — drop --aggregate "
                 "hierarchical / --plan"
             )
-        if args.phase_metrics:
-            raise SystemExit(
-                "--elastic needs the fused step's ok_bits metric; "
-                "--phase-metrics has no membership wiring — drop one"
-                + _TIMELINE_HINT
-            )
         if args.elastic_patience < 1:
             raise SystemExit(
                 f"--elastic-patience {args.elastic_patience}: must be >= 1"
@@ -1652,7 +1578,6 @@ def _argv_preflight(args: argparse.Namespace) -> None:
             aggregate=args.aggregate if multi else None,
             overlap=args.overlap,
             zero1=_partition(args) == "zero1" and multi,
-            phase_metrics=args.phase_metrics,
             num_aggregate=args.num_aggregate if multi else None,
             keep_ckpts=args.keep_ckpts,
             # the loops save every `save_freq or eval_freq` steps — check
@@ -2340,23 +2265,13 @@ def cmd_train(args: argparse.Namespace) -> int:
             )
 
     _warn_dead_flags(args)
-    if args.phase_metrics:
-        warnings.warn(
-            "--phase-metrics is DEPRECATED: it times the four phases as "
-            "separate blocking programs, so it cannot observe any fused "
-            "program we ship (superstep, stream-encode, sparse-rows, "
-            "tune, delayed, elastic, hierarchical are all rejected). "
-            "The replacement is trace-based: run with --profile-dir and "
-            "use `report timeline` to get per-step "
-            "encode/exchange/decode/compute spans of the REAL fused step"
-        )
     if args.bf16:
         # an unverified record from before this round (one v5e chip, never
         # reproduced on the stock TPU backend) had bf16 run the CIFAR CNN
-        # ladder SLOWER than f32 (7.78-7.91 vs 6.50 ms/step on config 2);
-        # the cause was never found (ROADMAP S5). Warn rather than refuse:
+        # ladder SLOWER than f32 (7.78-7.91 vs 6.50 ms/step on ResNet-18);
+        # the cause was never found (ROADMAP S10). Warn rather than refuse:
         # the mode is correct, and matmul-dominated models (the lm
-        # subcommand, bench config 6) are where it is expected to pay.
+        # subcommand) are where it is expected to pay.
         warnings.warn(
             "--bf16 ran slower than f32 for the CIFAR-class CNN recipes in "
             "an unverified v5e record from before this round (7.8 vs 6.5 "
@@ -2407,13 +2322,6 @@ def cmd_train(args: argparse.Namespace) -> int:
         # measurement on the stock backend (ROADMAP S7); the CPU default
         # stays K=1, the per-step loop exactly as before
         superstep = 8 if jax.default_backend() == "tpu" else 1
-    if superstep > 1 and args.phase_metrics:
-        warnings.warn(
-            "--phase-metrics times individual phase programs and cannot "
-            "run under a fused superstep scan; forcing --superstep 1"
-            + _TIMELINE_HINT
-        )
-        superstep = 1
     n_dev = args.n_devices or len(jax.devices())
     if (
         chaos is not None and chaos.config.die_faults
@@ -2466,7 +2374,7 @@ def cmd_train(args: argparse.Namespace) -> int:
         # buffers from jnp constants — never the data iterator or the
         # init seed — so the trajectory is bit-identical to a pinned
         # fabric with the same resolved knobs (the PR-6 probe-isolation
-        # precedent, drilled by bench config 14).
+        # precedent, drilled by tests/test_fabric_obs.py).
         from atomo_tpu.obs.fabric import ensure_fabric_probe
 
         if n_dev <= 1:
@@ -2711,7 +2619,6 @@ def cmd_train(args: argparse.Namespace) -> int:
             aggregate=args.aggregate if n_dev > 1 else None,
             overlap=args.overlap,
             zero1=_partition(args) == "zero1" and n_dev > 1,
-            phase_metrics=args.phase_metrics,
             num_aggregate=args.num_aggregate if n_dev > 1 else None,
             keep_ckpts=args.keep_ckpts,
             save_freq=save_freq,
@@ -2887,7 +2794,6 @@ def cmd_train(args: argparse.Namespace) -> int:
             )
     if n_dev > 1:
         from atomo_tpu.parallel import distributed_train_loop, make_mesh
-        from atomo_tpu.training import stepwise_shrink
 
         if args.aggregate == "auto" and hybrid_plan is not None:
             # the hybrid plan's wire bytes decide — the dense-path byte
@@ -3063,8 +2969,6 @@ def cmd_train(args: argparse.Namespace) -> int:
                 compress_ckpt=args.compress, log_every=args.log_interval,
                 health_timeout=args.health_timeout,
                 guard=guard, chaos=chaos, keep_ckpts=args.keep_ckpts,
-                phase_metrics=args.phase_metrics,
-                lr_fn=stepwise_shrink(args.lr, args.lr_shrinkage, args.shrinkage_freq),
                 profile_dir=args.profile_dir or None,
                 compute_dtype=jnp.bfloat16 if args.bf16 else None,
                 superstep=superstep,
@@ -3749,8 +3653,7 @@ def cmd_report(args: argparse.Namespace) -> int:
     newest ``--profile-dir`` trace into per-step encode/exchange/decode/
     compute spans (the ``named_phase`` scopes inside the fused step),
     join them against metrics.jsonl, and write
-    ``train_dir/timeline_report.json``. This is the replacement the
-    deprecated ``--phase-metrics`` mode points at: it observes the REAL
+    ``train_dir/timeline_report.json``. It observes the REAL
     fused/superstep/stream-encode/hybrid programs.
 
     Both verbs are pure host-side file reads: no jax, no devices, safe
@@ -4035,9 +3938,7 @@ def build_parser() -> argparse.ArgumentParser:
                        help="run (default): the cross-artifact run "
                             "report; timeline: per-step encode/exchange/"
                             "decode/compute spans from a --profile-dir "
-                            "trace, joined against metrics.jsonl — the "
-                            "replacement for the deprecated "
-                            "--phase-metrics mode")
+                            "trace, joined against metrics.jsonl")
     p_rep.add_argument("--train-dir", type=str, default="output/models/",
                        metavar="N", help="the run's artifact directory")
     p_rep.add_argument("--profile-dir", type=str, default="",
@@ -4084,8 +3985,7 @@ def main(argv=None) -> int:
     # jax.config only: nothing ahead of the sub-command body may initialise
     # a backend (a supervising parent must leave the chip to its child).
     # Logged to stderr so verbs with a machine-readable stdout (report
-    # --json consumers, shell pipelines) stay clean — same contract as
-    # bench.py.
+    # --json consumers, shell pipelines) stay clean.
     enable_compile_cache(log_fn=lambda m: print(m, file=sys.stderr, flush=True))
     argv = list(sys.argv[1:] if argv is None else argv)
     known = {"train", "evaluate", "tune", "lm", "report", "-h", "--help"}

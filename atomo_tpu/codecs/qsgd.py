@@ -26,8 +26,8 @@ two interchangeable encode/decode implementations:
     round 4 (VERDICT r3 #4);
   * the fused Pallas kernels (atomo_tpu.ops.qsgd_kernels) — scale,
     stochastic rounding, coding, and packing in one VMEM-resident pass;
-    opt-in via ``use_pallas=True``, still bit-compatible and measured by
-    bench.py each round.
+    opt-in via ``use_pallas=True``, still bit-compatible; no on-chip
+    measurement of it is on record in this round's ledger.
 
 Payloads from either path decode identically on either path (VERDICT r1
 next-round #2). Stochastic rounding uses jax.random uniforms (bit-identical
@@ -143,8 +143,8 @@ class QsgdCodec:
         RECORD (ops.qsgd_kernels.PACK_KERNEL_MEASURED_WINS, resolved by
         pack_kernel_default): the use_pallas precedent codified — the
         kernel is default-ON exactly on TPU device kinds with a recorded
-        measured hardware win (none yet; bench.py measures both paths
-        each round and the first win graduates it by adding one evidence
+        measured hardware win (none yet: no on-chip measurement on
+        record; the first win graduates it by adding one evidence
         entry), and the jnp oracle everywhere else, with every off-TPU
         backend falling back automatically by construction.
         True opts in unconditionally: compiled for the device it is on
@@ -194,8 +194,8 @@ class QsgdCodec:
         kernel's planar-layout grid adds overhead it never wins back.
         Auto-selecting the slower path contradicted the kernel's
         HBM-bandwidth rationale; the kernel stays as an opt-in
-        (use_pallas=True) and bench.py keeps measuring both paths each
-        round so a future kernel win can flip this back with evidence."""
+        (use_pallas=True); a ledger line that shows the kernel winning
+        can flip this back with evidence."""
         if self.use_pallas is None:
             return False
         return bool(self.use_pallas)

@@ -214,8 +214,8 @@ class MeshSpec:
         return {name: size for name, size in self.axes}
 
     def describe(self) -> str:
-        """Human grammar: ``dp4``, ``dp2xici2`` — the string log lines and
-        bench rows print."""
+        """Human grammar: ``dp4``, ``dp2xici2`` — the string log lines
+        print."""
         return "x".join(f"{n}{s}" for n, s in self.axes)
 
     def layout_name(self) -> str:

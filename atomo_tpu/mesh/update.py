@@ -23,9 +23,9 @@ steps. This module finishes the move, per the paper's recipe:
     replicated ones per codec (tested per codec in tests/test_mesh.py).
 
 Per-chip persistent state, P params / N chips (f32, momentum-SGD):
-replicated 8P bytes; zero1 4P + 4P/N; sharded-update 8P/N — the memory
-row bench config 15 (``sharded_update_memory``) measures from the actual
-device buffers rather than asserts.
+replicated 8P bytes; zero1 4P + 4P/N; sharded-update 8P/N — read from
+the actual device buffers by tests/test_mesh.py
+(``test_per_chip_persistent_state_shrinks_by_world_size``).
 
 The carry is ordinary: a :class:`ShardedUpdateState` is a pytree of plain
 arrays, so it rides ``lax.scan`` (superstep), checkpoints (``device_get``

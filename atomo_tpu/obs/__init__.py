@@ -23,8 +23,7 @@ Five layers over the evidence artifacts PRs 5-12 established:
     encode/exchange/decode/compute phase spans parsed from a
     ``--profile-dir`` trace (the ``named_phase`` scopes inside the fused
     step), joined against metrics.jsonl — the live exposed-vs-hidden
-    attribution the legacy blocking ``--phase-metrics`` mode can never
-    produce for shipped programs.
+    attribution of the shipped programs.
   * :mod:`~atomo_tpu.obs.report` — join metrics.jsonl + incidents.jsonl
     + membership.json + tune_decision.json + fabric_probe.json into one
     time-ordered ``run_report.json`` with cross-artifact consistency

@@ -8,7 +8,7 @@ wire is, not what it measures as. ROADMAP open item 2 says it out loud:
 "*measure* the fabric instead of naming it". This module is that probe:
 
   * :func:`probe_fabric` runs fenced ``ppermute`` / ``all_gather``
-    ladders over a size sweep on the real mesh (the bench fence
+    ladders over a size sweep on the real mesh (the probe fence
     discipline — warm, dispatch loop, device->host scalar fence,
     best-of-reps via ``tuning.probe.fenced_seconds_per_call``), one
     ladder per tier: the flat mesh's single fabric, or — when
@@ -34,7 +34,7 @@ value is a PRICING input, never a semantics input. The probe runs on
 deterministic ``jnp``-built buffers — it never touches the training data
 iterator's shuffle RNG or the run's init seed — so ``--fabric measured``
 trains bit-identical to the same resolved knobs under a pinned scalar
-fabric (drilled by bench config 14's in-row parity gate).
+fabric (drilled through the CLI by tests/test_fabric_obs.py).
 
 The probe also arms DRIFT BLAME (tuning.autopilot.OnlineRetuner): when a
 step-time drift alarm fires, the retuner re-runs the cheap

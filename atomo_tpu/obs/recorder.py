@@ -1,7 +1,7 @@
 """FlightRecorder — structured per-step telemetry (``metrics.jsonl``).
 
 Every subsystem built in PRs 5-10 left its evidence in its own artifact
-(incidents.jsonl, membership.json, tune_decision.json, bench JSON) while
+(incidents.jsonl, membership.json, tune_decision.json) while
 the per-step signal that EXPLAINS them — loss, step wall, guard verdicts,
 wire bytes, the aggregate mode actually in effect after a re-tune — lived
 only as ephemeral stdout text. The recorder makes the run itself a

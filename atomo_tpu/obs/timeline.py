@@ -1,16 +1,12 @@
 """Trace-based phase timeline — ``report timeline``.
 
-The legacy ``--phase-metrics`` mode times the four phases as SEPARATE
-blocking programs, which is why its conflict matrix rejects superstep,
-stream-encode, sparse-rows, tune, delayed, elastic, and hierarchical —
-it cannot observe any program we actually ship. The honest phase surface
-for the FUSED step has existed since PR 3: the ``named_phase``
+The phase surface of the FUSED step is the ``named_phase``
 (``jax.named_scope``) regions — ``encode`` / ``exchange`` /
 ``decode_mean`` / ``ring_exchange_decode`` / ``delayed_*`` /
 ``hybrid_exchange`` — survive into the compiled program as HLO op-name
 metadata, and a ``--profile-dir`` trace records every op execution with
 its timing. This module turns that trace into the per-step phase
-timeline ``--phase-metrics`` never could produce:
+timeline:
 
   1. PARSE: ``jax.profiler`` writes ``plugins/profile/<run>/*.xplane.pb``
      (a TSL XSpace protobuf). :func:`parse_xplane` is a minimal

@@ -82,15 +82,15 @@ def interpret_requested() -> bool:
 # resolves default-ON exactly for the device kinds listed here with a
 # measured win, and to the jnp oracle everywhere else — including every
 # non-TPU backend, which stays the automatic fallback unconditionally.
-# A future bench round that records a pack-kernel win on real hardware
+# A PERF_LEDGER.jsonl line that records a pack-kernel win on real hardware
 # graduates the kernel by adding one entry with its evidence pointer; no
 # code-path change, and the decision is auditable in-place.
 PACK_KERNEL_MEASURED_WINS: dict = {
     # device-kind substring (lowercase) -> {"win": bool, "evidence": str}
     #
     # No entry yet: the bucketed pack/unpack kernels (PR 10) have no
-    # real-TPU measurement on record — bench.py measures both paths each
-    # round, and the first recorded win lands here with its artifact.
+    # real-TPU measurement on record (no benchmark cell runs them,
+    # ROADMAP W3); the first recorded win lands here with its ledger line.
 }
 
 

@@ -26,7 +26,6 @@ from atomo_tpu.parallel.replicated import (  # noqa: F401
     make_delayed_oracle_steps,
     make_distributed_eval_step,
     make_distributed_train_step,
-    make_phase_train_steps,
     replicate_state,
     shard_batch,
     shard_superbatch,

@@ -510,7 +510,7 @@ def make_delayed_model_axis_step(
 
     ``oracle_parts=True`` returns ``{"produce", "apply"}`` instead: the
     SAME closures, separately jitted — the two-program eager oracle
-    tests/bench drive host-side to prove the fused program's stale-by-one
+    the tests drive host-side to prove the fused program's stale-by-one
     schedule bit-exact (the replicated family's ``_oracle_parts``
     precedent)."""
     if codec is None:

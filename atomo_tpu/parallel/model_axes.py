@@ -14,7 +14,7 @@ MeshSpec` model-axis layout to a runnable program:
     path), with the dp gradient exchange routed through the compressed
     stack when the caller hands a
     :class:`~atomo_tpu.parallel.lm.DpExchange`;
-  * state/specs/token-sharding come bundled, so a driver (CLI, bench,
+  * state/specs/token-sharding come bundled, so a driver (CLI, benchmark,
     test) asks for a layout by name instead of re-deriving the recipe.
 
 The legacy builders stay importable and bit-identical — this is a

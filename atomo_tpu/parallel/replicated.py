@@ -75,7 +75,7 @@ from atomo_tpu.parallel.common import (
 )
 from atomo_tpu.parallel.compile import compile_step
 from atomo_tpu.parallel.mesh import placement_line, replicated
-from atomo_tpu.utils.tracing import PHASE_METRICS_HINT, named_phase
+from atomo_tpu.utils.tracing import named_phase
 from atomo_tpu.training.resilience import (
     grad_ok,
     masked_mean,
@@ -2246,7 +2246,7 @@ def make_distributed_train_step(
 
         if _oracle_parts:
             # the two-program eager oracle: the SAME closures, separately
-            # jitted — what tests/bench drive host-side to prove the fused
+            # jitted — what the tests drive host-side to prove the fused
             # program's trajectory bit-exact
             def apply_prog(state, payload_x, ok_x, valid, stats_x, ok_now_x):
                 prev = jax.tree_util.tree_map(
@@ -2618,107 +2618,6 @@ def make_delayed_oracle_steps(
     )
 
 
-def make_phase_train_steps(
-    model,
-    optimizer,
-    mesh: Mesh,
-    codec=None,
-    *,
-    axis: str = "dp",
-    augment: bool = False,
-    compute_dtype=None,
-):
-    """Split the SPMD train step into four separately-jitted programs so the
-    host can time each phase — the observability the reference's log line
-    carries (worker Comp/Encode/Comm: src/distributed_worker.py:228-247;
-    master Gather/Decode: src/sync_replicas_master_nn.py:197-221) and which
-    the fused single-program step cannot expose (XLA interleaves everything).
-
-    Returns a dict of jitted callables:
-      comp(state, key, images, labels) -> (grads_x, new_stats, stats)
-      encode(state, key, grads_x)      -> (payloads_x, msg_bytes)   [codec]
-      comm(payloads_x or grads_x)      -> gathered (replicated)
-      update(state, gathered, new_stats) -> new_state
-
-    ``grads_x``/``payloads_x`` carry a leading per-replica axis sharded over
-    ``axis`` so per-chip values survive the program boundary. Opt-in via
-    --phase-metrics: the fused make_distributed_train_step remains the
-    default (faster — phase boundaries cost fusion and add host syncs).
-    """
-    n_dev = mesh.shape[axis]
-
-    def comp(state: TrainState, key, images, labels):
-        my = jax.lax.axis_index(axis)
-        step_key = jax.random.fold_in(key, state.step)
-        k_aug, k_drop, _ = jax.random.split(jax.random.fold_in(step_key, my), 3)
-        if augment:
-            images = augment_batch(k_aug, images)
-        (loss, (logits, new_stats)), grads = jax.value_and_grad(
-            partial(_loss_fn, model, compute_dtype=compute_dtype), has_aux=True
-        )(state.params, state.batch_stats, images, labels, k_drop)
-        prec1, prec5 = accuracy(logits, labels)
-        stats = {
-            "loss": jax.lax.pmean(loss, axis),
-            "prec1": jax.lax.pmean(prec1, axis),
-            "prec5": jax.lax.pmean(prec5, axis),
-        }
-        new_stats = jax.lax.pmean(new_stats, axis)
-        grads_x = jax.tree.map(lambda g: g[None], grads)
-        return grads_x, new_stats, stats
-
-    def encode(state: TrainState, key, grads_x):
-        my = jax.lax.axis_index(axis)
-        step_key = jax.random.fold_in(key, state.step)
-        _, _, k_codec = jax.random.split(jax.random.fold_in(step_key, my), 3)
-        grads = jax.tree.map(lambda g: g[0], grads_x)
-        payloads, stats = encode_tree(codec, k_codec, grads)
-        payloads_x = jax.tree.map(lambda p: p[None], payloads)
-        return payloads_x, jnp.asarray(stats.payload_bytes, jnp.int32)
-
-    def comm(tree_x):
-        local = jax.tree.map(lambda p: p[0], tree_x)
-        return jax.lax.all_gather(local, axis)
-
-    def comm_dense(grads_x):
-        local = jax.tree.map(lambda g: g[0], grads_x)
-        return jax.lax.pmean(local, axis)
-
-    def update(state: TrainState, gathered, new_stats):
-        if codec is None:
-            mean_grads = gathered  # already the pmean-ed dense gradient
-        else:
-            mean_grads = decode_mean_tree(codec, gathered, state.params, n_dev)
-        updates, new_opt = optimizer.update(mean_grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        return TrainState(
-            step=state.step + 1,
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=new_opt,
-        )
-
-    def sm(fn, in_specs, out_specs, donate=()):
-        return compile_step(
-            fn, mesh, in_specs=in_specs, out_specs=out_specs,
-            donate_argnums=donate, check_vma=False,
-        )
-
-    fns = {
-        "comp": sm(
-            comp,
-            (P(), P(), P(axis), P(axis)),
-            (P(axis), P(), P()),
-        ),
-        "comm": sm(comm_dense if codec is None else comm, (P(axis),), P()),
-        "update": sm(update, (P(), P(), P()), P(), donate=(0,)),
-    }
-    if codec is not None:
-        fns["encode"] = sm(
-            encode, (P(), P(), P(axis)), (P(axis), P())
-        )
-    return fns
-
-
 def make_distributed_eval_step(model, mesh: Mesh, axis="dp"):
     """Eval takes only (params, batch_stats) — NOT the whole TrainState —
     so a ZeRO-1 run's dp-sharded optimizer buffers are never re-replicated
@@ -2768,8 +2667,6 @@ def distributed_train_loop(
     log_fn=print,
     log_every: int = 1,
     health_timeout: float = 0.0,
-    phase_metrics: bool = False,
-    lr_fn=None,
     profile_dir: Optional[str] = None,
     profile_steps: int = 3,
     compute_dtype=None,
@@ -2810,12 +2707,6 @@ def distributed_train_loop(
     from the last checkpoint is the recovery story (SURVEY.md §5.3: the
     reference hangs forever on a dead worker).
 
-    ``phase_metrics`` swaps the fused step for the four separately-jitted
-    phase programs of :func:`make_phase_train_steps` and fills the log
-    line's Comp/Encode/Comm fields with real per-phase seconds, plus the
-    reference master line's Gather/Decode (``lr_fn(step)`` supplies its lr
-    column). Default off: the fused program is faster.
-
     ``profile_dir`` captures a jax.profiler device trace (TensorBoard /
     XProf loadable) around ``profile_steps`` steady-state steps — the
     honest way to see encode/decode cost INSIDE the fused program, where
@@ -2824,10 +2715,9 @@ def distributed_train_loop(
     ``superstep`` > 1 runs fused K-step blocks (one dispatch, one metric
     fetch, data double-buffered onto the device per block — see
     training.train_loop's superstep notes; identical boundary-snapped
-    cadence for log/eval/checkpoint/watchdog/chaos). Incompatible with
-    ``phase_metrics`` (whose whole point is host-visible phase
-    boundaries). ``profile_dir`` profiles the second block instead of
-    ``profile_steps`` individual steps.
+    cadence for log/eval/checkpoint/watchdog/chaos). ``profile_dir``
+    profiles the second block instead of ``profile_steps`` individual
+    steps.
 
     ``overlap="delayed"`` runs the stale-by-one overlapped step (see
     make_distributed_train_step): the loop threads a :class:`DelayedState`
@@ -2846,7 +2736,7 @@ def distributed_train_loop(
     in-flight encoded payload too (delayed checkpoints carry it), so the
     rolled-back trajectory is the same program family's uninterrupted
     one. Not supported with ``--zero1`` (the sharded optimizer template
-    cannot be rebuilt mid-run) or ``--phase-metrics``.
+    cannot be rebuilt mid-run).
 
     ``plan`` (topology.schedule.AggregationPlan) selects the two-level
     schedule for ``aggregate='hierarchical'`` — inner psum/cring,
@@ -2866,8 +2756,7 @@ def distributed_train_loop(
     mode flips within the bit-identical gather<->ring operator pair and
     the step program is rebuilt (at the doctor's current chaos
     generation, when armed); the decision — switch or keep — lands in
-    ``incidents.jsonl``. Not supported with ``--phase-metrics`` (no
-    fused step to re-pick).
+    ``incidents.jsonl``.
 
     ``elastic`` (elastic.ElasticConfig) arms membership tracking: the
     step is built with ``track_ok_bits`` + ``survivor_exact`` (requires
@@ -2889,8 +2778,8 @@ def distributed_train_loop(
     at the new world size without charging the restart budget
     (``reshard="reexec"`` keeps that exit path as the only one). Needs a
     checkpoint cadence and a flat blocking aggregate; rejects zero1 /
-    delayed / hierarchical / phase_metrics (the world-size-shaped state
-    those modes carry cannot be resumed across a reshape).
+    delayed / hierarchical (the world-size-shaped state those modes
+    carry cannot be resumed across a reshape).
 
     ``recorder`` (obs.recorder.FlightRecorder) arms the flight recorder:
     one ``metrics.jsonl`` record per step — the superstep loop rides its
@@ -2901,8 +2790,7 @@ def distributed_train_loop(
     cutting the metric timeline in lockstep with the checkpoints. None
     (default): zero new device ops, stdout byte-identical.
     ``track_quality`` arms the in-graph per-layer estimator-quality
-    probes (see make_distributed_train_step); not supported with
-    --phase-metrics (no fused step to probe).
+    probes (see make_distributed_train_step).
 
     ``hybrid`` (sparse.hybrid.HybridPlan) arms the per-layer sparse-row
     hybrid exchange (see make_distributed_train_step, which owns the
@@ -2939,9 +2827,9 @@ def distributed_train_loop(
     dead end — is bit-exact. Trajectories are bit-identical to the
     replicated loop per codec in the canonical decode order (see
     make_distributed_train_step for the fused-SVD/guarded-gather
-    fusion-drift caveat). Rejects --phase-metrics, --elastic,
-    --on-diverge and --sparse-rows honestly (see the in-loop messages);
-    supersedes ``zero1``.
+    fusion-drift caveat). Rejects --elastic, --on-diverge and
+    --sparse-rows honestly (see the in-loop messages); supersedes
+    ``zero1``.
 
     ``quorum`` (quorum.QuorumConfig; ``--quorum Q --staleness K``) runs
     bounded-staleness quorum aggregation: the loop threads a
@@ -2957,9 +2845,8 @@ def distributed_train_loop(
     kill->restart->resume. The conflict matrix (mirrored at CLI
     preflight and in the builder) rejects delayed overlap,
     hierarchical, hybrid, sharded-update/zero1, elastic, EF,
-    num_aggregate, superstep>1, stream-encode, obs-quality,
-    phase-metrics, the doctor and the budget retuner — each with its
-    reason in the raise."""
+    num_aggregate, superstep>1, stream-encode, obs-quality, the doctor
+    and the budget retuner — each with its reason in the raise."""
     from atomo_tpu.training.checkpoint import latest_step, load_checkpoint
     from atomo_tpu.training.resilience import (
         SUPERVISED_ENV,
@@ -2985,12 +2872,6 @@ def distributed_train_loop(
                 "hierarchical schedules — legacy plan or the "
                 "topology re-encoded plans — have no delayed form)"
             )
-        if phase_metrics:
-            raise ValueError(
-                "--phase-metrics times blocking phase programs and cannot "
-                "describe the overlapped step; drop one of the flags"
-                + PHASE_METRICS_HINT
-            )
         if zero1 and resume:
             raise ValueError(
                 "--overlap delayed cannot resume a --zero1 run (the "
@@ -3000,12 +2881,6 @@ def distributed_train_loop(
                 "in-flight payload as a sharded carry leaf and resume "
                 "bit-exact"
             )
-    if tuner is not None and phase_metrics:
-        raise ValueError(
-            "the online re-tuner rebuilds the fused step; --phase-metrics "
-            "has no fused step to re-pick — drop one"
-            + PHASE_METRICS_HINT
-        )
     if error_feedback:
         # loop-level half of the EfState conflict matrix (the builder
         # re-checks; these need the loop's own knobs)
@@ -3040,12 +2915,6 @@ def distributed_train_loop(
                 "--partition sharded-update yet: the residual carry is "
                 "untested against the sharded state templates"
             )
-        if phase_metrics:
-            raise ValueError(
-                "--error-feedback needs the fused step (the residual "
-                "rides its carry); --phase-metrics has no fused step"
-                + PHASE_METRICS_HINT
-            )
         if hybrid is not None or num_aggregate:
             raise ValueError(
                 "--error-feedback does not compose with --sparse-rows / "
@@ -3072,12 +2941,6 @@ def distributed_train_loop(
                 "budget_tuner re-allocates at checkpoint boundaries and "
                 "needs a save cadence (--save-freq or --eval-freq > 0)"
             )
-    if track_quality and phase_metrics:
-        raise ValueError(
-            "--obs-quality probes the fused step's encode in-graph; "
-            "--phase-metrics has no fused step — drop one"
-            + PHASE_METRICS_HINT
-        )
     if track_quality and codec is None:
         raise ValueError(
             "--obs-quality probes the codec's estimator error; dense "
@@ -3091,13 +2954,6 @@ def distributed_train_loop(
                 "stream; the hierarchical boundary re-encode is not "
                 "bucket-aware yet — rejected rather than silently "
                 "degraded)"
-            )
-        if phase_metrics:
-            raise ValueError(
-                "--phase-metrics times a monolithic encode phase program "
-                "and cannot describe the bucket-streamed schedule; drop "
-                "one of the flags"
-                + PHASE_METRICS_HINT
             )
     if elastic is not None:
         if guard is None:
@@ -3123,12 +2979,6 @@ def distributed_train_loop(
                 "in-flight payload, inner-group drop units) that a "
                 "shrink restart cannot resume"
             )
-        if phase_metrics:
-            raise ValueError(
-                "--elastic needs the fused step's ok_bits metric; "
-                "--phase-metrics has no membership wiring — drop one"
-                + PHASE_METRICS_HINT
-            )
         if jax.process_count() > 1:
             raise ValueError(
                 "--elastic is single-process for now: a multi-host "
@@ -3144,7 +2994,6 @@ def distributed_train_loop(
             aggregate=aggregate,
             overlap=overlap,
             zero1=zero1,
-            phase_metrics=phase_metrics,
             num_aggregate=num_aggregate,
             keep_ckpts=keep_ckpts,
             save_freq=save_freq,
@@ -3157,12 +3006,6 @@ def distributed_train_loop(
             raise ValueError(
                 "--partition sharded-update supersedes --zero1 (ZeRO-1 "
                 "is its shard-state-only degenerate point); pass one"
-            )
-        if phase_metrics:
-            raise ValueError(
-                "--partition sharded-update is not supported with "
-                "--phase-metrics (the phased update program assumes a "
-                "replicated optimizer state)" + PHASE_METRICS_HINT
             )
         if elastic is not None:
             raise ValueError(
@@ -3186,7 +3029,7 @@ def distributed_train_loop(
     if quorum is not None:
         # the quorum conflict matrix, loop half (the builder re-checks
         # its subset; these carry the CLI-flag phrasing and the knobs
-        # only the loop knows — elastic/diverge/tuners/phase-metrics)
+        # only the loop knows — elastic/diverge/tuners)
         if codec is None or aggregate not in ("gather", "ring"):
             raise ValueError(
                 "--quorum needs a compressing codec with --aggregate "
@@ -3232,12 +3075,6 @@ def distributed_train_loop(
                 "--quorum does not compose with --error-feedback: a "
                 "dropped-or-stale payload would orphan its residual "
                 "and the telescoping bound no longer holds"
-            )
-        if phase_metrics:
-            raise ValueError(
-                "--quorum needs the fused step (the staleness ring "
-                "rides its carry); --phase-metrics has no fused step"
-                + PHASE_METRICS_HINT
             )
         if superstep > 1:
             raise ValueError(
@@ -3675,101 +3512,52 @@ def distributed_train_loop(
             )
     if superstep < 1:
         raise ValueError(f"superstep must be >= 1, got {superstep}")
-    if phase_metrics:
-        import warnings
+    # the online re-tuner may flip gather<->ring mid-run (the
+    # bit-identical operator pair); every step (re)build — including
+    # the doctor's rollback rebuilds — reads the CURRENT mode from
+    # this cell so a later rollback cannot silently revert a re-tune
+    agg_cell = {"mode": aggregate}
+    # the budget retuner may re-allocate per-leaf ranks mid-run (a
+    # new PerLeafCodec): every step (re)build reads the CURRENT
+    # codec from this cell — the agg_cell discipline applied to the
+    # codec knob, so a later retune rebuild cannot silently revert
+    # a re-allocation
+    codec_cell = {"codec": codec}
 
-        if superstep > 1:
-            raise ValueError(
-                "--phase-metrics times individual phase programs and cannot "
-                "run under a fused superstep scan; drop --phase-metrics or "
-                "use --superstep 1"
-                + PHASE_METRICS_HINT
-            )
-        if guard is not None or chaos is not None:
-            raise ValueError(
-                "--phase-metrics is an observability mode without the "
-                "anomaly-guard/chaos hooks; drop --phase-metrics to use "
-                "--grad-guard / --chaos"
-            )
-        if zero1:
-            raise ValueError(
-                "--zero1 is not supported with --phase-metrics (the phased "
-                "update program assumes a replicated optimizer state)"
-            )
-        if grad_accum > 1:
-            raise ValueError(
-                "--grad-accum is not supported with --phase-metrics (the "
-                "phase split assumes one fused compute program)"
-            )
-        if hybrid is not None:
-            raise ValueError(
-                "--sparse-rows is not supported with --phase-metrics "
-                "(the phased programs assume one whole-tree codec "
-                "exchange; there is no row-aware phase split)"
-                + PHASE_METRICS_HINT
-            )
-        if num_aggregate:
-            warnings.warn(
-                "--phase-metrics uses full aggregation; ignoring --num-aggregate"
-            )
-        if codec is not None and aggregate != "gather":
-            warnings.warn(
-                "--phase-metrics always uses gather aggregation (its phase "
-                "split is gather/decode); ignoring --aggregate "
-                f"{aggregate!r} — drop --phase-metrics to time the psum path"
-            )
-        step_fn = _make_phased_step_fn(
-            model, optimizer, mesh, codec, augment=augment,
-            compute_dtype=compute_dtype,
+    def build_step(generation=0, remedy_cfg=None, densify=False):
+        chaos_now = (
+            chaos.with_generation(generation)
+            if chaos is not None and generation
+            else chaos
         )
-        build_step = None
-    else:
-        # the online re-tuner may flip gather<->ring mid-run (the
-        # bit-identical operator pair); every step (re)build — including
-        # the doctor's rollback rebuilds — reads the CURRENT mode from
-        # this cell so a later rollback cannot silently revert a re-tune
-        agg_cell = {"mode": aggregate}
-        # the budget retuner may re-allocate per-leaf ranks mid-run (a
-        # new PerLeafCodec): every step (re)build reads the CURRENT
-        # codec from this cell — the agg_cell discipline applied to the
-        # codec knob, so a later retune rebuild cannot silently revert
-        # a re-allocation
-        codec_cell = {"codec": codec}
+        return make_distributed_train_step(
+            model, optimizer, mesh,
+            None if densify else codec_cell["codec"],
+            aggregate=agg_cell["mode"], augment=augment,
+            num_aggregate=num_aggregate, compute_dtype=compute_dtype,
+            zero1_specs=zero1_specs, sharded_update=su_specs,
+            grad_accum=grad_accum,
+            inner_axis=inner_axis, guard=guard, chaos=chaos_now,
+            superstep=superstep, ring_bucket_size=ring_bucket_size,
+            overlap="off" if densify else overlap,
+            # densify swaps to dense psum aggregation, which has no
+            # encode to stream — the window runs monolithic
+            stream_encode=False if densify else stream_encode,
+            stream_bucket_bytes=stream_bucket_bytes,
+            remedy=remedy_cfg, track_grad_norm=diverge is not None,
+            track_ok_bits=elastic is not None,
+            # the densify window has no estimator to probe
+            track_quality=False if densify else track_quality,
+            survivor_exact=elastic is not None,
+            plan=plan,
+            # the densify window's dense psum has no per-leaf payload
+            # path: the hybrid plan stands down with the codec
+            hybrid=None if densify else hybrid,
+            error_feedback=error_feedback,
+            quorum=quorum,
+        )
 
-        def build_step(generation=0, remedy_cfg=None, densify=False):
-            chaos_now = (
-                chaos.with_generation(generation)
-                if chaos is not None and generation
-                else chaos
-            )
-            return make_distributed_train_step(
-                model, optimizer, mesh,
-                None if densify else codec_cell["codec"],
-                aggregate=agg_cell["mode"], augment=augment,
-                num_aggregate=num_aggregate, compute_dtype=compute_dtype,
-                zero1_specs=zero1_specs, sharded_update=su_specs,
-                grad_accum=grad_accum,
-                inner_axis=inner_axis, guard=guard, chaos=chaos_now,
-                superstep=superstep, ring_bucket_size=ring_bucket_size,
-                overlap="off" if densify else overlap,
-                # densify swaps to dense psum aggregation, which has no
-                # encode to stream — the window runs monolithic
-                stream_encode=False if densify else stream_encode,
-                stream_bucket_bytes=stream_bucket_bytes,
-                remedy=remedy_cfg, track_grad_norm=diverge is not None,
-                track_ok_bits=elastic is not None,
-                # the densify window has no estimator to probe
-                track_quality=False if densify else track_quality,
-                survivor_exact=elastic is not None,
-                plan=plan,
-                # the densify window's dense psum has no per-leaf payload
-                # path: the hybrid plan stands down with the codec
-                hybrid=None if densify else hybrid,
-                error_feedback=error_feedback,
-                quorum=quorum,
-            )
-
-        step_fn = build_step()
+    step_fn = build_step()
     batch_axes = ("dp", inner_axis) if aggregate == "hierarchical" else "dp"
     eval_fn = (
         make_distributed_eval_step(model, mesh, axis=batch_axes)
@@ -4050,7 +3838,7 @@ def distributed_train_loop(
             state = _distributed_steps(
                 state, step_fn, eval_fn, stream, train_iter, test_iter, mesh,
                 key, timer, n_train, start_step, max_steps, log_every, log_fn,
-                eval_freq, save_freq, train_dir, compress_ckpt, monitor, lr_fn,
+                eval_freq, save_freq, train_dir, compress_ckpt, monitor,
                 profile_dir, profile_steps, batch_axes,
                 guard=guard, chaos=chaos, keep_ckpts=keep_ckpts,
                 rig=rig, incidents=incidents, tuner=tuner, retune=retune,
@@ -4060,61 +3848,10 @@ def distributed_train_loop(
     return state
 
 
-def _make_phased_step_fn(model, optimizer, mesh, codec, *, augment,
-                         compute_dtype=None):
-    """Wrap make_phase_train_steps into a (state, key, si, sl) ->
-    (state, metrics, phase_seconds) callable with host-side phase timing."""
-    import time as _time
-
-    from atomo_tpu.utils.tracing import fence_tree as _fence
-
-    fns = make_phase_train_steps(model, optimizer, mesh, codec, augment=augment,
-                                 compute_dtype=compute_dtype)
-    dense_bytes_cache = {}
-
-    def step_fn(state, key, si, sl):
-        from atomo_tpu.utils.tracing import span
-
-        ph = {}
-        t0 = _time.perf_counter()
-        with span("comp"):
-            grads_x, new_stats, stats = fns["comp"](state, key, si, sl)
-            _fence(stats["loss"])
-        ph["comp"] = _time.perf_counter() - t0
-        if codec is not None:
-            t0 = _time.perf_counter()
-            with span("encode"):
-                wire, msg_bytes = fns["encode"](state, key, grads_x)
-                # the int() fetch IS the fence (blocking scalar transfer)
-                msg_bytes = int(msg_bytes)
-            ph["encode"] = _time.perf_counter() - t0
-        else:
-            wire = grads_x
-            if "dense" not in dense_bytes_cache:
-                dense_bytes_cache["dense"] = tree_nbytes(state.params)
-            msg_bytes = dense_bytes_cache["dense"]
-            ph["encode"] = 0.0
-        t0 = _time.perf_counter()
-        with span("gather"):
-            gathered = fns["comm"](wire)
-            _fence(gathered)
-        ph["gather"] = _time.perf_counter() - t0
-        t0 = _time.perf_counter()
-        with span("decode_update"):
-            state = fns["update"](state, gathered, new_stats)
-            _fence(state.params)
-        ph["decode"] = _time.perf_counter() - t0
-        metrics = dict(stats)
-        metrics["msg_bytes"] = msg_bytes
-        return state, metrics, ph
-
-    return step_fn
-
-
 def _distributed_steps(
     state, step_fn, eval_fn, stream, train_iter, test_iter, mesh, key,
     timer, n_train, start_step, max_steps, log_every, log_fn, eval_freq,
-    save_freq, train_dir, compress_ckpt, monitor, lr_fn=None,
+    save_freq, train_dir, compress_ckpt, monitor,
     profile_dir=None, profile_steps=3, batch_axes="dp",
     guard=None, chaos=None, keep_ckpts=0, rig=None, incidents=None,
     tuner=None, retune=None, elastic_rig=None, recorder=None,
@@ -4123,7 +3860,7 @@ def _distributed_steps(
     import time as _time
 
     from atomo_tpu.training.resilience import retrying_saver
-    from atomo_tpu.utils.metrics import StepMetrics, master_line
+    from atomo_tpu.utils.metrics import StepMetrics
     from atomo_tpu.utils.tracing import ProfileWindow
 
     save_fn = retrying_saver(log_fn, incidents)
@@ -4154,14 +3891,12 @@ def _distributed_steps(
             # line and any staleness_exceeded incidents — then the
             # vector rides into the compiled step as a traced input
             arrivals = quorum_rig.begin_step(step)
-            out = step_fn(state, key, si, sl, arrivals)
+            state, metrics = step_fn(state, key, si, sl, arrivals)
         else:
-            out = step_fn(state, key, si, sl)
+            state, metrics = step_fn(state, key, si, sl)
         if prof.ends_at(step):
-            jax.block_until_ready(out[0].params)
+            jax.block_until_ready(state.params)
             prof.close()
-        state, metrics = out[0], out[1]
-        phases = out[2] if len(out) > 2 else None
         if step == start_step + 1:
             log_fn(placement_line(state, si))
         if monitor is not None:
@@ -4241,9 +3976,6 @@ def _distributed_steps(
                 dataset_size=n_train,
                 loss=float(metrics["loss"]),
                 time_cost=timer.lap(),
-                comp_dur=phases["comp"] if phases else 0.0,
-                encode_dur=phases["encode"] if phases else 0.0,
-                comm_dur=phases["gather"] if phases else 0.0,
                 msg_bytes=int(metrics["msg_bytes"]),
                 prec1=float(metrics["prec1"]),
                 prec5=float(metrics["prec5"]),
@@ -4251,15 +3983,6 @@ def _distributed_steps(
             from atomo_tpu.obs.recorder import emit_worker_line
 
             emit_worker_line(recorder, rec, log_fn)
-            if phases:
-                log_fn(
-                    master_line(
-                        step,
-                        phases["decode"],
-                        float(lr_fn(step)) if lr_fn is not None else 0.0,
-                        phases["gather"],
-                    )
-                )
         if eval_freq and eval_fn is not None and step % eval_freq == 0:
             _distributed_eval(
                 eval_fn, state, test_iter, mesh, batch_axes, step, log_fn

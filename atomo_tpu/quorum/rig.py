@@ -153,8 +153,8 @@ class QuorumRig:
             )
             if exposed > 0:
                 # the rig owns the straggler wait: Q-th-arrival exposure,
-                # not the blocking max — this sleep IS the measured cost
-                # bench config 17 compares against the blocking baseline
+                # not the blocking max — this sleep IS the cost a blocking
+                # step pays as max(delays) (comm_model.quorum_exposed_wait_s)
                 time.sleep(exposed)
             rec = {
                 "kind": "arrival",

@@ -296,7 +296,7 @@ def plan_for_model(
     batch_per_chip: int,
     slots: int,
 ) -> HybridPlan:
-    """Convenience composition the CLI and bench share: probe gradient ->
+    """Convenience composition the CLI and the tests share: probe gradient ->
     measured densities + inferred bounds -> :func:`plan_hybrid`."""
     grads = probe_gradient(model, images, labels)
     return plan_hybrid(

@@ -37,7 +37,7 @@ Design rules, in the house order of importance:
     dropped count in ``payload.overflow``. Callers that claim
     losslessness (the hybrid plan) must size the budget from a true
     worst-case bound (``sparse.hybrid.infer_row_bounds``: a lookup
-    touches at most batch x slots rows), and the bench/tests gate on
+    touches at most batch x slots rows), and the tests gate on
     ``overflow == 0`` rather than trusting the claim.
 
 Wire accounting: ``max_rows x (ncols x itemsize + 4)`` bytes + the 4-byte

@@ -78,10 +78,7 @@ from typing import Callable, Optional, Sequence
 
 # re-export: the supervisor protocol constant lives in utils.tracing so
 # utils.chaos (crashloop's reader side) can share it without an import cycle
-from atomo_tpu.utils.tracing import (  # noqa: F401
-    ATTEMPT_ENV,
-    PHASE_METRICS_HINT,
-)
+from atomo_tpu.utils.tracing import ATTEMPT_ENV  # noqa: F401
 
 SUPERVISED_ENV = "ATOMO_SUPERVISED"  # set by run_supervised on children
 # the trainer's "roll me back from a clean checkpoint" exit: distinct from
@@ -524,7 +521,6 @@ def diverge_conflict(
     aggregate=None,
     overlap=None,
     zero1=False,
-    phase_metrics=False,
     num_aggregate=None,
     keep_ckpts=None,
     save_freq=None,
@@ -570,12 +566,6 @@ def diverge_conflict(
         return (
             "--on-diverge is not supported with --zero1 (the sharded "
             "optimizer template cannot be rebuilt mid-run); drop one"
-        )
-    if phase_metrics:
-        return (
-            "--on-diverge needs the fused step's metric series; "
-            "--phase-metrics has no doctor wiring — drop one"
-            + PHASE_METRICS_HINT
         )
     if remedy == "densify":
         if codec is None:

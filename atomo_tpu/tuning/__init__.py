@@ -8,8 +8,8 @@ package when PR 7 added the performance autopilot:
     probe ladder.
   * :mod:`probe` — the measured-probe runner the autopilot and the grid
     search share: fenced short-run timing of a candidate step program,
-    with every completed row written atomically (the bench ladder's
-    partial-artifact discipline).
+    with every completed row written atomically
+    (``write_json_atomic``).
   * :mod:`autopilot` — ``--auto tune``: predict a ranked candidate list
     from the comm model, probe the top of it, pick the knob vector, write
     the ``tune_decision.json`` decision artifact, and re-tune online when

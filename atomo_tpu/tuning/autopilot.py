@@ -514,8 +514,8 @@ def tune(
             # harness builds replicated-family programs, not model-axis
             # LM steps; these rows are priced from the wire model plus
             # the layout's pre-priced axis-collective floor
-            # (model_comm_s / pipeline_bubble_s), and their measured
-            # evidence is bench's lm_compressed_dp_wire in-row gates
+            # (model_comm_s / pipeline_bubble_s); their byte accounting is
+            # held by tests/test_model_axes.py, their time is not measured
             ladder.record({
                 **pub,
                 "probed": False,
@@ -523,8 +523,7 @@ def tune(
                     "model-axis lm candidates are priced (dp wire + "
                     "axis-collective floor), not probed — the probe "
                     "harness builds replicated-family programs; "
-                    "measured evidence lands in bench "
-                    "lm_compressed_dp_wire"
+                    "no on-chip measurement on record"
                 ),
             })
             continue

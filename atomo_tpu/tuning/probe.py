@@ -1,14 +1,14 @@
 """Shared measured-probe runner — the autopilot's measurement half.
 
 One timing discipline for every short measured probe in the tuning
-package (and bench.py's scenario matrix): warm the compiled program, then
+package: warm the compiled program, then
 time a dispatch loop ended by a device->host scalar fetch
 (utils.tracing.fence_tree — a fence on every backend that also
 returns the value for the finiteness check), best-of-N
 against shared-host contention. Every completed row is ALSO written to a
 JSON artifact atomically as it lands (:class:`ProbeLadder`), so a killed
-or timed-out tune leaves parseable partial evidence — the same
-tmp+rename contract the bench ladder's partial artifact carries.
+or timed-out tune leaves parseable partial evidence
+(utils.tracing.write_json_atomic's tmp+rename contract).
 
 Probes are TRAJECTORY-NEUTRAL by construction: they run on synthetic
 batches drawn from their own PRNG keys and on states initialized from
@@ -31,7 +31,7 @@ class ProbeLadder:
     """Rows-as-they-complete artifact recorder (atomic partial JSON).
 
     ``artifact_path=None`` disables writing (rows still accumulate for
-    the caller). The document shape mirrors bench.py's partial artifact:
+    the caller). The document shape:
     ``{"kind": ..., "meta": {...}, "rows": [...], "complete": bool}``.
     Write failures warn and never crash the run being tuned — evidence is
     best-effort, training is not.
@@ -76,8 +76,8 @@ class ProbeLadder:
 
 def model_init_fn(model, sample):
     """The deterministic param-init closure every byte-budget consumer
-    shares (the CLI's ``--aggregate auto`` resolution, the autopilot, the
-    bench scenario matrix, the README table generator): fixed PRNGKey(0)
+    shares (the CLI's ``--aggregate auto`` resolution, the autopilot,
+    scripts/scenario_table.py): fixed PRNGKey(0)
     for params/dropout over a zeros ``sample``, params extracted. ONE
     definition so the byte budgets those surfaces compute can never
     silently diverge. Meant for jax.eval_shape — never materializes."""
@@ -354,8 +354,8 @@ def probe_candidate(
     m = box.get("m")
     if m is not None and "msg_bytes" in m:
         # the executed program's OWN byte accounting (per-chip message on
-        # the scarcest fabric + dense gradient size) — what bench config
-        # 11 compares the comm model's predictions against
+        # the scarcest fabric + dense gradient size) — what
+        # tests/test_topology.py compares the byte budget against
         import numpy as np
 
         row["measured_msg_bytes"] = int(

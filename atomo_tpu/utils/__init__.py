@@ -4,5 +4,4 @@ from atomo_tpu.utils.metrics import (  # noqa: F401
     StepMetrics,
     Timer,
     accuracy,
-    master_line,
 )

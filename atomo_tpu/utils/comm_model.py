@@ -205,8 +205,7 @@ def quorum_exposed_wait_s(delays, quorum: int) -> float:
     step only waits until Q payloads are present, so its exposure is the
     Q-th smallest delay (quorum.schedule's quorum floor promotes the
     nearest stragglers first, making this exact, not a bound). This is
-    the quantity the autopilot's ``+qK`` candidates are priced by and
-    bench config 17 measures."""
+    the quantity the autopilot's ``+qK`` candidates are priced by."""
     d = sorted(float(x) for x in delays)
     if not d:
         return 0.0
@@ -709,8 +708,8 @@ def enumerate_candidates(
     ``+ab``) of every plain blocking gather/ring candidate, priced from
     the adaptive allocation's per-leaf pairs
     (``budget.allocation_leaf_budgets`` — the clamped-actual sums the
-    wrapped codec's executed program reports, the bench config 16
-    wire-match gate); the sparse-candidate restrictions apply for the
+    wrapped codec's executed program reports, tests/test_budget.py's
+    wire-match); the sparse-candidate restrictions apply for the
     same reason until the delayed/streamed compositions are probed.
     ``+sp`` and ``+ab`` do not cross (the hybrid planner prices the
     dense sub-list at the base codec's budget).
@@ -1075,8 +1074,7 @@ def recommend_for_scenario(
     """Per-scenario recommended config: measured single-chip anchors +
     the analytic fabric term (exactly crossover_report's construction,
     generalized over the whole candidate space INCLUDING the codec axis
-    — the SparCML-style pick the scenario-matrix bench row and the
-    README tables publish).
+    — the SparCML-style pick scripts/scenario_table.py prints).
 
     ``codec_budgets``: codec name -> (dense_bytes, payload_bytes);
     ``measured_ms``: codec name -> measured single-chip ms/step (the
@@ -1101,8 +1099,7 @@ def recommend_for_scenario(
             allow_overlap=allow_overlap,
             # stream-encode candidates (+se) are opt-in here so the
             # published tables' candidate space only widens when the
-            # caller asks (scenario_table.py --stream; bench config 10
-            # keeps its historical space)
+            # caller asks (scenario_table.py --stream)
             allow_stream=allow_stream,
         )
         top = rank_candidates(
@@ -1211,7 +1208,7 @@ def crossover_report(
     ways_list=DEFAULT_WAYS,
     bandwidths=DEFAULT_BANDWIDTHS,
 ) -> dict:
-    """The per-config comm model attached to bench rows (JSON-ready).
+    """The per-config comm model of scripts/comm_crossover.py (JSON-ready).
 
     ``dense_step_s``/``svd_step_s`` are measured single-chip step times
     (compute + codec, no inter-chip comm); the model adds the fabric term.

@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives, and what it did.
 
-One rule, shared by every entry point (cli, bench.py children, and through
-them chip_smoke.py):
+One rule, shared by every entry point (cli, and through it chip_smoke.py
+and the benchmark's adapters):
 
   * ``JAX_COMPILATION_CACHE_DIR`` set -> JAX reads it itself; nothing here
     names a directory.

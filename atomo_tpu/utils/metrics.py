@@ -5,7 +5,7 @@ The reference's observability *is* its print format: the worker line
 (src/tiny_tuning_parser.py:17-19), and `accuracy` (prec@k) is duplicated in
 four files (SURVEY.md §5.5). Here: one accuracy implementation, a structured
 ``StepMetrics`` record (the machine-readable source of truth), and a
-formatter emitting the reference's exact worker/master line shapes so
+formatter emitting the reference's exact worker line shape so
 existing log-scraping tooling keeps working.
 """
 
@@ -81,19 +81,11 @@ class StepMetrics:
         return json.dumps(dataclasses.asdict(self))
 
 
-def master_line(step: int, decode_dur: float, lr: float, gather_dur: float) -> str:
-    """Reference master print format (sync_replicas_master_nn.py:221)."""
-    return "Master: Step: {}, Decode Cost: {}, Cur lr {}, Gather: {}".format(
-        step, decode_dur, lr, gather_dur
-    )
-
-
 class Timer:
-    """Wall-clock span timer for the Comp/Encode/Comm phase metrics.
+    """Wall-clock lap timer behind the worker line's ``Time Cost`` field.
 
-    Note: under jit these spans measure *dispatch+block* time; callers that
-    want per-phase device time should use jax.profiler traces instead
-    (atomo_tpu.utils.tracing).
+    Note: under jit a lap measures *dispatch+block* time; per-phase device
+    time comes from jax.profiler traces (atomo_tpu.utils.tracing).
     """
 
     def __init__(self):

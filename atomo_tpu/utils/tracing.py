@@ -21,8 +21,6 @@ program, so wall-clock phase spans are replaced by:
   * ``profile(dir)``      — a jax.profiler trace capturing device timelines
                             (the honest way to see encode/decode cost inside
                             the fused step).
-  * ``StepTimer``         — per-step host timing with a trailing-window
-                            summary, feeding StepMetrics.time_cost.
   * ``IncidentLog``       — the robustness stack's machine-readable
                             post-mortem artifact (train_dir/incidents.jsonl):
                             every divergence alarm, rollback, retried host
@@ -51,18 +49,6 @@ ATTEMPT_ENV = "ATOMO_RUN_ATTEMPT"
 # fires only at epoch 0 — the re-admitted member comes back healthy) and
 # the elastic coordinator cross-checks it against membership.json.
 MEMBERSHIP_EPOCH_ENV = "ATOMO_MEMBERSHIP_EPOCH"
-
-# The one pointer every --phase-metrics conflict reject carries (CLI
-# preflight, both train loops, the doctor's conflict matrix — defined in
-# this stdlib-only module because all of them import it): the legacy
-# blocking mode is deprecated in favor of the trace-based timeline,
-# which observes exactly the fused programs the conflict matrix refuses
-# to let --phase-metrics near.
-PHASE_METRICS_HINT = (
-    " (deprecated mode — the trace-based replacement observes fused "
-    "programs: run with --profile-dir and use `report timeline`)"
-)
-
 
 # The span vocabulary of the training loops (PERF.md §3). A parent span is
 # one iteration: a superstep block, or one step of a per-step loop; its
@@ -200,11 +186,10 @@ def fence_tree(tree) -> float:
     program that computes it has run. ``jax.block_until_ready`` waits for
     the same work (tests_tpu/test_fence_tpu.py checks that the two agree
     on the chip); this one is kept because it also RETURNS the fetched
-    float, so callers validate finiteness with the same call (bench.py's
-    measurement_valid discipline). One program runs at a time per device,
-    so fencing any output of a program fences the whole program. Shared
-    by the phased step timer, bench.py's phase micro-compares, and the
-    config-9 overlap compare, so the fencing discipline cannot drift."""
+    float, so callers validate finiteness with the same call. One program
+    runs at a time per device, so fencing any output of a program fences
+    the whole program. The autopilot's probe runner (tuning/probe.py) is
+    its caller."""
     import jax
     import jax.numpy as jnp
 
@@ -280,11 +265,11 @@ class ProfileWindow:
 def write_json_atomic(path: str, obj) -> None:
     """Write ``obj`` as JSON via tmp + ``os.replace`` — readers never see a
     torn file, even under SIGKILL mid-write (atomic on POSIX). The ONE
-    artifact-writing discipline shared by the bench ladder's partial
-    artifact, the autopilot's ``tune_decision.json``, and the LR grid's
-    ``lr_grid.json``, so every evidence file survives the failures the
-    robustness stack drills. Raises OSError to the caller — artifact
-    criticality (best-effort vs must-land) is a per-call-site policy."""
+    artifact-writing discipline shared by the autopilot's
+    ``tune_decision.json`` and the LR grid's ``lr_grid.json``, so every
+    evidence file survives the failures the robustness stack drills.
+    Raises OSError to the caller — artifact criticality (best-effort vs
+    must-land) is a per-call-site policy."""
     d = os.path.dirname(path)
     if d:
         os.makedirs(d, exist_ok=True)
@@ -434,27 +419,3 @@ class IncidentLog:
         for r in recs:
             lines.append("  " + format_incident(r))
         return "\n".join(lines)
-
-
-class StepTimer:
-    """Rolling per-step wall timing with window statistics."""
-
-    def __init__(self, window: int = 50):
-        self._t0 = time.perf_counter()
-        self._laps: collections.deque[float] = collections.deque(maxlen=window)
-
-    def lap(self) -> float:
-        now = time.perf_counter()
-        dt = now - self._t0
-        self._t0 = now
-        self._laps.append(dt)
-        return dt
-
-    @property
-    def mean(self) -> float:
-        return sum(self._laps) / len(self._laps) if self._laps else 0.0
-
-    @property
-    def steps_per_sec(self) -> float:
-        m = self.mean
-        return 1.0 / m if m > 0 else 0.0

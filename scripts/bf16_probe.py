@@ -1,14 +1,16 @@
-"""bf16-vs-f32 localization probe (VERDICT r3 weak #2: the --bf16 step
-measured SLOWER than f32 on the v5e — 7.78-7.91 vs 6.50 ms — which inverts
-the MXU's native-bf16 advantage; this script finds where the time goes).
+"""bf16-vs-f32 localization probe (VERDICT r3 weak #2: an unverified
+record from before this round had the --bf16 step SLOWER than f32 on the
+v5e — 7.78-7.91 vs 6.50 ms; no ledger line holds it — which inverts the
+MXU's native-bf16 advantage; this script finds where the time goes).
 
-Five scan-fenced timings on whatever backend jax resolves (meant for the
-real chip; CPU numbers are not probative for the MXU question):
+Five scan-fenced timings on whatever backend jax resolves: it times on the
+host platform unless run on the chip, and only a chip run is probative for
+the MXU question. No on-chip run of it is on record (ROADMAP S9, S10):
 
   matmul_f32 / matmul_bf16   pure (4096x4096)@(4096x4096) — the MXU sanity
                              anchor: bf16 MUST win here or the timing
                              itself is miscounting
-  resnet_f32 / resnet_bf16   the full train-step pair bench.py compares
+  resnet_f32 / resnet_bf16   the full ResNet-18 train step, f32 and bf16
   convnet_f32 / convnet_bf16 the same ResNet-18 trunk with BatchNorm
                              REMOVED (GroupNorm-free plain conv stack):
                              if the bf16 regression disappears here, the
@@ -78,7 +80,7 @@ def main() -> int:
         )
         print(json.dumps({**out, "partial": True}), flush=True)
 
-    # 2) the bench pair: full ResNet-18 train step
+    # 2) the full ResNet-18 train step, both precisions
     model = get_model("resnet18", 10)
     opt = make_optimizer("sgd", lr=0.01, momentum=0.9)
     rng = jax.random.PRNGKey(0)

@@ -12,7 +12,7 @@ lines). That rule used to be remembered; this lint makes it enforced:
     unless it is the ``write_json_atomic`` implementation itself
     (utils/tracing.py) — the package owns every train_dir artifact, so a
     direct dump there is a discipline escape by construction;
-  * in ``scripts/`` and ``bench.py`` a ``json.dump`` whose argument
+  * in ``scripts/`` a ``json.dump`` whose argument
     expressions mention a train_dir path is rejected (those entrypoints
     legitimately write repo-level artifacts/ files with their own
     atomicity story, which stays out of scope — the rule is about the
@@ -95,9 +95,6 @@ def collect_violations(repo: str = REPO) -> list[str]:
             for f in os.listdir(sdir)
             if f.endswith(".py")
         ]
-    bench = os.path.join(repo, "bench.py")
-    if os.path.exists(bench):
-        targets.append(bench)
     violations = []
     for path in sorted(targets):
         violations += scan_file(path, os.path.relpath(path, repo))

@@ -22,9 +22,11 @@ bytes-on-wire becoming time. Results land in artifacts/COMM_CROSSOVER.json
 and feed the analytic crossover tables (atomo_tpu/utils/comm_model.py)
 printed alongside.
 
-Caveats (honest): the host 'fabric' is one machine's memory system shared
-by all 8 virtual devices — absolute times are not TPU ICI/DCN times, and
-the compute side runs on ~1 core. What transfers to hardware is the
+Caveats (honest): this script times on the host platform. The host
+'fabric' is one machine's memory system shared by all 8 virtual devices —
+absolute times are not TPU ICI/DCN times, are not speed numbers, and no
+on-chip measurement of the exchange is on record (ROADMAP S6, S9); the
+compute side runs on ~1 core. What transfers to hardware is the
 *byte-proportionality* of the exchange phase, which is the quantity the
 analytic model parameterizes with real fabric bandwidths.
 
@@ -234,8 +236,7 @@ def main() -> int:
         },
         "measured": results,
         # analytic model seeded with the config-2 anchors (dense 6.50 ms,
-        # svd3 9.01 ms — unverified figures from before this round);
-        # bench.py re-attaches this per config with same-session numbers
+        # svd3 9.01 ms — unverified figures from before this round)
         "model_onchip_config2": crossover_report(
             dense_bytes, payload_bytes, 6.50e-3, 9.01e-3
         ),

@@ -56,7 +56,7 @@ def main() -> int:
                          "loss floor off zero so the gate can discriminate)")
     ap.add_argument("--acc-target", type=float, default=60.0,
                     help="dense prec@1 (percent) the recipe must reach (the "
-                         "stand-in for BASELINE.md's 93% — no real CIFAR-10 "
+                         "stand-in for BASELINE.md's 93%% — no real CIFAR-10 "
                          "here)")
     ap.add_argument("--acc-gap", type=float, default=5.0,
                     help="max dense-svd prec@1 gap (percentage points)")

@@ -1,9 +1,12 @@
 """Attribute the SVD encode tax, phase by phase (VERDICT r4 next-round #2).
 
-Round 3 measured config 2 (ResNet-18 / CIFAR-10 / svd rank 3) at +2.5 ms
-over dense on a v5e chip; the round-4 gram/CholeskyQR2 stack claims most of
-that back but was never measured. This script produces the breakdown that
-decides what (if anything) is left to optimize:
+An unverified record from before this round had ResNet-18 / CIFAR-10 / svd
+rank 3 at +2.5 ms over dense on a v5e chip. The ledger since holds the
+codec at 0.8 ms of a step at batch 1250 (`resnet18-1chip-svd3` 61.693 ms
+against `resnet18-1chip-dense` 60.902; ledger, PR 30). This script times
+on whatever backend jax resolves (the host platform unless run on the
+chip; no on-chip run of it is on record, ROADMAP S9) and produces the
+breakdown that decides what (if anything) is left to optimize:
 
   encode_full       encode_tree on the real ResNet-18 gradient pytree (the
                     production path: bucketed vmap, auto algorithm)
@@ -15,10 +18,10 @@ decides what (if anything) is left to optimize:
   bucket table      per-shape-bucket encode cost (count x shape -> ms), the
                     data a further batching optimization would need
 
-Timing discipline: identical to bench.py — each phase runs STEPS times
-under one lax.scan dispatch with every payload leaf kept live, fenced by a
-device->host scalar fetch, best-of-3 (per-call host dispatch would
-otherwise swamp millisecond-scale phases; see bench.py's docstring).
+Timing discipline: each phase runs STEPS times under one lax.scan
+dispatch with every payload leaf kept live, fenced by a device->host
+scalar fetch, best-of-3 (per-call host dispatch would otherwise swamp
+millisecond-scale phases).
 
 Writes <out>/ENCODE_PROFILE.json + .md. Reference hot spot being
 attributed: the per-layer numpy SVD at src/codings/svd.py:95.
@@ -93,7 +96,7 @@ def main() -> int:
         return tot
 
     def timed(fn, *fn_args) -> float:
-        """ms per call: scan-fenced best-of-3 (bench.py discipline)."""
+        """ms per call: scan-fenced best-of-3."""
 
         @jax.jit
         def many(k, a):
@@ -207,8 +210,8 @@ def main() -> int:
         "# SVD encode-tax breakdown",
         "",
         f"{args.network} rank-{args.rank} gradients on {dev.device_kind} "
-        f"({dev.platform}); {steps}-step scan-fenced best-of-3 "
-        "(bench.py discipline). Reference hot spot: per-layer numpy SVD, "
+        f"({dev.platform}); {steps}-step scan-fenced best-of-3. "
+        "Reference hot spot: per-layer numpy SVD, "
         "src/codings/svd.py:95.",
         "",
         "| phase | ms/step |",

@@ -1,23 +1,15 @@
 #!/usr/bin/env python
 """Generate the README's per-scenario recommended-config tables.
 
-Two sources, one table shape (comm_model.recommend_for_scenario both
-ways, so the README and the bench row can never disagree about what a
-recommendation means):
+Model-only (comm_model.recommend_for_scenario): real byte budgets from
+jax.eval_shape on the CPU backend (cheap — no training, no device work)
++ the stated anchors (utils/comm_model.py — unverified figures from
+before this round, no on-chip measurement on record) scaled by gradient
+size. Deterministic, so the table is reproducible by anyone:
+`python scripts/scenario_table.py`. It orders candidates; it is not a
+speed statement.
 
-  --from-bench PATH   read a bench scenario_matrix row (the last line of
-                      a `python bench.py --config 10` run, or the
-                      bench_partial.json artifact) and print its
-                      measured-anchor recommendations.
-  (default)           model-only: real byte budgets from jax.eval_shape
-                      on the CPU backend (cheap — no training, no
-                      device work) + the stated anchors
-                      (utils/comm_model.py — unverified figures from
-                      before this round) scaled by gradient size. Deterministic, so the README table is
-                      reproducible by anyone:
-                      `python scripts/scenario_table.py`.
-
-Usage: python scripts/scenario_table.py [--ways N] [--from-bench PATH]
+Usage: python scripts/scenario_table.py [--ways N] [--from-probe PATH]
 """
 
 from __future__ import annotations
@@ -70,8 +62,7 @@ def model_only_recs(ways: int, dcn_ways: int = 2,
     renderer serves both). Caveats, stated: the two-tier numbers use the
     SAME size-scaled single-chip anchors as the flat rows plus the
     fabric module's per-hop latency estimates; they order plans, they do
-    not promise wall-clock — bench config 11 carries the measured
-    evidence and its calibration fields.
+    not promise wall-clock (not measured on a chip).
 
     ``fabric_probe`` (``--from-probe``: a ``fabric_probe.json``
     document) replaces the preset fabric columns with the PROBED tiers
@@ -153,10 +144,9 @@ def sparse_recs(ways: int) -> dict:
     recommendations PLUS the per-layer hybrid sparse-row candidate
     (``+sp``), priced from the real hybrid plan's per-leaf wire bytes
     (comm_model.leaf_budget_totals — the sums the executed program
-    reports, bench config 13's wire-match gate). Opt-in so the published
+    reports, tests/test_sparse.py's wire-match). Opt-in so the published
     historical table is stable by default; model-only ordering with the
-    same stated anchors as the flat rows — bench config 13 carries the
-    measured evidence."""
+    same stated anchors as the flat rows."""
     import jax.numpy as jnp
 
     from atomo_tpu.codecs import DenseCodec, QsgdCodec
@@ -237,12 +227,11 @@ def adaptive_recs(ways: int) -> dict:
     synthetic batch (deterministic: fixed keys, no data files), priced
     from the allocation's clamped per-leaf pairs
     (``budget.allocation_leaf_budgets`` — the same sums the wrapped
-    codec's executed program reports, bench config 16's wire-match
-    gate). Opt-in so the published historical table is stable; the +ab
+    codec's executed program reports, tests/test_budget.py's wire-match).
+    Opt-in so the published historical table is stable; the +ab
     wire at the default budget EQUALS the uniform wire (the solver
     spends the same total), so the predicted ms/step ties the flat svd3
-    candidate and the column's value is the variance split it buys —
-    bench config 16 carries the measured Pareto evidence."""
+    candidate and the column's value is the variance split it buys."""
     import jax
     import jax.numpy as jnp
 
@@ -308,18 +297,17 @@ def adaptive_recs(ways: int) -> dict:
 
 def lm_recs(ways: int, tp: int = 2) -> dict:
     """``--lm``: the model-axis LM scenario column — the dp x tp
-    TransformerLM (bench config 19's shape) with the controller's
+    TransformerLM (width 32, depth 2, dp2 x tp2) with the controller's
     ``lm[tp2]+...`` candidates, priced exactly the way
     ``controller.solve`` prices them: the dp exchange over the tp-LOCAL
     gradient shard (each tp shard exchanges its own slice — the same
-    per-leaf accounting bench config 19's byte-match gate pins to the
-    executed program) plus the layout's pre-priced axis-collective
+    per-leaf accounting tests/test_model_axes.py's byte-match pins to
+    the executed program) plus the layout's pre-priced axis-collective
     floor (``comm_model.tp_psum_wire_bytes`` over the fabric). The
     candidate space includes the ``+delayed`` stale-by-one rows
     (``overlap`` column: the exchange priced as ``max(0, chain -
     compute - bubble)`` hidden behind the NEXT step's compute). Opt-in
-    so the published historical table is stable; model-only ordering —
-    bench configs 19/20 carry the measured evidence."""
+    so the published historical table is stable; model-only ordering."""
     import jax
     import jax.numpy as jnp
 
@@ -445,23 +433,19 @@ def main() -> int:
                          "predicted exposure drops to its pipeline tail "
                          "(comm_model.stream_exposed_encode_s). Off by "
                          "default so the published table's historical "
-                         "candidate space is stable; bench config 12 "
-                         "carries the measured streamed-encode evidence")
+                         "candidate space is stable")
     ap.add_argument("--adaptive", action="store_true", default=False,
                     help="add the lenet scenario re-ranked with the "
                          "adaptive variance-budget (+ab) candidates, "
                          "priced from a real allocation's clamped "
                          "per-leaf wire bytes. Off by default so the "
-                         "published table's historical rows are stable; "
-                         "bench config 16 carries the measured Pareto "
-                         "evidence")
+                         "published table's historical rows are stable")
     ap.add_argument("--sparse", action="store_true", default=False,
                     help="add the embedding x zipf scenario with the "
                          "per-layer hybrid sparse-row (+sp) candidate, "
                          "priced from the real plan's per-leaf wire "
                          "bytes. Off by default so the published table's "
-                         "historical rows are stable; bench config 13 "
-                         "carries the measured sparse evidence")
+                         "historical rows are stable")
     ap.add_argument("--lm", action="store_true", default=False,
                     help="add the model-axis LM scenario (dp x tp2 "
                          "TransformerLM) with the controller's lm[tp2] "
@@ -469,12 +453,7 @@ def main() -> int:
                          "included — priced over the tp-LOCAL gradient "
                          "shard + the tp psum floor. Off by default so "
                          "the published table's historical rows are "
-                         "stable; bench configs 19/20 carry the "
-                         "measured evidence")
-    ap.add_argument("--from-bench", type=str, default="",
-                    help="read recommendations from a bench "
-                         "scenario_matrix row / artifact instead of the "
-                         "model-only anchors")
+                         "stable")
     ap.add_argument("--from-probe", type=str, default="",
                     help="price the fabric columns from a "
                          "fabric_probe.json artifact (--fabric measured "
@@ -482,24 +461,6 @@ def main() -> int:
                          "the probed per-chip GB/s, and the two-tier "
                          "row from the probed bandwidths AND latencies")
     args = ap.parse_args()
-    if args.from_bench:
-        with open(args.from_bench) as f:
-            doc = json.load(f)
-        row = doc
-        if "rows" in doc:  # a bench partial artifact: find the matrix row
-            row = next(
-                (r for r in doc["rows"]
-                 if r.get("metric") == "scenario_matrix"),
-                None,
-            )
-        if not row or "recommendations" not in row:
-            print("no scenario_matrix recommendations in that file",
-                  file=sys.stderr)
-            return 1
-        ways = row.get("ways", args.ways)
-        print(render(row["recommendations"], ways,
-                     f"measured anchors, {args.from_bench}"))
-        return 0
     fabric_probe = None
     if args.from_probe:
         with open(args.from_probe) as f:
@@ -519,8 +480,8 @@ def main() -> int:
         if fabric_probe is not None
         else "model-only anchors, unverified figures from before this round; "
              "2-tier rows: topology planner over the same anchors + "
-             "stated latency estimates — ordering only, measured "
-             "evidence is bench config 11"
+             "stated latency estimates — ordering only, not measured "
+             "on a chip"
     )
     print(render(recs, args.ways, source))
     return 0

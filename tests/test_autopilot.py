@@ -503,9 +503,6 @@ def test_preflight_rejects_auto_tune_with_pinned_knobs(pinned):
 
 
 def test_preflight_auto_tune_other_conflicts_and_acceptance():
-    with pytest.raises(SystemExit, match="phase-metrics"):
-        _preflight(["--auto", "tune", "--train-dir", "d",
-                    "--phase-metrics"])
     with pytest.raises(SystemExit, match="train-dir"):
         _preflight(["--auto", "tune", "--train-dir", ""])
     # the clean form passes preflight (superstep 0 = auto is not a pin)

@@ -9,7 +9,7 @@ import pytest
 
 from atomo_tpu.parallel.launch import HealthMonitor, global_mesh, initialize
 from atomo_tpu.training import make_optimizer, stepwise_shrink
-from atomo_tpu.utils.tracing import StepTimer, clear, span, spans
+from atomo_tpu.utils.tracing import clear, span, spans
 
 
 def test_span_records_into_the_ring():
@@ -28,14 +28,6 @@ def test_span_is_safe_anywhere():
         with span("inner", 2):
             pass
     assert [(r[0], r[1], r[2]) for r in spans()] == [("inner", 2, "region"), ("region", None, None)]
-
-
-def test_step_timer_stats():
-    t = StepTimer(window=4)
-    for _ in range(6):
-        time.sleep(0.002)
-        t.lap()
-    assert t.mean > 0 and t.steps_per_sec > 0
 
 
 def test_health_monitor_raises_after_silence():

@@ -10,7 +10,7 @@ Contracts pinned here (atomo_tpu/budget + parallel/replicated EfState):
     the codec's exact dense fallback — ``--on-diverge densify``'s remedy
     as the dial's spend-everything limit.
   * The allocator's predicted per-leaf byte sums equal the executed
-    encode's to the byte (the bench config 16 wire-match gate), under
+    encode's to the byte (the wire-match gate), under
     jit, the superstep scan and the streamed per-bucket encode — the
     per-leaf ranks are STATIC trace-time values.
   * budget_alloc.json round-trips; reuse refuses codec/leaf mismatches;

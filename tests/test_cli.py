@@ -291,13 +291,6 @@ def test_on_diverge_flag_validation():
             "--code", "sgd", "--on-diverge", "densify",
             "--train-dir", "/tmp/nonexistent-unused",
         ])
-    # --phase-metrics has no doctor wiring
-    with pytest.raises(SystemExit):
-        main([
-            "train", "--synthetic", "--n-devices", "2", "--max-steps", "1",
-            "--code", "svd", "--on-diverge", "skip", "--phase-metrics",
-            "--train-dir", "/tmp/nonexistent-unused",
-        ])
     # densify cannot compose with the delayed overlap
     with pytest.raises(SystemExit):
         main([

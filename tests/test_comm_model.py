@@ -1,7 +1,7 @@
 """Analytic comm-cost model invariants (atomo_tpu/utils/comm_model.py).
 
 The measured side lives in scripts/comm_crossover.py (8-device exchange
-timings); these tests pin the model algebra the bench rows embed.
+timings, host platform); these tests pin the model algebra.
 """
 
 import math

@@ -658,11 +658,6 @@ def _main(*extra):
         ),
         (
             ("--elastic", "--grad-guard", "--n-devices", "4",
-             "--phase-metrics"),
-            "ok_bits",
-        ),
-        (
-            ("--elastic", "--grad-guard", "--n-devices", "4",
              "--elastic-patience", "0"),
             "must be >= 1",
         ),

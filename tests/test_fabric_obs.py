@@ -714,33 +714,41 @@ def test_preflight_rejects_measured_without_train_dir():
               "--synthetic", "--n-devices", "1"])
 
 
-def test_phase_metrics_rejects_point_at_report_timeline():
-    """Satellite: the conflict rejects all carry the replacement
-    pointer, and the shared constant keeps the surfaces from drifting."""
+def test_fabric_measured_trains_bit_identical_to_pinned(tmp_path):
+    """PRICING ONLY, through the real CLI: a ``--fabric measured`` run
+    (both tiers probed at start-up, the artifact left complete) and a
+    ``--fabric ici`` run with the same resolved knobs leave bit-identical
+    checkpoints — the probe must not perturb the trajectory."""
     from atomo_tpu.cli import main
-    from atomo_tpu.training.resilience import diverge_conflict
-    from atomo_tpu.utils.tracing import PHASE_METRICS_HINT
+    from atomo_tpu.models import get_model
+    from atomo_tpu.training import create_state, make_optimizer
+    from atomo_tpu.training.checkpoint import load_checkpoint
 
-    assert "report timeline" in PHASE_METRICS_HINT
-    for argv in (
-        ["train", "--auto", "tune", "--train-dir", "x",
-         "--phase-metrics"],
-        ["train", "--overlap", "delayed", "--code", "qsgd",
-         "--n-devices", "4", "--phase-metrics"],
-        ["train", "--stream-encode", "on", "--code", "qsgd",
-         "--n-devices", "4", "--phase-metrics"],
-        ["train", "--sparse-rows", "on", "--n-devices", "4",
-         "--phase-metrics"],
-        ["train", "--obs-quality", "--code", "qsgd", "--phase-metrics"],
-        ["train", "--elastic", "--train-dir", "x", "--grad-guard",
-         "--save-freq", "2", "--n-devices", "4", "--phase-metrics"],
-    ):
-        with pytest.raises(SystemExit, match="report timeline"):
-            main(argv)
-    reason = diverge_conflict(
-        "skip", train_dir="x", phase_metrics=True, save_freq=2,
+    steps, batch = 2, 8
+    common = [
+        "train", "--synthetic", "--dataset", "mnist", "--network", "lenet",
+        "--batch-size", str(batch), "--max-steps", str(steps),
+        "--eval-freq", "0", "--save-freq", str(steps),
+        "--log-interval", "0", "--n-devices", str(N_DEV), "--code", "qsgd",
+        "--quantization-level", "8", "--aggregate", "gather",
+        "--seed", "3", "--momentum", "0.5",
+    ]
+    d_meas, d_pin = str(tmp_path / "measured"), str(tmp_path / "pinned")
+    assert main(common + ["--train-dir", d_meas, "--fabric", "measured",
+                          "--dcn-ways", "2"]) == 0
+    assert main(common + ["--train-dir", d_pin, "--fabric", "ici"]) == 0
+    doc = read_fabric_probe(d_meas)
+    assert doc and doc["complete"]
+    assert {t["label"] for t in doc["tiers"]} == {"ici", "dcn"}
+    tpl = jax.device_get(create_state(
+        get_model("lenet", 10), make_optimizer("sgd", lr=0.01, momentum=0.5),
+        jax.random.PRNGKey(3), jnp.zeros((batch, 28, 28, 1)),
+    ))
+    a = jax.tree_util.tree_leaves(load_checkpoint(d_meas, tpl, step=steps))
+    b = jax.tree_util.tree_leaves(load_checkpoint(d_pin, tpl, step=steps))
+    assert len(a) == len(b) and all(
+        np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(a, b)
     )
-    assert reason and "report timeline" in reason
 
 
 def test_report_timeline_verb_requires_a_trace(tmp_path):

@@ -31,6 +31,8 @@ Contracts pinned here:
     DelayedState resets its carry to the fresh valid=0 value.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,6 +51,7 @@ from atomo_tpu.parallel.model_axes import build_model_axis_program
 from atomo_tpu.training import make_optimizer
 from atomo_tpu.utils.comm_model import (
     candidate_name,
+    codec_leaf_payload_bytes,
     moe_all_to_all_wire_bytes,
     overlap_report,
     pipeline_bubble_fraction,
@@ -446,6 +449,15 @@ def _assert_parity_and_scopes(layout, *, ways=2, n_dev=4):
     assert _leaves_equal(s0.params, s1.params), layout
     assert float(m0["loss"]) == float(m1["loss"]), layout
     assert float(m0["msg_bytes"]) == float(m1["msg_bytes"]), layout
+    # executed wire == the comm model's per-leaf payload sum priced over
+    # the model-axis-LOCAL shard shapes, to the byte, and below dense
+    assert int(m1["msg_bytes"]) == sum(
+        codec_leaf_payload_bytes(
+            CODEC, leaf.sharding.shard_shape(leaf.shape)
+        )
+        for leaf in jax.tree_util.tree_leaves(s1.params)
+    ), layout
+    assert float(m1["msg_bytes"]) < float(m1["dense_bytes"]), layout
     # the timeline anchors survive into the scoped program's HLO
     toks = scoped.shard_tokens(_tokens(1))
     txt = scoped.step.lower(
@@ -523,11 +535,10 @@ def test_tp_family_stream_encode_parity():
 #
 # The fill-the-bubble family: the dp exchange consumes the PREVIOUS
 # step's encoded payload while this step's backward runs. Budget
-# discipline: the dp-tp gather anchor drill and the replicated-degenerate
-# (pure-dp) oracle parity drill are the tier-1 witnesses; ring and the
-# dp-pp family ride the slow lane. The dp-pp end-to-end gates (off-HLO
-# byte identity on the pipelined family, equal wire, bit-exact carry
-# resume) run in bench config 20 / bench_smoke check 18.
+# discipline: the dp-tp gather anchor drill, the replicated-degenerate
+# (pure-dp) oracle parity drill and the dp-pp gates
+# (test_pp_family_delayed_gates) are the tier-1 witnesses; ring and the
+# dp-pp anchors ride the slow lane.
 
 
 def _delayed(aggregate="gather"):
@@ -611,6 +622,102 @@ def test_dp_family_delayed_oracle_parity():
 
     assert _leaves_equal(d.train, train)
     assert _leaves_equal(d.carry.payload, payload)
+
+
+@functools.lru_cache(maxsize=None)
+def _pp_program(kind):
+    """dp2 x pp2 programs the gates below share (one build and one
+    compile each): blocking, explicit overlap="off", delayed, a second
+    delayed build (the restarted process) and the two-program oracle."""
+    exchange = {
+        "blocking": DpExchange(aggregate="gather"),
+        "off": DpExchange(aggregate="gather", overlap="off"),
+    }.get(kind, _delayed())
+    spec = MeshSpec.from_layout("dp-pp", 4, 2)
+    return build_model_axis_program(
+        spec, CFG, _opt(), jax.random.PRNGKey(0), CODEC,
+        num_microbatches=2, exchange=exchange,
+        oracle_parts=kind == "oracle",
+    )
+
+
+def _pp_steps(prog, state, lo, hi, key):
+    state = jax.tree_util.tree_map(jnp.copy, state)  # the step donates it
+    for i in range(lo, hi):
+        state, m = prog.step(
+            state, jax.random.fold_in(key, i),
+            prog.shard_tokens(_tokens(100 + i)),
+        )
+    return state, m
+
+
+@pytest.mark.parametrize(
+    "gate", ["off_hlo", "equal_wire", "oracle_parity", "carry_resume"]
+)
+def test_pp_family_delayed_gates(gate, tmp_path):
+    """The pipelined family, where the bubble the carry fills exists:
+    ``overlap="off"`` lowers the blocking program byte for byte; delayed
+    moves the blocking step's bytes; the fused delayed step replays the
+    host-driven produce/apply oracle bit for bit (params AND carry); and
+    T steps + save + fresh build + load + place + T steps equals 2T
+    uninterrupted steps bit for bit (params AND carry)."""
+    T = 2
+    key = jax.random.PRNGKey(42)
+    if gate == "off_hlo":
+        plain, off = _pp_program("blocking"), _pp_program("off")
+        toks = plain.shard_tokens(_tokens(1))
+        assert plain.step.lower(plain.state, key, toks).as_text() == (
+            off.step.lower(off.state, key, toks).as_text()
+        )
+        return
+    fused = _pp_program("delayed")
+    if gate == "equal_wire":
+        blocking = _pp_program("blocking")
+        _, md = _pp_steps(fused, fused.state, 0, 2, key)
+        _, mb = _pp_steps(blocking, blocking.state, 0, 1, key)
+        assert float(md["msg_bytes"]) == float(mb["msg_bytes"])
+        assert 0.0 < float(md["msg_bytes"]) < float(md["dense_bytes"])
+    elif gate == "oracle_parity":
+        oracle = _pp_program("oracle")
+        train = oracle.state.train
+        payload = oracle.state.carry.payload
+        valid = oracle.state.carry.valid
+        for i in range(2 * T):
+            k = jax.random.fold_in(key, i)
+            toks = oracle.shard_tokens(_tokens(100 + i))
+            new_payload, _ = oracle.step["produce"](train, k, toks)
+            train, _ = oracle.step["apply"](train, payload, valid)
+            payload, valid = new_payload, jnp.float32(1.0)
+        d, _ = _pp_steps(fused, fused.state, 0, 2 * T, key)
+        assert _leaves_equal(d.train, train)
+        assert _leaves_equal(d.carry.payload, payload)
+    else:
+        from jax.sharding import NamedSharding
+
+        from atomo_tpu.parallel.lm import place_model_axis_carry
+        from atomo_tpu.parallel.replicated import DelayedState
+        from atomo_tpu.training.checkpoint import (
+            load_checkpoint,
+            save_checkpoint,
+        )
+
+        whole, _ = _pp_steps(fused, fused.state, 0, 2 * T, key)
+        half, _ = _pp_steps(fused, fused.state, 0, T, key)
+        save_checkpoint(str(tmp_path), half)
+        fresh = _pp_program("restarted")
+        host = load_checkpoint(str(tmp_path), jax.device_get(fresh.state))
+        resumed = DelayedState(
+            train=jax.tree_util.tree_map(
+                lambda leaf, sp: jax.device_put(
+                    leaf, NamedSharding(fresh.mesh, sp)
+                ),
+                host.train, fresh.state_specs,
+            ),
+            carry=place_model_axis_carry(fresh.mesh, host.carry),
+        )
+        resumed, _ = _pp_steps(fresh, resumed, T, 2 * T, key)
+        assert _leaves_equal(whole.train.params, resumed.train.params)
+        assert _leaves_equal(whole.carry.payload, resumed.carry.payload)
 
 
 @pytest.mark.slow

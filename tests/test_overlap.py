@@ -351,10 +351,6 @@ def test_delayed_loop_validations():
         distributed_train_loop(model, opt, mesh, it, codec=None,
                                aggregate="psum", overlap="delayed",
                                max_steps=1)
-    with pytest.raises(ValueError, match="phase-metrics"):
-        distributed_train_loop(model, opt, mesh, it, codec=QSGD,
-                               aggregate="gather", overlap="delayed",
-                               phase_metrics=True, max_steps=1)
     with pytest.raises(ValueError, match="zero1"):
         distributed_train_loop(model, opt, mesh, it, codec=QSGD,
                                aggregate="gather", overlap="delayed",

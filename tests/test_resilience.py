@@ -569,9 +569,6 @@ def test_diverge_conflict_matrix():
     assert diverge_conflict("densify", **ok) is None
     assert "train_dir" in diverge_conflict("skip", train_dir="")
     assert "zero1" in diverge_conflict("skip", train_dir="/t", zero1=True)
-    assert "phase-metrics" in diverge_conflict(
-        "skip", train_dir="/t", phase_metrics=True
-    )
     assert "compressing" in diverge_conflict("densify", train_dir="/t")
     for kw, frag in [
         (dict(overlap="delayed"), "delayed"),

@@ -406,7 +406,7 @@ def test_hybrid_off_is_byte_identical_and_adds_no_ops():
 
 
 def test_hybrid_gather_trajectory_bit_matches_all_dense():
-    """The trajectory-level lossless contract (bench config 13's gate):
+    """The trajectory-level lossless contract:
     with the lossless DenseCodec on the tower, hybrid-vs-off gather
     trajectories are bit-identical — the row path changed the wire, not
     one bit of arithmetic."""
@@ -645,7 +645,6 @@ def test_preflight_conflict_matrix():
           "--plan", "legacy"], "re-encode"),
         (base + ["--overlap", "delayed"], "delayed"),
         (base + ["--stream-encode", "on"], "assignment-aware"),
-        (base + ["--phase-metrics"], "phase"),
         (base + ["--grad-guard"], "skip-and-rescale"),
         (base + ["--num-aggregate", "2"], "num-aggregate"),
         (["--sparse-rows", "on", "--code", "qsgd", "--n-devices", "4",

@@ -21,7 +21,7 @@ knob, utils/comm_model's pipeline accounting):
     aggregation-operator contract: bit-identical to gather's canonical
     (unfused) decode order.
   * The conflict matrix rejects stream x {dense, psum, hierarchical,
-    plan, phase-metrics, single-device} with the stated reasons.
+    plan, single-device} with the stated reasons.
   * comm_model: exposed encode becomes the pipeline tail
     (stream_exposed_encode_s), overlap_report states it, +se candidates
     enter the autopilot space with a reduced predicted encode term.
@@ -474,8 +474,6 @@ def test_preflight_conflict_matrix():
         (["--stream-encode", "on", "--code", "qsgd", "--n-devices", "4",
           "--aggregate", "hierarchical", "--plan", "legacy"],
          "bucket-aware"),
-        (["--stream-encode", "on", "--code", "qsgd", "--n-devices", "4",
-          "--phase-metrics"], "phase"),
         (["--stream-encode", "on", "--code", "qsgd", "--n-devices", "4",
           "--auto", "tune", "--train-dir", "/tmp/x"], "pinned"),
     ]

@@ -470,7 +470,7 @@ def test_distributed_guard_rescale_mid_scan_matches_sequential():
 def test_distributed_train_loop_superstep_runs_and_logs(tmp_path):
     """distributed_train_loop with K=3 over 6 steps: boundary-snapped log
     lines (2 with log_every=2 -> boundaries 3 and 6), checkpoints at
-    boundaries, phase-metrics refusal."""
+    boundaries."""
     from atomo_tpu.parallel import distributed_train_loop, make_mesh
 
     model, opt = _model_opt()
@@ -486,13 +486,6 @@ def test_distributed_train_loop_superstep_runs_and_logs(tmp_path):
     assert [int(l.split("Step: ")[1].split(",")[0]) for l in worker_lines] == [3, 6]
     assert list_steps(str(tmp_path)) == [3, 6]
     assert int(jax.device_get(state.step)) == 6
-
-    with pytest.raises(ValueError, match="phase-metrics"):
-        distributed_train_loop(
-            model, opt, mesh, _make_iter(), max_steps=2,
-            codec=QsgdCodec(bits=4, bucket_size=128),
-            superstep=2, phase_metrics=True,
-        )
 
 
 # ------------------------------------------------------------ perf sweep

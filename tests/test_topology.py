@@ -20,15 +20,10 @@ Contracts being pinned:
     multi-tier meshes.
 """
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from atomo_tpu.codecs import DenseCodec, QsgdCodec, SvdCodec
 from atomo_tpu.parallel.mesh import make_mesh
@@ -229,19 +224,59 @@ def _fake_grads(c, key):
     }
 
 
-def _plan_parity(codec, pname, n_outer=2, n_inner=2):
-    from bench import two_tier_parity
+def _plan_parity(codec, pname, n_outer=2, n_inner=2, bucket_size=256):
+    """The executed two-level operator (planned_two_level_mean, outer
+    gather forced to the canonical unfused decode order) against the
+    canonical decode-order oracle in SPMD form (two_level_canonical_mean),
+    SPMD program against SPMD program, over the same per-chip gradients
+    and keys: True when every leaf is bit-identical."""
+    from jax.sharding import PartitionSpec as P
+
+    from atomo_tpu.topology.execute import two_level_canonical_mean
 
     mesh = make_mesh(
         n_outer * n_inner, axes=(("dcn", n_outer), ("ici", n_inner))
     )
+    axis, inner_axis = mesh.axis_names
+    plan = plan_from_name(pname)
     key = jax.random.PRNGKey(3)
+    step_key = jax.random.PRNGKey(11)
     grads_by_chip = [
         jax.device_get(_fake_grads(c, key)) for c in range(n_outer * n_inner)
     ]
-    return two_tier_parity(
-        mesh, codec, plan_from_name(pname), grads_by_chip,
-        jax.random.PRNGKey(11), n_outer, n_inner, bucket_size=256,
+
+    def make_fn(canonical):
+        def fn(x):
+            o = jax.lax.axis_index(axis)
+            my = o * n_inner + jax.lax.axis_index(inner_axis)
+            grads = jax.lax.switch(
+                my, [lambda c=c: grads_by_chip[c]
+                     for c in range(len(grads_by_chip))],
+            )
+            ki = inner_codec_key(step_key, my)
+            ko = outer_codec_key(step_key, o)
+            kw = dict(axis=axis, inner_axis=inner_axis,
+                      n_inner=n_inner, n_outer=n_outer)
+            if canonical:
+                return two_level_canonical_mean(
+                    codec, plan, grads, ki, ko, **kw
+                )
+            mean, _, _, _ = planned_two_level_mean(
+                codec, plan, grads, ki, ko, **kw,
+                ring_bucket_size=bucket_size, unfused_decode=True,
+            )
+            return mean
+
+        return fn
+
+    def run(fn):
+        return jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=(P((axis, inner_axis)),), out_specs=P(),
+            check_vma=False,
+        ))(jnp.zeros((n_outer * n_inner,)))
+
+    return _leaves_equal(
+        jax.device_get(run(make_fn(False))), jax.device_get(run(make_fn(True)))
     )
 
 
@@ -496,20 +531,34 @@ def test_probe_candidate_runs_hierarchical_plan():
     program's own byte accounting."""
     from atomo_tpu.models import get_model
     from atomo_tpu.training import make_optimizer
-    from atomo_tpu.tuning.probe import probe_candidate
+    from atomo_tpu.tuning.probe import (
+        byte_budget,
+        model_init_fn,
+        probe_candidate,
+    )
 
+    model = get_model("lenet", 10)
+    codec = QsgdCodec(bits=8, bucket_size=512)
     row = probe_candidate(
         {"aggregate": "hierarchical", "plan": "psum+ring",
          "overlap": "off", "superstep": 1, "name": "hier[psum+ring]"},
-        model=get_model("lenet", 10),
+        model=model,
         optimizer=make_optimizer("sgd", lr=0.01, momentum=0.9),
-        codec=QsgdCodec(bits=8, bucket_size=512),
+        codec=codec,
         n_dev=4, sample_shape=(28, 28, 1), num_classes=10, batch=8,
         steps=2, reps=1, dcn_ways=2,
     )
     assert row["probed"] and row["sync_ok"]
     assert row["measured_ms_per_step"] > 0
     assert 0 < row["measured_msg_bytes"] < row["measured_dense_bytes"]
+    # the bytes the comm model prices each tier from (plan_wire_bytes over
+    # the eval_shape budget) are the executed program's own: the dense
+    # gradient on the inner psum, the encoded payload on the outer ring
+    dense_b, payload_b = byte_budget(
+        codec, model_init_fn(model, jnp.zeros((1, 28, 28, 1)))
+    )
+    assert row["measured_dense_bytes"] == dense_b
+    assert row["measured_msg_bytes"] == payload_b
     with pytest.raises(ValueError, match="dcn_ways"):
         probe_candidate(
             {"aggregate": "hierarchical", "plan": "psum+ring",
