@@ -3490,6 +3490,7 @@ def cmd_lm(args: argparse.Namespace) -> int:
         )
         return ce, extra_note
 
+    import collections
     import math
     import time
 
@@ -3579,48 +3580,74 @@ def cmd_lm(args: argparse.Namespace) -> int:
         span,
     )
 
-    save_freq = args.save_freq
+    # The loop keeps ONE step in flight: iteration j launches step j+1 and
+    # only then fetches the loss of step j and does step j's boundary work
+    # (recorder, log line), so the device has its next execution queued when
+    # one ends and the log runs one step behind the device. `state` is
+    # donated into a launch, so an iteration whose own state is read DRAINS
+    # instead, it fetches its loss with nothing launched after it: the first
+    # (the placement line), one with an evaluation or a save due, the last,
+    # and the last of the --profile-dir window; the iteration after a drain
+    # launches two. The `step` span, its `fetch` and its `boundary` carry
+    # the step they report, `next_batch` and `dispatch` the step they launch.
+    # `Time Cost:` is the wall time from the loss before to this one: what a
+    # step costs. An exception out of the loop (the benchmark closes its
+    # window by raising through `print`) waits for nothing. The loop stays
+    # in this function: moved into one of its own, the step's first call and
+    # the jits after it lowered a second slower on the v5e's host (PERF.md
+    # §6, PR 32), and `setup_s` is an end-to-end metric.
     prof = ProfileWindow(args.profile_dir or None, print, recorder)
     clear_spans()  # the ring holds this loop's iterations
-    for i in range(start + 1, args.max_steps + 1):
-        with span(STEP, i):
-            t0 = time.time()
-            if i == start + 2:  # step 1 is dominated by compilation
-                prof.open(i, i + PROFILE_STEPS - 1)
-            with span(NEXT_BATCH):
-                batch = next_batch()
-            with span(DISPATCH):
-                state, metrics = step(state, jax.random.fold_in(key, i), batch)
+    launched = start  # the last step handed to the device
+    in_flight = collections.deque()  # metrics of the steps launched and not yet reported
+    arrived = time.time()  # when the last loss came back
+    for j in range(start + 1, args.max_steps + 1):
+        with span(STEP, j):
+            if j == start + 2:  # step 1 is dominated by compilation
+                prof.open(j, j + PROFILE_STEPS - 1)
+            eval_due = args.eval_freq and j % args.eval_freq == 0
+            save_due = args.train_dir and (
+                (args.save_freq and j % args.save_freq == 0) or j == args.max_steps
+            )
+            window_ends = prof.ends_at(j)
+            drain = j == start + 1 or eval_due or save_due or window_ends
+            # launch through step j, and one step more unless j's state is read
+            while launched < min(j + (not drain), args.max_steps):
+                launched += 1
+                with span(NEXT_BATCH, launched):
+                    batch = next_batch()
+                with span(DISPATCH, launched):
+                    state, metrics = step(state, jax.random.fold_in(key, launched), batch)
+                in_flight.append(metrics)
+            metrics = in_flight.popleft()
             with span(FETCH):
-                loss = float(metrics["loss"])  # device sync: honest step timing
-            if prof.ends_at(i):
+                loss = float(metrics["loss"])  # waits for step j, and for no later one
+            now = time.time()
+            wall, arrived = now - arrived, now  # what a step costs: loss to loss
+            if window_ends:
                 prof.close()
             with span(BOUNDARY):
-                if i == start + 1:
+                if j == start + 1:
                     print(placement_line(state, batch), flush=True)
                 if recorder is not None:
-                    recorder.record_block(
-                        i, jax.device_get(metrics), wall_s=time.time() - t0
-                    )
-                if i % args.log_interval == 0 or i == args.max_steps:
+                    recorder.record_block(j, jax.device_get(metrics), wall_s=wall)
+                if j % args.log_interval == 0 or j == args.max_steps:
                     print(
-                        f"LM: Step: {i}, Layout: {layout}({spec.describe()}), "
+                        f"LM: Step: {j}, Layout: {layout}({spec.describe()}), "
                         f"Loss: {loss:.4f}, PPL: {math.exp(min(loss, 30.0)):.2f}, "
-                        f"Time Cost: {time.time() - t0:.4f}, "
+                        f"Time Cost: {wall:.4f}, "
                         f"Msg(MB): {float(metrics['msg_bytes']) / 1e6:.4f}, "
                         f"Dense(MB): {float(metrics['dense_bytes']) / 1e6:.4f}",
                         flush=True,
                     )
-                if args.eval_freq and i % args.eval_freq == 0:
+                if eval_due:  # drained: `state` is the state after step j
                     vl, vl_extra = eval_ppl(state)
                     print(
-                        f"LM Validation: Step: {i}, Loss: {vl:.4f}, "
+                        f"LM Validation: Step: {j}, Loss: {vl:.4f}, "
                         f"PPL: {math.exp(min(vl, 30.0)):.2f}" + vl_extra,
                         flush=True,
                     )
-                if args.train_dir and (
-                    (save_freq and i % save_freq == 0) or i == args.max_steps
-                ):
+                if save_due:
                     from atomo_tpu.training.checkpoint import save_checkpoint
 
                     save_checkpoint(args.train_dir, state, compress=args.compress)

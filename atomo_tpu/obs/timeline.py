@@ -477,6 +477,25 @@ def host_spans(space: dict) -> list[dict]:
     return sorted(out, key=lambda sp: sp["start_us"])
 
 
+def ran_ahead(spans: list[dict]) -> tuple[int, int]:
+    """``(n, m)``: of the ``m`` iterations (parent spans) among ``spans``,
+    the ``n`` that hold a ``dispatch`` of a later step than their own, so
+    that launched the next step before they asked for their own loss
+    (``cmd_lm`` keeps one step in flight). An iteration without one
+    drained; the other loops' always do."""
+    parents = [sp for sp in spans if sp["name"] in tracing.PARENT_SPANS]
+    launches = [sp for sp in spans if sp["name"] == tracing.DISPATCH]
+    ahead = sum(
+        any(
+            p["start_us"] <= d["start_us"] and d["end_us"] <= p["end_us"]
+            and None not in (d["step"], p["step"]) and d["step"] > p["step"]
+            for d in launches
+        )
+        for p in parents
+    )
+    return ahead, len(parents)
+
+
 def idle_by_span(busy: list, spans: list[dict], lo: float, hi: float) -> dict:
     """``{span name: idle us}`` over ``[lo, hi]``: every stretch of
     :data:`MIN_IDLE_GAP_US` or more that no interval of ``busy`` covers,
@@ -710,6 +729,7 @@ def build_timeline(
          "ms": round((sp["end_us"] - sp["start_us"]) / 1e3, 4)}
         for sp in hspans
     ]
+    doc["ran_ahead"] = list(ran_ahead(hspans))
     if executions:
         # idle on the step's reference device line: nothing of ANY program
         # runs there, first execution's start to the last one's end
@@ -882,6 +902,8 @@ def summarize_timeline(doc: dict) -> str:
             f"{n} {len(by_name[n])} x {sorted(by_name[n])[len(by_name[n]) // 2]}"
             for n in HOST_SPANS if n in by_name
         ))
+    if doc.get("ran_ahead", [0, 0])[1]:
+        lines.append("  ran ahead: {} of {} iterations".format(*doc["ran_ahead"]))
     if doc.get("idle_by_span_ms") is not None:
         lines.append(
             f"  device idle {doc['device_idle_ms']} ms of "

@@ -64,6 +64,9 @@ PUT = "put"  # jnp.asarray + device_put of the block (returns once enqueued)
 NEXT_BATCH = "next_batch"  # producing and placing one batch (per-step loops)
 FETCH = "fetch"  # the host waiting on the device, and the result coming back
 BOUNDARY = "boundary"  # everything after the fetch: recorder, doctor, log, eval, save
+# cmd_lm keeps one step in flight: its parent span, FETCH and BOUNDARY carry
+# the step they report, NEXT_BATCH and DISPATCH the step they launch, which is
+# the one after unless the iteration drained
 PARENT_SPANS = (BLOCK, STEP)
 
 # (name, step, parent, t0, t1) on time.perf_counter; str/int/float only, so
@@ -93,7 +96,8 @@ def _annotation(name: str, step):
 
 class span:
     """Host span ``name`` of iteration ``step`` (the optimizer step the
-    iteration's dispatch ends on; a child without one takes its parent's),
+    iteration's dispatch ends on, in ``cmd_lm`` the step it reports; a child
+    without one takes its parent's),
     recorded on two clocks at once: as a jax.profiler annotation, which
     costs a flag check while no profiler session runs and lands on the
     device trace's clock while one does, and as one flat record in the
@@ -234,7 +238,9 @@ class ProfileWindow:
 
     def ends_at(self, step: int) -> bool:
         """True while a capture is open whose last step ``step`` reaches:
-        the caller fences that step's dispatch, then calls :meth:`close`."""
+        the caller fences that step's dispatch (and launches no later one
+        first, so the capture ends on a whole step), then calls
+        :meth:`close`."""
         return self._ctx is not None and step >= self.last_step
 
     def open(self, first_step: int, last_step: int, what: str = "steps") -> None:

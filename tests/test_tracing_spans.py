@@ -179,16 +179,27 @@ def test_per_step_train_loop_emits_step_spans_and_fetches_only_when_due():
 
 
 def test_lm_loop_emits_step_spans():
+    """One of each span for every step, under that step's number: `fetch` and
+    `boundary` in the step's own iteration, `next_batch` and `dispatch` in
+    the iteration before, ahead of its fetch (one step in flight), but for
+    the first step's and the second's: the first drains."""
     from atomo_tpu.cli import main
 
-    assert main(LM + ["--max-steps", "3", "--log-interval", "1"]) == 0
+    assert main(LM + ["--max-steps", "5", "--log-interval", "1"]) == 0
     its = _iterations(spans(), STEP)
-    assert sorted(its) == [1, 2, 3]
+    assert sorted(its) == [1, 2, 3, 4, 5]
     for step, kids in its.items():
         assert kids == {NEXT_BATCH: 1, DISPATCH: 1, FETCH: 1, BOUNDARY: 1}, step
     by_step = {r[1]: r for r in spans() if r[0] == FETCH}
-    ends = [by_step[i][4] for i in (1, 2, 3)]
+    ends = [by_step[i][4] for i in (1, 2, 3, 4, 5)]
     assert ends == sorted(ends)  # a fence-free step counter: fenced stamps in step order
+    launched = {r[1]: r[3] for r in spans() if r[0] == DISPATCH}
+    assert by_step[1][4] <= launched[2]  # the first step drains: the placement line reads its state
+    for i in (2, 3, 4):
+        assert launched[i + 1] < by_step[i][3], i  # asked for once the next step is out
+    parents = {r[1]: r for r in spans() if r[0] == STEP}
+    for i in (2, 3, 4):  # the launch of step i+1 lies in the iteration that reports step i
+        assert parents[i][3] <= launched[i + 1] < parents[i][4], i
 
 
 # ------------------------------------------------------------ device scopes
