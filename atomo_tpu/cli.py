@@ -3047,6 +3047,33 @@ def cmd_train(args: argparse.Namespace) -> int:
     return 0
 
 
+def _latent_moe_sizes(args: argparse.Namespace, pattern: tuple):
+    """`lm --block glm`'s sizes as the model takes them, or a one-line refusal."""
+    from atomo_tpu.models.moe import LatentMoeSizes
+
+    if args.block != "glm" or set(pattern) != {"mla"}:
+        raise SystemExit(
+            "--layer-pattern: an mla layer comes with --block glm, whose every layer is one"
+        )
+    sizes = ("q_rank", "kv_rank", "nope_dim", "rope_dim", "value_dim",
+             "routed_experts", "expert_width")
+    missing = [f"--{name.replace('_', '-')}" for name in sizes if getattr(args, name) <= 0]
+    if missing:
+        raise SystemExit(f"--block glm needs its sizes: {' '.join(missing)}")
+    try:
+        return LatentMoeSizes(
+            q_rank=args.q_rank, kv_rank=args.kv_rank, nope_dim=args.nope_dim,
+            rope_dim=args.rope_dim, value_dim=args.value_dim, rope_theta=args.rope_theta,
+            experts=args.routed_experts, experts_held=args.experts_held,
+            first_expert=args.first_expert, per_token=args.experts_per_token,
+            expert_width=args.expert_width, shared_experts=args.shared_experts,
+            dense_layers=args.dense_layers, route_scale=args.route_scale,
+            mtp_depth=args.mtp_depth, mtp_weight=args.mtp_weight,
+        )
+    except ValueError as e:
+        raise SystemExit(f"--block glm: {e}") from None
+
+
 def _lm_block_config(args: argparse.Namespace) -> dict:
     """The TransformerLM fields that `lm --block`, `--layer-pattern` and the
     sizes beside them set; empty for GPT-2's block, so that the layouts whose
@@ -3068,6 +3095,8 @@ def _lm_block_config(args: argparse.Namespace) -> dict:
         block["remat"] = args.remat
     if pattern != ("full",):
         block["layer_pattern"] = pattern
+    if "mla" in block.get("layer_pattern", ()):
+        block["latent_moe"] = _latent_moe_sizes(args, block["layer_pattern"])
     if "linear" in pattern:
         if args.linear_key_dim <= 0 or args.linear_value_dim <= 0:
             raise SystemExit(
@@ -3483,6 +3512,8 @@ def cmd_lm(args: argparse.Namespace) -> int:
             from atomo_tpu.models.transformer import TransformerLM
 
             logits = TransformerLM(**cfg).apply({"params": params}, toks)
+            if isinstance(logits, tuple):  # beside the prediction module's
+                logits = logits[0]
         ce = float(
             _optax.softmax_cross_entropy_with_integer_labels(
                 logits[:, :-1], toks[:, 1:]
@@ -3810,7 +3841,10 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p_lm.add_argument("--layout", type=str, default="dp",
                       choices=["dp", "dp-sp", "dp-tp", "dp-ep", "dp-pp",
-                               "dp-tp-sp"])
+                               "dp-tp-sp"],
+                      help="dp-ep is parallel/moe.py's switch top-1 block with "
+                           "dropped tokens, another model than --block glm's "
+                           "expert layer")
     p_lm.add_argument("--ways", type=int, default=2, metavar="N",
                       help="model-axis size (sp/tp/ep/pp shards; the tp "
                            "size for dp-tp-sp)")
@@ -3831,18 +3865,23 @@ def build_parser() -> argparse.ArgumentParser:
     p_lm.add_argument("--depth", type=int, default=4)
     p_lm.add_argument("--num-heads", type=int, default=4)
     p_lm.add_argument("--block", type=str, default="gpt2",
-                      choices=["gpt2", "olmo"],
+                      choices=["gpt2", "olmo", "glm"],
                       help="the block's recipe (models/transformer.py "
                            "BLOCK_RECIPES): gpt2 = pre-LayerNorm, learned "
                            "positions, GELU MLP; olmo = RMSNorm on each "
                            "sublayer's output, q/k norm, SiLU-gated FFN, no "
-                           "positional embedding. --layout dp only")
+                           "positional embedding; glm = pre-RMSNorm, latent "
+                           "attention with rotary keys, a dense gated FFN in "
+                           "the leading layers and sigmoid-routed experts "
+                           "beside a shared one after (models/moe.py), sized "
+                           "by the flags from --q-rank to --mtp-weight. "
+                           "--layout dp only")
     p_lm.add_argument("--layer-pattern", type=str, default="full",
                       metavar="KIND[,KIND...]",
                       help="mixer of each layer, repeated over --depth: full "
                            "(softmax attention) | linear (gated delta rule, "
                            "models/linear_attention.py), e.g. "
-                           "linear,linear,linear,full")
+                           "linear,linear,linear,full; mla is --block glm's")
     p_lm.add_argument("--ffn-width", type=int, default=0, metavar="N",
                       help="hidden width of the FFN (0 = 4 x --width)")
     p_lm.add_argument("--linear-key-dim", type=int, default=0, metavar="N",
@@ -3858,7 +3897,29 @@ def build_parser() -> argparse.ArgumentParser:
                            "weights for the backward pass and rebuilds the "
                            "rest there (less memory, about a forward pass of "
                            "the cheap operations more)")
-    p_lm.add_argument("--num-experts", type=int, default=8)
+    for flag, kind, default, text in (
+        ("--q-rank", int, 0, "width of the queries' latent"),
+        ("--kv-rank", int, 0, "width of the keys' and values' latent"),
+        ("--nope-dim", int, 0, "per-head query/key size without position"),
+        ("--rope-dim", int, 0, "per-head query/key size that is rotated"),
+        ("--value-dim", int, 0, "per-head value size"),
+        ("--rope-theta", float, 10000.0, "base of the rotary frequencies"),
+        ("--routed-experts", int, 0, "the router's outputs"),
+        ("--experts-held", int, 0, "experts this chip holds of them (0 = all)"),
+        ("--first-expert", int, 0, "the first expert held"),
+        ("--experts-per-token", int, 4, "experts a token is routed to"),
+        ("--expert-width", int, 0, "hidden width of one expert"),
+        ("--shared-experts", int, 1, "experts every token goes through"),
+        ("--dense-layers", int, 1, "leading layers with the dense FFN"),
+        ("--route-scale", float, 1.0, "factor on the normalised routing weights"),
+        ("--mtp-depth", int, 0, "multi-token-prediction modules (0 | 1)"),
+        ("--mtp-weight", float, 0.3, "weight of the prediction module's loss"),
+    ):
+        p_lm.add_argument(flag, type=kind, default=default, metavar="N",
+                          help=f"--block glm: {text}")
+    p_lm.add_argument("--num-experts", type=int, default=8,
+                      help="--layout dp-ep: experts of parallel/moe.py's switch "
+                           "top-1 layer, another model than --block glm's expert layer")
     p_lm.add_argument("--microbatches", type=int, default=2)
     p_lm.add_argument("--batch-size", type=int, default=8)
     p_lm.add_argument("--max-steps", type=int, default=50)

@@ -11,7 +11,12 @@ RMSNorm, the norm on each sublayer's output, no positional embedding, a
 SiLU-gated FFN of its own width, an RMSNorm over the projected queries and
 keys, a per-layer mixer from ``layer_pattern`` (``full`` attention or the
 ``linear`` gated delta rule of models/linear_attention.py), and ``remat``,
-which has a block keep only its weight matmuls for the backward pass.
+which has a block keep only its weight matmuls for the backward pass. A
+pattern of ``mla`` layers takes its block whole from models/moe.py (latent
+attention with rotary keys, a dense FFN in the leading layers and routed
+experts beside a shared one after, sized by ``latent_moe``), and with
+``latent_moe.mtp_depth`` the model returns a second set of logits, from the
+multi-token-prediction module, beside the first.
 
 The attention callable is injectable: ``attention_fn(q, k, v)`` receives
 (B, H, S, D). Default is the single-device exact softmax
@@ -30,18 +35,22 @@ import jax
 import jax.numpy as jnp
 
 from atomo_tpu.models.linear_attention import GatedDeltaNet
+from atomo_tpu.models.moe import LatentMoeBlock, LatentMoeSizes, gated_ffn, mtp_input
 from atomo_tpu.parallel.ring import full_attention, kept_score_bytes
-from atomo_tpu.utils.tracing import named_phase
 
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
-MIXERS = ("full", "linear")
+MIXERS = ("full", "linear", "mla")
 # the block choices that go together, as `lm --block` names them; "olmo" is
 # the OLMo 2/3 family's: the norm reordered onto the sublayers' outputs, q/k
-# norm, SwiGLU, and (Olmo-Hybrid's `rope_theta: null`) no positions at all
+# norm, SwiGLU, and (Olmo-Hybrid's `rope_theta: null`) no positions at all;
+# "glm" is the GLM-4.7-Flash / DeepSeek-V3 family's: pre-norm RMSNorm, no
+# positional table (the `mla` layers rotate their keys), SwiGLU, and every
+# layer latent attention over a sigmoid-gated expert layer (models/moe.py)
 BLOCK_RECIPES = {
     "gpt2": {},
     "olmo": dict(norm="rmsnorm", norm_placement="post", positions="none",
                  ffn="swiglu", qk_norm=True),
+    "glm": dict(norm="rmsnorm", positions="none", ffn="swiglu", layer_pattern=("mla",)),
 }
 
 
@@ -111,10 +120,7 @@ class Block(nn.Module):
             return nn.Dense(width, use_bias=False, name="down")(y)
         if self.ffn != "swiglu":
             raise ValueError(f"unknown ffn {self.ffn!r}; expected gelu | swiglu")
-        with named_phase("ffn"):
-            gate = nn.Dense(hidden, use_bias=False, name="gate")(y)
-            y = nn.silu(gate) * nn.Dense(hidden, use_bias=False, name="up")(y)
-            return nn.Dense(width, use_bias=False, name="down")(y)
+        return gated_ffn(y, hidden)
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
@@ -166,6 +172,7 @@ class TransformerLM(nn.Module):
     linear_value_dim: int = 0
     linear_conv_width: int = 4
     remat: str = "none"  # none | dots: what a block keeps for the backward pass of a training step
+    latent_moe: Optional[LatentMoeSizes] = None  # of the `mla` layers, which then are all the layers
 
     @nn.compact
     def __call__(
@@ -176,7 +183,8 @@ class TransformerLM(nn.Module):
         shard embeds its true positions (not local 0..S/n)."""
         b, s = tokens.shape
         head_dim = self.width // self.num_heads
-        x = nn.Embed(self.vocab_size, self.width, name="tok_emb")(tokens)
+        embed = nn.Embed(self.vocab_size, self.width, name="tok_emb")
+        x = embed(tokens)
         if self.positions == "learned":
             pos = nn.Embed(self.max_len, self.width, name="pos_emb")(
                 pos_offset + jnp.arange(s)
@@ -192,15 +200,18 @@ class TransformerLM(nn.Module):
             # also where nothing is trained (initialisation, evaluation):
             # flax's lifted remat keeps the scope of a call on concrete
             # arrays alive, and with it a copy of the parameters
-            block = Block
+            remat = lambda block: block  # noqa: E731
         else:
             # keep the matmuls against weights, rebuild the rest of a block
             # (elementwise passes, attention, the chunks and their scan)
             # inside the backward pass
-            block = nn.remat(
-                Block, static_argnums=(2,),
+            remat = partial(
+                nn.remat, static_argnums=(2,),
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             )
+        if "mla" in self.layer_pattern:
+            return self._latent_moe(remat(LatentMoeBlock), embed, x, tokens, train, pos_offset)
+        block = remat(Block)
         shared = {f: getattr(self, f) for f in BLOCK_FIELDS}
         for i in range(self.depth):
             x = block(
@@ -212,6 +223,35 @@ class TransformerLM(nn.Module):
             )(x, train)
         x = _norm(self.norm, "ln_f")(x)
         return nn.Dense(self.vocab_size, use_bias=False, name="head")(x)
+
+    def _latent_moe(self, block, embed, x, tokens, train, pos_offset):
+        """The layers of a model whose every layer is ``mla``, the last norm
+        and the head; with the prediction module, (logits, its logits): the
+        module sees the last block's output at t beside the embedding of
+        token t+1, runs one more expert block, and predicts token t+2 through
+        the same embedding and head. All S positions are computed (the last,
+        whose next token is the first, predicts nothing and is causal's last,
+        so it reaches no other) and the loss leaves the last two out."""
+        z = self.latent_moe
+        if z is None or set(self.layer_pattern) != {"mla"}:
+            raise ValueError(
+                "an `mla` layer needs latent_moe's sizes and every layer of "
+                f"layer_pattern to be `mla`, not {self.layer_pattern}"
+            )
+        if self.norm != "rmsnorm" or self.positions != "none" or self.ffn != "swiglu":
+            raise ValueError("`mla` layers are pre-norm RMSNorm blocks with a gated FFN and no positional table")
+        make = partial(block, self.num_heads, z, self.ffn_width or 4 * self.width,
+                       attention_fn=self.attention_fn)
+        for i in range(self.depth):
+            x = make(experts=i >= z.dense_layers, name=f"block{i}")(x, train, pos_offset)
+        norm = partial(nn.RMSNorm, epsilon=z.norm_eps)
+        head = nn.Dense(self.vocab_size, use_bias=False, name="head")
+        logits = head(norm(name="ln_f")(x))
+        if not z.mtp_depth:
+            return logits
+        m = mtp_input(z, embed(jnp.roll(tokens, -1, axis=1)), x)
+        m = make(experts=True, name="mtp_block")(m, train, pos_offset)
+        return logits, head(norm(name="mtp_norm")(m))
 
 
 def lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:

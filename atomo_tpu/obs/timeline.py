@@ -86,6 +86,12 @@ PHASE_OF_SCOPE = {
     "delta_chunk": "delta_chunk",
     "delta_scan": "delta_scan",
     "ffn": "ffn",
+    "mla": "mla",
+    "moe": "moe",
+    "moe_route": "moe_route",
+    "moe_dispatch": "moe_dispatch",
+    "moe_experts": "moe_experts",
+    "mtp": "mtp",
     "update": "update",
 }
 # the codec's and the exchange's phases are measured AGAINST the compute
@@ -94,11 +100,15 @@ PHASE_OF_SCOPE = {
 # innermost scope's: `forward_backward` is what is left of it outside
 # `attention`, `ffn` and the linear layers' mixer core, and
 # `linear_attention` what is left of that core (convolution, normalisation,
-# gates) outside `delta_chunk` and `delta_scan`: the core is the three.
+# gates) outside `delta_chunk` and `delta_scan`: the core is the three. In
+# the same way `mla` is the latent attention's projections, norms and
+# rotation outside `attention`, and the routed experts are `moe_route`,
+# `moe_dispatch` and `moe_experts` with `moe` what is left outside the three.
 PHASES = ("encode", "exchange", "decode")
 MODEL_PHASES = (
     "forward_backward", "attention", "linear_attention", "delta_chunk",
-    "delta_scan", "ffn", "update",
+    "delta_scan", "ffn", "mla", "moe", "moe_route", "moe_dispatch",
+    "moe_experts", "mtp", "update",
 )
 # the loop's host spans, as utils.tracing names them
 HOST_SPANS = (
@@ -326,6 +336,21 @@ def phase_of(op_name: Optional[str]) -> str:
             if ph:
                 return ph
     return "compute"
+
+
+# instructions the TPU's compiler makes as custom calls and gives no
+# metadata, by the prefix of their name, with the scope of the one place in
+# the program that they come from: the grouped products of models/moe.py
+# (`jax.lax.ragged_dot`), forward and both transposes (22 of a step's 75 ms
+# in the routed experts on the v5e, PERF.md §6, PR 33)
+SCOPE_OF_INSTRUCTION = {"ragged-dot": "moe_experts"}
+
+
+def _scope_by_instruction(name: str) -> Optional[str]:
+    for prefix, scope in SCOPE_OF_INSTRUCTION.items():
+        if name.startswith(prefix):
+            return scope
+    return None
 
 
 def latest_trace(profile_dir: str) -> Optional[str]:
@@ -665,6 +690,8 @@ def build_timeline(
     events = sorted(events_by_pid[pid], key=lambda e: e["start_us"])
     for ev in events:
         ev["phase"] = phase_of(scopes.get(ev["name"]))
+        if ev["phase"] == "compute":  # no scope in its metadata: one of the compiler's own?
+            ev["phase"] = phase_of(_scope_by_instruction(ev["name"]))
     check(
         "timeline_phases_present", True,
         f"module {doc['module']} carries "

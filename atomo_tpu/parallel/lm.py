@@ -20,6 +20,7 @@ scalar.
 from __future__ import annotations
 
 import dataclasses
+import operator
 from functools import partial
 
 
@@ -579,6 +580,28 @@ def make_delayed_model_axis_step(
     )
 
 
+# The collections a layer sows a count into, each with how the step joins it:
+# over the layers of one replica, then over the dp replicas. The layer chooses
+# the collection; no name is read here.
+SOWN_COUNTS = {
+    # from shapes as the layer is traced (`lin_state_bytes`,
+    # `attn_score_bytes`): constants, alike on every replica, and joined
+    # by `+` so that their sum stays a constant of the step's text
+    "counters": (operator.add, None),
+    # from the data as the step runs (the expert layer's rows): each
+    # replica's own, so their sum and their most
+    "counts": (operator.add, jax.lax.psum),
+    "counts_max": (jnp.maximum, jax.lax.pmax),
+}
+
+
+def keep_float32(cast, master, names):
+    """``cast`` with the leaves named in ``names`` taken from ``master``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, low, full: full if path[-1].key in names else low, cast, master
+    )
+
+
 def make_lm_train_step(
     lm_config: dict,
     optimizer,
@@ -607,10 +630,17 @@ def make_lm_train_step(
     # lazy: models.transformer imports parallel.ring, so a module-level
     # import here would cycle through parallel/__init__ (which exports tp,
     # which imports this module)
+    from atomo_tpu.models.moe import FLOAT32_LEAVES
     from atomo_tpu.models.transformer import TransformerLM
 
     n_sp = mesh.shape[sp_axis]
     n_dp = mesh.shape[dp_axis]
+    latent_moe = lm_config.get("latent_moe")
+    if n_sp > 1 and latent_moe is not None:
+        raise ValueError(
+            f"the `mla` layers' prediction module reads each token's successor "
+            f"in its own shard: {sp_axis}={n_sp} needs latent_moe unset"
+        )
     if n_sp > 1 and "linear" in lm_config.get("layer_pattern", ()):
         raise ValueError(
             f"a linear-attention layer carries its state along the sequence, "
@@ -635,23 +665,35 @@ def make_lm_train_step(
             if compute_dtype is not None:
                 # bf16 MXU compute, f32 master state; token ids are integer
                 # inputs, so only the params need the cast
-                params = cast_params(params, compute_dtype)
+                master, params = params, cast_params(params, compute_dtype)
+                if latent_moe is not None:  # the gate computes in float32
+                    params = keep_float32(params, master, FLOAT32_LEAVES)
             s_local = tokens.shape[1]
-            # `counters`: what the layers count from their shapes as they
-            # are traced (constants: `lin_state_bytes`, `attn_score_bytes`)
+            # what the layers count as they are traced or run: SOWN_COUNTS
             logits, sown = model.apply(
                 {"params": params},
                 tokens,
                 train=True,
                 pos_offset=jax.lax.axis_index(sp_axis) * s_local,
-                mutable=["counters"],
+                mutable=list(SOWN_COUNTS),
             )
+            mtp_logits = None
+            if latent_moe is not None and latent_moe.mtp_depth:
+                logits, mtp_logits = logits
             if compute_dtype is not None:
                 logits = logits.astype(jnp.float32)
             targets, valid = sp_boundary_targets_and_mask(tokens, sp_axis, n_sp)
             ce = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
             total = jax.lax.psum(jnp.sum(valid), sp_axis)
-            return jax.lax.psum(jnp.sum(ce * valid), sp_axis) / total, sown
+            loss = jax.lax.psum(jnp.sum(ce * valid), sp_axis) / total
+            if mtp_logits is not None:
+                # the prediction module's logits at t are of token t+2: a mean
+                # of its own over the S-2 positions that have one
+                ce = optax.softmax_cross_entropy_with_integer_labels(
+                    mtp_logits.astype(jnp.float32), jnp.roll(tokens, -2, axis=1)
+                )
+                loss = loss + latent_moe.mtp_weight * jnp.mean(ce[:, :-2])
+            return loss, sown
 
         with named_phase("forward_backward"):
             (loss, sown), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -665,9 +707,14 @@ def make_lm_train_step(
         # inflation verified empirically (tests/test_ring.py oracle parity).
         grads = jax.lax.pmean(grads, sp_axis)
         counters: dict = {}
-        for path, value in jax.tree_util.tree_leaves_with_path(sown):
-            name = path[-2].key  # .../<module>/<counter>/<index in its tuple>
-            counters[name] = counters.get(name, 0.0) + value
+        for collection, (join, over_dp) in SOWN_COUNTS.items():
+            joined: dict = {}
+            for path, value in jax.tree_util.tree_leaves_with_path(sown.get(collection, {})):
+                name = path[-2].key  # .../<module>/<counter>/<index in its tuple>
+                joined[name] = join(joined.get(name, 0.0), value)
+            if over_dp is not None:
+                joined = {name: over_dp(value, dp_axis) for name, value in joined.items()}
+            counters.update(joined)
         return k_codec, grads, loss, counters
 
     def grads_fn(state: TrainState, key, tokens):

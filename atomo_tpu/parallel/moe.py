@@ -1,5 +1,8 @@
 """Expert parallelism: switch-style MoE transformer over an 'ep' mesh axis.
 
+This switch top-1 layer with a fixed capacity is another model than `lm --block
+glm`'s expert layer (models/moe.py: top-k, no dropped token, a shared expert).
+
 The reference has no MoE and no model sharding of any kind (SURVEY.md §2.1);
 this module adds the third model-sharding axis next to tp and sp. Design:
 
