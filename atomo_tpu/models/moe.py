@@ -65,7 +65,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from atomo_tpu.parallel.ring import full_attention, kept_score_bytes
+from atomo_tpu.parallel.ring import full_attention, fused_layers, kept_score_bytes
 from atomo_tpu.utils.tracing import named_phase
 
 INIT = nn.initializers.normal(0.02)
@@ -172,6 +172,8 @@ class LatentAttention(nn.Module):
         out = fn(q, k, v)  # (B, H, S, value_dim)
         if kept := kept_score_bytes(fn, q):
             self.sow("counters", "attn_score_bytes", jnp.float32(kept))
+        if fused := fused_layers(fn, q):
+            self.sow("counters", "attn_fused_layers", jnp.float32(fused))
         with named_phase("mla"):
             out = out.transpose(0, 2, 1, 3).reshape(b, s, h * z.value_dim)
             return dense(width, name="o")(out)

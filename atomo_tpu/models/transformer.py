@@ -36,7 +36,7 @@ import jax.numpy as jnp
 
 from atomo_tpu.models.linear_attention import GatedDeltaNet
 from atomo_tpu.models.moe import LatentMoeBlock, LatentMoeSizes, gated_ffn, mtp_input
-from atomo_tpu.parallel.ring import full_attention, kept_score_bytes
+from atomo_tpu.parallel.ring import full_attention, fused_layers, kept_score_bytes
 
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
 MIXERS = ("full", "linear", "mla")
@@ -88,9 +88,11 @@ class MultiHeadAttention(nn.Module):
         fn = self.attention_fn or partial(full_attention, causal=True)
         q, k, v = heads(q), heads(k), heads(v)
         out = fn(q, k, v)  # (B, H, S, D)
+        # read by the lm step into its metrics, summed over the full layers
         if kept := kept_score_bytes(fn, q):
-            # read by the lm step into its metrics, summed over the full layers
             self.sow("counters", "attn_score_bytes", jnp.float32(kept))
+        if fused := fused_layers(fn, q):
+            self.sow("counters", "attn_fused_layers", jnp.float32(fused))
         out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
         return nn.Dense(x.shape[-1], use_bias=False, name="proj")(out)
 

@@ -1,31 +1,51 @@
-"""Pallas TPU kernel for fused (flash) attention — the LM forward hot path.
+"""Pallas TPU kernels for fused softmax attention, forward and backward.
 
-The jnp attention paths (parallel.ring.full_attention / blockwise_attention)
-leave the softmax chain to XLA: scores, max, exp, sum and the PV matmul are
-separate HBM-visible ops unless XLA fuses them. This kernel is the classic
-flash-attention schedule: the grid walks (batch, head, q-block, k-block)
-with the k-block axis innermost, K/V arrive one (block_k, D) tile at a time
-(Pallas double-buffers the HBM→VMEM DMA), and an online-softmax accumulator
-lives in VMEM scratch across the k sweep. The S×S score matrix never
-exists, VMEM residency is O(block·D) — independent of S, so sequence
-length is NOT bounded by VMEM (ADVICE r3 #1: the round-3 kernel kept the
-full (S, D) K/V resident per program, capping S at ~16k for D=64 f32 on a
-16 MB-VMEM core). For causal masks, k-blocks strictly above the diagonal
-skip their FLOPs via `pl.when` (the static grid still walks — and
-prefetches — those blocks, so causal saves compute but not bandwidth).
+The jnp attention paths (parallel.ring) leave the softmax chain to XLA: per
+query block the float32 scores go to HBM, come back for the row maximum and
+for the exponential, go out again as ``p`` and come back for ``p·v``; the
+backward pass does the same with ``dp`` and ``ds`` and sums dK and dV over
+the blocks in a float32 array of K's size. At 256-wide heads over 4096
+positions that traffic, not the products, sets the time (PERF.md §6, PR 34).
+Here a score tile never leaves VMEM.
 
-Scope discipline (round-2 lesson: TPU-only code paths must stay testable):
-  * forward = Pallas kernel, bit-compared against full_attention in the
-    TPU-semantics interpreter on CPU (tests/) and compiled on the chip
-    (tests_tpu/);
-  * backward = jax.vjp of the jnp blockwise oracle (identical math), so
-    training through ``flash_attention`` is exact and needs no hand-written
-    transpose kernel; the fused win applies to the forward pass.
-  * shapes that don't tile (S % block) fall back to blockwise_attention —
-    no silent padding semantics.
+Three kernels, each over a grid of (batch, head, live tile): the live
+(query block, key block) pairs are listed once from the static shape and
+handed to the kernel as scalar-prefetched tables, so a key block wholly
+above the diagonal is neither computed nor fetched, nor does the grid step
+over it.
 
-No reference analogue: the reference has no attention at all (SURVEY.md
-§5.7); this is TPU-first capability the framework adds on top of parity.
+  forward   walks a query block's key blocks, innermost, with the running
+            maximum, row sum and unnormalised output in VMEM scratch;
+            writes the output and the rows' log-sum-exp, the one residual
+            beside q, k, v and o.
+  dK, dV    walks a key block's query blocks and accumulates both in
+            float32 VMEM scratch. It works on the transposed tile (keys as
+            rows), so the rows' statistics lie along the lanes as they are
+            stored and no tile is transposed.
+  dQ        walks a query block's key blocks and accumulates dQ.
+
+Both backward kernels rebuild ``p`` from q, k and the log-sum-exp;
+``delta = rowsum(dO * O)`` is computed once outside them.
+
+Precision is the jnp path's (parallel/ring.py): operands enter the
+products in the dtype they arrive in and accumulate in float32
+(``Precision.HIGHEST`` for float32 operands); scores, maximum, exponentials,
+sums and accumulators are float32; ``p`` and ``ds`` are rounded to the
+operands' dtype once, where they become operands; dQ, dK and dV are rounded
+once, from float32.
+
+``parallel.ring`` takes ``fused_attention`` for its one-device causal path
+where ``ring.fused_blocks`` says so; the rule and its table of block sizes
+by head size (``ring.FUSED_BLOCKS``, from chip timings) live there, beside
+the dispatch, because importing this module imports Pallas (a second of
+every process's set-up) and a run that takes no kernel must not pay it.
+``flash_attention`` is the explicit entry (`lm --attn-impl ulysses-flash`),
+causal or not, with the caller's block sizes; a sequence that does not tile
+falls back to ``blockwise_attention``.
+
+Tested in the TPU-semantics interpreter on CPU (tests/) against the
+float32 one-block oracle, and compiled by Mosaic, compared and timed on
+the chip (tests_tpu/).
 """
 
 from __future__ import annotations
@@ -35,131 +55,279 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from atomo_tpu.ops.qsgd_kernels import _interpret_mode, interpret_requested
 
-NEG_INF = float("-inf")
+LANES = 128
+# a masked score: far below any real one, and finite, so that a row whose
+# keys in a tile are all masked never meets inf - inf
+MASKED = -0.7 * float(np.finfo(np.float32).max)
+# the tiles' temporaries at blocks of 1024 pass the compiler's default of 16 MiB
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-def _fa_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-    scale: float, causal: bool,
+def _lanes(x, n: int):
+    """x (rows, 128) with every lane alike -> (rows, n)."""
+    if n % LANES == 0:
+        return jnp.tile(x, (1, n // LANES))
+    if n < LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _dot(a, b, contract_b: int):
+    """a (m, c) times b, contracted over b's axis ``contract_b``: operands as
+    they are, float32 result."""
+    precision = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(
+        a, b, (((1,), (contract_b,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32,
+    )
+
+
+def _last_key_block(i, bq: int, bk: int, nk: int, causal: bool):
+    """The last key block query block i sees."""
+    return jnp.minimum(((i + 1) * bq - 1) // bk, nk - 1) if causal else nk - 1
+
+
+def _live_tiles(s: int, bq: int, bk: int, causal: bool, keys_outermost: bool):
+    """The (query block, key block) pairs with a key at or below a query, as
+    two int32 tables in the order a kernel walks them."""
+    pairs = [
+        (i, j) for i in range(s // bq) for j in range(s // bk)
+        if not causal or j * bk <= (i + 1) * bq - 1
+    ]
+    if keys_outermost:
+        pairs.sort(key=lambda pair: (pair[1], pair[0]))
+    return tuple(np.asarray(t, np.int32) for t in zip(*pairs))
+
+
+def _causal_tile(i, j, bq: int, bk: int, keys_as_rows: bool):
+    """Where a tile's key is at or below its query."""
+    shape = (bk, bq) if keys_as_rows else (bq, bk)
+    q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 1 if keys_as_rows else 0)
+    k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 0 if keys_as_rows else 1)
+    return k_pos <= q_pos
+
+
+def _masked_or_not(step, i, j, bq: int, bk: int, causal: bool):
+    """Run ``step(masked)``: with the mask only on a tile the diagonal crosses."""
+    if not causal:
+        step(False)
+        return
+    crosses = (j + 1) * bk - 1 > i * bq
+    pl.when(crosses)(partial(step, True))
+    pl.when(jnp.logical_not(crosses))(partial(step, False))
+
+
+def _fwd_kernel(
+    i_tab, j_tab, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
+    scale: float, causal: bool, nk: int,
 ):
-    """One (batch, head, q-block, k-block) grid step. Blocks: q/o
-    (1, 1, Bq, D) pinned across the k sweep; k/v (1, 1, Bk, D) — one tile
-    per step, streamed from HBM. The online-softmax state (m, l, acc)
-    lives in VMEM scratch, initialized at k-block 0 and folded into o_ref
-    at the last k-block."""
-    iq = pl.program_id(2)
-    jk = pl.program_id(3)
-    bq = q_ref.shape[2]
-    bk = k_ref.shape[2]
+    bq, bk, dv = q_ref.shape[2], k_ref.shape[2], v_ref.shape[3]
+    t = pl.program_id(2)
+    i, j = i_tab[t], j_tab[t]
 
-    @pl.when(jk == 0)
-    def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    @pl.when(j == 0)
+    def _first_key_block():
+        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    # causal: a k-block whose first position is past this q-block's last
-    # position is fully masked — skip its FLOPs (the DMA still happened;
-    # see module docstring)
-    live = (jk * bk <= (iq + 1) * bq - 1) if causal else (jk >= 0)
+    def step(masked: bool):
+        v = v_ref[0, 0]
+        s = _dot(q_ref[0, 0], k_ref[0, 0], 1) * scale  # (bq, bk)
+        if masked:
+            s = jnp.where(_causal_tile(i, j, bq, bk, False), s, MASKED)
+        m_prev = m_scr[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - _lanes(m_next, bk))
+        m_scr[...] = m_next
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        # p rounded once, where it becomes an operand
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, dv) + _dot(p.astype(v.dtype), v, 0)
 
-    @pl.when(live)
-    def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)  # (Bq, D)
-        k_blk = k_ref[0, 0].astype(jnp.float32)  # (Bk, D)
-        v_blk = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (Bq, Bk)
-        if causal:
-            q_pos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-            k_pos = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_cur)
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe), 0.0)
-        alpha = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-        m_ref[...] = m_new
-        l_ref[...] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _masked_or_not(step, i, j, bq, bk, causal)
 
-    @pl.when(jk == pl.num_programs(3) - 1)
-    def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], jnp.finfo(jnp.float32).tiny)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+    @pl.when(j == _last_key_block(i, bq, bk, nk, causal))
+    def _last_key_block_of_the_rows():
+        l = l_scr[...]
+        o_ref[0, 0] = (acc_scr[...] / _lanes(l, dv)).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_scr[...] + jnp.log(l)).T[:1]  # rows along the lanes
 
 
-def _flash_forward(
-    q, k, v, *, causal: bool, scale: float, block_q: int, block_k: int,
-    interpret: bool,
+def _dkv_kernel(
+    i_tab, j_tab, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    dk_scr, dv_scr, *, scale: float, causal: bool, nq: int,
 ):
-    b, h, s, d = q.shape
-    grid = (b, h, s // block_q, s // block_k)
-    kernel = partial(_fa_kernel, scale=scale, causal=causal)
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    t = pl.program_id(2)
+    i, j = i_tab[t], j_tab[t]
+
+    @pl.when(i == ((j * bk) // bq if causal else 0))
+    def _first_query_block():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    def step(masked: bool):
+        q, do = q_ref[0, 0], do_ref[0, 0]
+        s = _dot(k_ref[0, 0], q, 1) * scale  # (bk, bq): keys as rows
+        if masked:
+            s = jnp.where(_causal_tile(i, j, bq, bk, True), s, MASKED)
+        p = jnp.exp(s - lse_ref[0, 0])
+        dv_scr[...] += _dot(p.astype(do.dtype), do, 0)
+        dp = _dot(v_ref[0, 0], do, 1)
+        ds = p * (dp - delta_ref[0, 0]) * scale
+        dk_scr[...] += _dot(ds.astype(q.dtype), q, 0)
+
+    _masked_or_not(step, i, j, bq, bk, causal)
+
+    @pl.when(i == nq - 1)
+    def _last_query_block():
+        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _dq_kernel(
+    i_tab, j_tab, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+    dq_scr, lse_scr, delta_scr, *, scale: float, causal: bool, nk: int,
+):
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    t = pl.program_id(2)
+    i, j = i_tab[t], j_tab[t]
+
+    @pl.when(j == 0)
+    def _first_key_block():
+        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+        # the rows' statistics from along the lanes to one a row, lanes alike
+        lse_scr[...] = jnp.broadcast_to(lse_ref[0, 0], (LANES, bq)).T
+        delta_scr[...] = jnp.broadcast_to(delta_ref[0, 0], (LANES, bq)).T
+
+    def step(masked: bool):
+        k = k_ref[0, 0]
+        s = _dot(q_ref[0, 0], k, 1) * scale  # (bq, bk)
+        if masked:
+            s = jnp.where(_causal_tile(i, j, bq, bk, False), s, MASKED)
+        p = jnp.exp(s - _lanes(lse_scr[...], bk))
+        dp = _dot(do_ref[0, 0], v_ref[0, 0], 1)
+        ds = p * (dp - _lanes(delta_scr[...], bk)) * scale
+        dq_scr[...] += _dot(ds.astype(k.dtype), k, 0)
+
+    _masked_or_not(step, i, j, bq, bk, causal)
+
+    @pl.when(j == _last_key_block(i, bq, bk, nk, causal))
+    def _last_key_block_of_the_rows():
+        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _call(kernel, name, tables, in_specs, out_specs, out_shape, scratch, interpret, operands):
+    b, h = operands[0].shape[:2]
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bb, hh, i, j: (bb, hh, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bb, hh, i, j: (bb, hh, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bb, hh, i, j: (bb, hh, j, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, d), lambda bb, hh, i, j: (bb, hh, i, 0)
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, h, len(tables[0])),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),  # running max m
-            pltpu.VMEM((block_q, 1), jnp.float32),  # running denom l
-            pltpu.VMEM((block_q, d), jnp.float32),  # unnormalized acc
-        ],
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=_interpret_mode(interpret),
-    )(q, k, v)
+    )(*tables, *operands)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    return _flash_forward(
-        q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret,
+def _rows_of(block: int, width: int):
+    """A tile of query rows: (1, 1, block, width) at query block i."""
+    return pl.BlockSpec((1, 1, block, width), lambda b, h, t, i_tab, j_tab: (b, h, i_tab[t], 0))
+
+
+def _keys_of(block: int, width: int):
+    return pl.BlockSpec((1, 1, block, width), lambda b, h, t, i_tab, j_tab: (b, h, j_tab[t], 0))
+
+
+def _stats_of(block: int):
+    """A query block's statistics, (B, H, 1, S) float32: rows along the lanes."""
+    return pl.BlockSpec((1, 1, 1, block), lambda b, h, t, i_tab, j_tab: (b, h, 0, i_tab[t]))
+
+
+def _forward(q, k, v, causal, scale, block, interpret):
+    (b, h, s, d), dv, (bq, bk) = q.shape, v.shape[-1], block
+    return _call(
+        partial(_fwd_kernel, scale=scale, causal=causal, nk=s // bk),
+        "fused_attention_fwd",
+        _live_tiles(s, bq, bk, causal, keys_outermost=False),
+        [_rows_of(bq, d), _keys_of(bk, d), _keys_of(bk, dv)],
+        [_rows_of(bq, dv), _stats_of(bq)],
+        [jax.ShapeDtypeStruct((b, h, s, dv), q.dtype), jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
+        [pltpu.VMEM((bq, LANES), jnp.float32), pltpu.VMEM((bq, LANES), jnp.float32),
+         pltpu.VMEM((bq, dv), jnp.float32)],
+        interpret, (q, k, v),
     )
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out = _flash_forward(
-        q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret,
+def _backward(q, k, v, o, lse, do, causal, scale, dkv_block, dq_block, interpret):
+    (b, h, s, d), dv = q.shape, v.shape[-1]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
+    operands = (q, k, v, do, lse, delta)
+
+    def specs(bq, bk):
+        return [_rows_of(bq, d), _keys_of(bk, d), _keys_of(bk, dv), _rows_of(bq, dv),
+                _stats_of(bq), _stats_of(bq)]
+
+    bq, bk = dkv_block
+    dk, dv_ = _call(
+        partial(_dkv_kernel, scale=scale, causal=causal, nq=s // bq),
+        "fused_attention_dkv",
+        _live_tiles(s, bq, bk, causal, keys_outermost=True),
+        specs(bq, bk), [_keys_of(bk, d), _keys_of(bk, dv)],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, dv), jnp.float32)],
+        interpret, operands,
     )
-    return out, (q, k, v)
-
-
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
-    # exact gradients via the jnp blockwise oracle (same online-softmax
-    # math, same O(S·block) memory); the fused kernel accelerates forward
-    from atomo_tpu.parallel.ring import blockwise_attention
-
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda qq, kk, vv: blockwise_attention(
-            qq, kk, vv, causal=causal, scale=scale, block_size=block_k
-        ),
-        q, k, v,
+    bq, bk = dq_block
+    dq = _call(
+        partial(_dq_kernel, scale=scale, causal=causal, nk=s // bk),
+        "fused_attention_dq",
+        _live_tiles(s, bq, bk, causal, keys_outermost=False),
+        specs(bq, bk), _rows_of(bq, d), jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((bq, d), jnp.float32), pltpu.VMEM((bq, LANES), jnp.float32),
+         pltpu.VMEM((bq, LANES), jnp.float32)],
+        interpret, operands,
     )
-    return vjp(do)
+    return dq, dk, dv_
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _fused(q, k, v, causal, scale, blocks, interpret):
+    return _forward(q, k, v, causal, scale, blocks[0], interpret)[0]
+
+
+def _fused_fwd(q, k, v, causal, scale, blocks, interpret):
+    o, lse = _forward(q, k, v, causal, scale, blocks[0], interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _fused_bwd(causal, scale, blocks, interpret, res, do):
+    return _backward(*res, do, causal, scale, blocks[1], blocks[2], interpret)
+
+
+_fused.defvjp(_fused_fwd, _fused_bwd)
+
+
+@partial(jax.jit, static_argnums=(3, 4, 5, 6))  # traced and lowered once a shape, not once a layer
+def fused_attention(q, k, v, causal: bool, scale: float, blocks: tuple, interpret: bool = False):
+    """Softmax attention (B, H, S, D) x (B, H, S, D) x (B, H, S, Dv) ->
+    (B, H, S, Dv) through the three kernels. ``blocks``: the (query rows, key
+    rows) of a tile in the forward, the dK/dV and the dQ kernel; S a whole
+    number of each."""
+    return _fused(q, k, v, causal, scale, blocks, interpret)
 
 
 def flash_attention(
@@ -173,17 +341,15 @@ def flash_attention(
     block_k: int = 128,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Fused exact attention (B, H, S, D) -> (B, H, S, D).
-
-    Forward runs the Pallas flash kernel, compiled by Mosaic for the
-    device it is on (``interpret=None`` interprets only when
-    ops.qsgd_kernels.interpret_requested says so — tests and CPU dry
-    runs); backward is the jnp blockwise oracle's VJP. Falls back to
-    blockwise_attention when S doesn't tile by the blocks — identical
-    results either way (tested)."""
+    """Fused exact attention (B, H, S, D) -> (B, H, S, D), forward and
+    backward through the kernels with the caller's block sizes, compiled by
+    Mosaic for the device it is on (``interpret=None`` interprets only when
+    ops.qsgd_kernels.interpret_requested says so: tests and CPU dry runs).
+    Falls back to blockwise_attention when S doesn't tile by the blocks:
+    the same result either way (tested)."""
     from atomo_tpu.parallel.ring import blockwise_attention
 
-    b, h, s, d = q.shape
+    s, d = q.shape[-2:]
     if scale is None:
         scale = 1.0 / (d**0.5)
     block_q = min(block_q, s)
@@ -194,4 +360,5 @@ def flash_attention(
         )
     if interpret is None:
         interpret = interpret_requested()
-    return _flash(q, k, v, causal, scale, block_q, block_k, interpret)
+    block = (block_q, block_k)
+    return fused_attention(q, k, v, causal, float(scale), (block, block, block), interpret)

@@ -24,6 +24,16 @@ exponentials and row sums are float32 always. Of score size the backward
 pass keeps the exponentials alone, rounded to that dtype (``_softmax_block``).
 
 Causal attention over one block (``full_attention`` and a ring of one shard)
+runs as the fused Pallas kernels of ``ops.attention_kernels``, forward and
+backward, where the code can see that they apply (``fused_blocks``: the
+arrays on a TPU, bfloat16, queries and keys of one length that is a whole
+number of the kernels' blocks, a head size they were measured to win at): a
+score tile then never leaves VMEM, the residuals are q, k, v, the output and
+a log-sum-exp a row, and ``kept_score_bytes`` is 0. The precision is the one
+stated above. Everywhere else (the CPU, float32, other shapes) it is the
+``jax.numpy`` block below, which is also the oracle the kernels are tested
+against and the block every multi-block path (the ring over n > 1,
+``blockwise_attention``, ``ulysses_attention``) shares. That path
 skips most of the masked half: the queries are cut into at most
 ``MAX_QUERY_BLOCKS`` equal blocks (``causal_query_blocks``), and each block
 runs against the keys up to its own end only, its softmax taken once over
@@ -38,7 +48,7 @@ what is left of the square, for the step's ``attn_score_bytes``.
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -219,6 +229,68 @@ def _causal_blocks_attention(q, k, v, n, scale):
     return (o / l[..., None]).astype(q.dtype)
 
 
+class Blocks(NamedTuple):
+    """(query rows, key rows) of a tile in each of the fused kernels."""
+
+    fwd: tuple[int, int]
+    dkv: tuple[int, int]
+    dq: tuple[int, int]
+
+
+# Head size -> the fused kernels' block sizes, clipped to the sequence: one
+# algorithm that wants other parameters at other shapes. A head size is
+# listed where the kernels beat ``_causal_blocks_attention`` on the chip,
+# forward and backward (tests_tpu/test_attention_tpu.py; the readings:
+# PERF.md §6, PR 34): at (2, 20, 4096, 256) 11.05 ms a layer against 19.56,
+# at (1, 30, 4096, 128) 4.48 against 10.65, every block size from 512 to 1024
+# within 5% of the best. 64-wide heads are left out: at (4, 16, 1024, 64) the
+# kernels read 1.19 ms a layer at their best blocks where the jnp blocks read
+# 0.60 (a 64-wide contraction fills half of a 128 x 128 MXU and half of
+# every lane). The table lives here and not with the kernels because their
+# module imports Pallas, a second of set-up that a run which takes no kernel
+# (`train`, or `lm` at a head size left out) does not pay.
+FUSED_BLOCKS: dict[int, Blocks] = {
+    256: Blocks(fwd=(512, 512), dkv=(512, 512), dq=(512, 512)),
+    128: Blocks(fwd=(512, 512), dkv=(1024, 1024), dq=(1024, 1024)),
+}
+
+
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
+def fused_blocks(q_shape, k_shape, dtype, on_tpu: Optional[bool] = None) -> Optional[Blocks]:
+    """The block sizes the fused kernels take for causal attention of these
+    shapes, or None where the jnp path stays: off the TPU, operands other
+    than bfloat16, queries and keys of different lengths, a head size
+    without a measured win, or a sequence that is no whole number of 128 or
+    of a block."""
+    s, d = q_shape[-2:]
+    table = FUSED_BLOCKS.get(d)
+    if table is None or dtype != jnp.bfloat16 or tuple(k_shape[-2:]) != (s, d) or s % 128:
+        return None
+    if not (_on_tpu() if on_tpu is None else on_tpu):
+        return None
+    blocks = Blocks(*((min(bq, s), min(bk, s)) for bq, bk in table))
+    if any(s % b for pair in blocks for b in pair):
+        return None
+    return blocks
+
+
+def _cut_causal_attention(q, k, v, scale):
+    """Causal attention of one K/V block on one device, nothing to rescale:
+    through the fused kernels where ``fused_blocks`` says so (a TPU,
+    bfloat16, a head size and a sequence they were measured at), else in
+    query blocks; None where the sequence is not cut (the one-block program)."""
+    if (blocks := fused_blocks(q.shape, k.shape, q.dtype)) is not None:
+        from atomo_tpu.ops.attention_kernels import fused_attention, interpret_requested
+
+        return fused_attention(q, k, v, True, float(scale), blocks, interpret_requested())
+    if (n := causal_query_blocks(q.shape[-2], k.shape[-2])) > 1:
+        return _causal_blocks_attention(q, k, v, n, scale)
+    return None
+
+
 def _common_dtype(q, k, v):
     dtype = jnp.result_type(q, k, v)
     return q.astype(dtype), k.astype(dtype), v.astype(dtype)
@@ -246,8 +318,8 @@ def ring_attention(
         scale = 1.0 / (d**0.5)
     q, k, v = _common_dtype(q, k, v)
     if axis_size == 1:  # one K/V block: no rotation, nothing to rescale
-        if causal and (n := causal_query_blocks(s_local, s_local)) > 1:
-            return _causal_blocks_attention(q, k, v, n, scale)
+        if causal and (out := _cut_causal_attention(q, k, v, scale)) is not None:
+            return out
         pos = jnp.arange(s_local)
         return _one_block_attention(
             q, k, v, _causal_bias(pos, pos) if causal else None, scale
@@ -293,25 +365,43 @@ def full_attention(
     if scale is None:
         scale = 1.0 / (d**0.5)
     q, k, v = _common_dtype(q, k, v)
-    if causal and (n := causal_query_blocks(q.shape[-2], k.shape[-2])) > 1:
-        return _causal_blocks_attention(q, k, v, n, scale)
+    if causal and (out := _cut_causal_attention(q, k, v, scale)) is not None:
+        return out
     bias = None
     if causal:
         bias = _causal_bias(jnp.arange(q.shape[-2]), jnp.arange(k.shape[-2]))
     return _one_block_attention(q, k, v, bias, scale)
 
 
-def kept_score_bytes(attention_fn, q: jax.Array) -> int:
-    """Bytes of exponentials ``attention_fn(q, k, v)`` keeps for the backward
-    pass, from shapes, where it is this module's one-block path over keys as
-    long as the queries: a ``partial`` of :func:`full_attention`, or of
-    :func:`ring_attention` over an axis of one. 0 for any other callable,
-    whose residuals are not counted here."""
+def _one_device_keywords(attention_fn) -> Optional[dict]:
+    """The keywords of ``attention_fn`` where it is this module's one-device
+    path: a ``partial`` of :func:`full_attention`, or of
+    :func:`ring_attention` over an axis of one. None for any other callable."""
     func = getattr(attention_fn, "func", attention_fn)
     given = getattr(attention_fn, "keywords", {})
-    if func is not full_attention and not (
-        func is ring_attention and given.get("axis_size") == 1
-    ):
+    if func is full_attention or (func is ring_attention and given.get("axis_size") == 1):
+        return given
+    return None
+
+
+def fused_layers(attention_fn, q: jax.Array) -> int:
+    """1 where ``attention_fn(q, k, v)``, over keys as long as the queries,
+    runs the fused kernels (``_cut_causal_attention``'s rule), else 0: the
+    step's ``attn_fused_layers``, summed over the layers."""
+    given = _one_device_keywords(attention_fn)
+    if given is None or not given.get("causal"):
+        return 0
+    return int(fused_blocks(q.shape, q.shape, q.dtype) is not None)
+
+
+def kept_score_bytes(attention_fn, q: jax.Array) -> int:
+    """Bytes of exponentials ``attention_fn(q, k, v)`` keeps for the backward
+    pass, from shapes, where it is this module's one-device path over keys as
+    long as the queries. 0 where the fused kernels run (they keep a
+    log-sum-exp a row and no exponential), and for any other callable, whose
+    residuals are not counted here."""
+    given = _one_device_keywords(attention_fn)
+    if given is None or fused_layers(attention_fn, q):
         return 0
     b, h, s, _ = q.shape
     n = causal_query_blocks(s, s) if given.get("causal") else 1

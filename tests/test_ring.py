@@ -557,6 +557,32 @@ def test_attn_score_bytes_at_the_cells_shapes(cell, mib):
     assert constant("attn_score_bytes") == mib * 2**20
 
 
+@pytest.mark.parametrize("cell,layers", [
+    ("glm47flash-1chip-dense", 6), ("olmohybrid-1chip-dense", 1), ("gpt2m-1chip-dense", 24),
+])
+def test_on_a_tpu_the_cells_steps_count_their_fused_layers_and_keep_no_exponentials(cell, layers, monkeypatch):
+    """The platform patched, the cell's step traced on shapes: every causal
+    softmax layer whose head size ``FUSED_BLOCKS`` lists (5 layers and the
+    prediction module of GLM, Olmo's one full layer; GPT-2's 24 once 64-wide
+    heads are listed) runs the kernels and keeps no exponentials, and a
+    head size left out keeps the jnp blocks and their count. Off the TPU
+    (the tests around this one) the same steps count no fused layer."""
+    from atomo_tpu.ops import attention_kernels
+    from atomo_tpu.parallel import ring as ring_mod
+
+    head = {"glm47flash-1chip-dense": 256, "olmohybrid-1chip-dense": 128, "gpt2m-1chip-dense": 64}[cell]
+    assert "attn_fused_layers" not in _cell_step_metrics(cell)[0]
+    monkeypatch.setattr(ring_mod, "_on_tpu", lambda: True)
+    # traced as the chip's compiler gets them (the interpreter's calls carry
+    # an effect, which no pruning removes); nothing is lowered here
+    monkeypatch.setattr(attention_kernels, "interpret_requested", lambda: False)
+    names, constant = _cell_step_metrics(cell)
+    if head in ring_mod.FUSED_BLOCKS:
+        assert constant("attn_fused_layers") == layers and "attn_score_bytes" not in names
+    else:
+        assert "attn_fused_layers" not in names and "attn_score_bytes" in names
+
+
 def test_attn_score_bytes_is_absent_where_no_full_layer_runs():
     names, _ = _cell_step_metrics("olmohybrid-1chip-dense", layer_pattern="linear,linear,linear,linear")
     assert "lin_state_bytes" in names and "attn_score_bytes" not in names

@@ -1,10 +1,11 @@
-"""Flash-attention Pallas kernel compiled by Mosaic on the real chip.
+"""The attention core on the real chip: the fused Pallas kernels compiled by
+Mosaic, and the jnp blocks beside them, compared and timed at the one-chip
+cells' shapes.
 
-The CPU suite (tests/test_attention_kernels.py) runs the same comparisons
-under the TPU-semantics interpreter; this file is the hardware half of the
-round-2 discipline: Mosaic-only lowering (dot_general shapes, iota layouts,
-the dynamic-bound fori_loop) has no CPU path, so only an on-chip compile
-can catch its regressions.
+The CPU suite (tests/test_attention_kernels.py) runs the kernels under the
+TPU-semantics interpreter; Mosaic's lowering (dot_general shapes, iota
+layouts, transposes, scalar-prefetched index maps) has no CPU path, and no
+time, rate or comparison of speed comes from anywhere but here.
 """
 
 import time
@@ -52,9 +53,9 @@ def test_flash_grad_compiles_on_tpu():
         assert np.all(np.isfinite(np.asarray(g)))
 
 
-# --- the jnp core at the one-chip cells' shapes (PR 27, PR 30)
+# --- the core at the one-chip cells' shapes (PR 27, PR 30, PR 34)
 
-CELL_SHAPES = {"gpt2m": (4, 16, 1024, 64), "olmohybrid": (1, 30, 4096, 128)}
+CELL_SHAPES = {"gpt2m": (4, 16, 1024, 64), "olmohybrid": (1, 30, 4096, 128), "glm47flash": (2, 20, 4096, 256)}
 
 
 def _rel(got, want):
@@ -71,6 +72,26 @@ def _one_block(q, k, v, scale=None):
     return ring._one_block_attention(q, k, v, bias, scale)
 
 
+def _blocked(q, k, v, scale=None):
+    """The jnp path in 8 query blocks (PR 30), whatever the dispatch takes."""
+    from atomo_tpu.parallel import ring
+
+    scale = 1.0 / q.shape[-1] ** 0.5 if scale is None else scale
+    return ring._causal_blocks_attention(q, k, v, ring.causal_query_blocks(q.shape[-2], k.shape[-2]), scale)
+
+
+def _fused(q, k, v, scale=None):
+    """The fused kernels with the table's blocks for the shape, or blocks of
+    512 at a head size the table leaves out."""
+    from atomo_tpu.ops.attention_kernels import fused_attention
+    from atomo_tpu.parallel.ring import FUSED_BLOCKS, Blocks
+
+    s, d = q.shape[-2:]
+    table = FUSED_BLOCKS.get(d) or Blocks((512, 512), (512, 512), (512, 512))
+    table = Blocks(*((min(bq, s), min(bk, s)) for bq, bk in table))
+    return fused_attention(q, k, v, True, 1.0 / d**0.5 if scale is None else scale, table)
+
+
 def _ring1(scale=None):
     from jax.sharding import PartitionSpec as P
 
@@ -84,70 +105,107 @@ def _ring1(scale=None):
     )
 
 
+def _forward_and_gradients(fn, w, *args):
+    out = jax.jit(fn)(*args)
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2)
+    ))(*args)
+    return [out, *grads]
+
+
+def _float32_oracle(q, k, v, w):
+    """Forward and dq, dk, dv of the uncut program on the same values through
+    float32 operands at Precision.HIGHEST, a sequence at a time (at GLM's
+    shape two sequences' float32 scores do not fit beside their gradients)."""
+    rows = [
+        _forward_and_gradients(_one_block, w[b:b + 1], *(x[b:b + 1].astype(jnp.float32) for x in (q, k, v)))
+        for b in range(q.shape[0])
+    ]
+    return [jnp.concatenate(parts, axis=0) for parts in zip(*rows)]
+
+
 @pytest.mark.parametrize("cell", list(CELL_SHAPES))
 def test_bf16_core_matches_float32_oracle_at_cell_shape_on_tpu(cell):
-    """(4, 16, 1024, 64) and (1, 30, 4096, 128) bfloat16, causal, in 8 query
-    blocks: the core as full_attention and as ring_attention with one shard
-    (the path of `lm --layout dp --n-devices 1 --bf16`), forward and the
-    gradients of a scalar loss, against the uncut program on the same values
-    through float32 operands at Precision.HIGHEST. Read on the v5e (PR 27,
-    one block at the first shape): forward 2.0e-3 (the output's own
-    rounding), gradients 3.4e-3 to 4.0e-3 of the oracle's norm; a scale
-    1.25x off has to fail the same limits."""
-    from atomo_tpu.parallel.ring import causal_query_blocks, full_attention
+    """The three cells' shapes in bfloat16, causal: the core as the fused
+    kernels, as the jnp path in 8 query blocks, as full_attention and as
+    ring_attention with one shard (the path of `lm --layout dp --n-devices 1
+    --bf16`; both take the kernels where ``fused_blocks`` says so), forward
+    and the gradients of a scalar loss, against the uncut program in
+    float32. Read on the v5e: forward 2.0e-3 (the output's own rounding),
+    gradients 3.1e-3 to 3.7e-3 of the oracle's norm for the blocked path (PR
+    30); the kernels are held to no more (PERF.md, PR 34). A scale 1.25x
+    off has to fail the same limits."""
+    from atomo_tpu.parallel.ring import causal_query_blocks, full_attention, fused_blocks
 
     b, h, s, d = CELL_SHAPES[cell]
     assert causal_query_blocks(s, s) == 8
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(2, b=b, h=h, s=s, d=d))
     w = jax.random.normal(jax.random.PRNGKey(3), q.shape, jnp.float32)
+    print(f"\n{cell}: the dispatch takes {fused_blocks(q.shape, k.shape, q.dtype) or 'the jnp blocks'}")
 
-    def both(fn, *args):
-        out = jax.jit(fn)(*args)
-        grads = jax.jit(jax.grad(
-            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2)
-        ))(*args)
-        return [out, *grads]
-
-    want = both(_one_block, *(x.astype(jnp.float32) for x in (q, k, v)))
-    limits = [1e-2, 2e-2, 2e-2, 2e-2]
-    for name, fn in (("full", partial(full_attention, causal=True)), ("ring1", _ring1())):
-        got = both(fn, q, k, v)
+    want = _float32_oracle(q, k, v, w)
+    limits = [4e-3, 6e-3, 6e-3, 6e-3]
+    paths = (("fused", _fused), ("blocked", _blocked), ("full", partial(full_attention, causal=True)),
+             ("ring1", _ring1()))
+    for name, fn in paths:
+        got = _forward_and_gradients(fn, w, q, k, v)
         read = [_rel(g, ref) for g, ref in zip(got, want)]
-        print(f"\n{cell} {name}: forward and dq, dk, dv against the float32 oracle: {read}")
+        print(f"{cell} {name}: forward and dq, dk, dv against the float32 oracle: {read}")
         for g, gap, limit in zip(got, read, limits):
             assert g.dtype == jnp.bfloat16
-            assert gap < limit, (gap, limit)
-    wrong = both(_ring1(scale=1.25 / d**0.5), q, k, v)
-    for g, ref, limit in zip(wrong, want, limits):
-        assert _rel(g, ref) > 2 * limit, (_rel(g, ref), limit)
+            assert gap < limit, (name, gap, limit)
+    for fn in (partial(_fused, scale=1.25 / d**0.5), _ring1(scale=1.25 / d**0.5)):
+        for g, ref, limit in zip(_forward_and_gradients(fn, w, q, k, v), want, limits):
+            assert _rel(g, ref) > 2 * limit, (_rel(g, ref), limit)
+
+
+def _ms_a_layer(fn, q, k, v, w, calls=30):
+    """Forward and backward, ms a call, and the first call with its compile, s."""
+    step = jax.jit(jax.grad(lambda *a: jnp.sum((fn(*a) * w).astype(jnp.float32)), argnums=(0, 1, 2)))
+    started = time.perf_counter()
+    jax.block_until_ready(step(q, k, v))
+    first = time.perf_counter() - started
+    started = time.perf_counter()
+    for _ in range(calls):
+        out = step(q, k, v)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - started) / calls * 1e3, first
 
 
 @pytest.mark.parametrize("cell", list(CELL_SHAPES))
-def test_blocked_core_is_faster_than_one_block_on_tpu(cell, monkeypatch):
-    """The core alone, forward and backward, ms a layer (printed for PERF.md;
-    PR 27 read 2.28 for one block at (4, 16, 1024, 64)): uncut, and cut into
-    at most 2, 4, 8 (the cap), 16 and 32 query blocks."""
+def test_fused_core_is_faster_than_the_blocked_one_where_the_table_lists_it_on_tpu(cell):
+    """The core alone, forward and backward, ms a layer (printed for
+    PERF.md): the jnp path in 8 query blocks against the fused kernels with
+    the table's blocks (the default blocks where the table leaves the head
+    size out: that row is why). A head size is in the table only if the
+    kernels win there."""
     from atomo_tpu.parallel import ring
 
     b, h, s, d = CELL_SHAPES[cell]
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(4, b=b, h=h, s=s, d=d))
     w = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.bfloat16)
+    read = {"blocked": _ms_a_layer(_blocked, q, k, v, w), "fused": _ms_a_layer(_fused, q, k, v, w)}
+    listed = ring.fused_blocks(q.shape, k.shape, q.dtype) is not None
+    print(f"\n{cell} {(b, h, s, d)} core, ms a layer (first call with its compile, s): "
+          + ", ".join(f"{name} {ms:.3f} ({first:.2f})" for name, (ms, first) in read.items())
+          + f"; in the table: {listed} {ring.FUSED_BLOCKS.get(d)}")
+    assert listed == (read["fused"][0] < read["blocked"][0]), read
 
-    def ms_a_layer(fn, calls=30):
-        step = jax.jit(jax.grad(lambda *a: jnp.sum((fn(*a) * w).astype(jnp.float32)), argnums=(0, 1, 2)))
-        started = time.perf_counter()
-        jax.block_until_ready(step(q, k, v))
-        first = time.perf_counter() - started
-        started = time.perf_counter()
-        for _ in range(calls):
-            out = step(q, k, v)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - started) / calls * 1e3, first
 
-    read = {"one block": ms_a_layer(_one_block)}
+@pytest.mark.parametrize("cell", ["gpt2m", "olmohybrid"])
+def test_blocked_core_is_faster_than_one_block_on_tpu(cell, monkeypatch):
+    """The jnp core alone, forward and backward, ms a layer (PR 27 read 2.28
+    for one block at (4, 16, 1024, 64)): uncut, and cut into at most 2, 4, 8
+    (the cap), 16 and 32 query blocks."""
+    from atomo_tpu.parallel import ring
+
+    b, h, s, d = CELL_SHAPES[cell]
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(4, b=b, h=h, s=s, d=d))
+    w = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.bfloat16)
+    read = {"one block": _ms_a_layer(_one_block, q, k, v, w)}
     for cap in (2, 4, 8, 16, 32):
         monkeypatch.setattr(ring, "MAX_QUERY_BLOCKS", cap)
-        read[f"{ring.causal_query_blocks(s, s)} blocks"] = ms_a_layer(partial(ring.full_attention, causal=True))
+        read[f"{ring.causal_query_blocks(s, s)} blocks"] = _ms_a_layer(_blocked, q, k, v, w)
     monkeypatch.undo()
     print(f"\n{cell} {(b, h, s, d)} core, ms a layer (first call with its compile, s): "
           + ", ".join(f"{name} {ms:.3f} ({first:.2f})" for name, (ms, first) in read.items()))
