@@ -3068,10 +3068,51 @@ def _latent_moe_sizes(args: argparse.Namespace, pattern: tuple):
             first_expert=args.first_expert, per_token=args.experts_per_token,
             expert_width=args.expert_width, shared_experts=args.shared_experts,
             dense_layers=args.dense_layers, route_scale=args.route_scale,
-            mtp_depth=args.mtp_depth, mtp_weight=args.mtp_weight,
+            mtp_depth=args.mtp_depth, mtp_weight=args.mtp_weight, scoring=args.router,
         )
     except ValueError as e:
         raise SystemExit(f"--block glm: {e}") from None
+
+
+def _expert_sizes(args: argparse.Namespace):
+    """The `experts` FFN's sizes (`lm --block mellum`), or a one-line refusal."""
+    from atomo_tpu.models.moe import ExpertSizes
+
+    missing = [f"--{name.replace('_', '-')}" for name in ("routed_experts", "expert_width")
+               if getattr(args, name) <= 0]
+    if missing:
+        raise SystemExit(f"--block {args.block} needs its experts' sizes: {' '.join(missing)}")
+    if args.shared_experts or args.dense_layers:
+        raise SystemExit(
+            f"--block {args.block}: every layer is routed experts alone; say "
+            "--shared-experts 0 --dense-layers 0 (a shared expert and leading dense "
+            "layers are --block glm's)"
+        )
+    try:
+        return ExpertSizes(
+            expert_width=args.expert_width, experts=args.routed_experts,
+            experts_held=args.experts_held, first_expert=args.first_expert,
+            per_token=args.experts_per_token, scoring=args.router, route_scale=args.route_scale,
+        )
+    except ValueError as e:
+        raise SystemExit(f"--block {args.block}: {e}") from None
+
+
+def _rope_rules(args: argparse.Namespace, pattern: tuple) -> tuple:
+    """((mixer kind, Rotary), ...) for the attention kinds of `pattern`:
+    --rope-theta for all, with YaRN's record on the `full` layers where
+    --yarn-factor is given (a window layer never reaches past its window, so
+    its frequencies stay as trained)."""
+    from atomo_tpu.models.rotary import Rotary, Yarn
+
+    yarn = None
+    if args.yarn_factor:
+        if args.yarn_factor <= 1 or args.yarn_original_len <= 0:
+            raise SystemExit("--yarn-factor scales past --yarn-original-len: a factor above 1 and a length")
+        yarn = Yarn(args.yarn_factor, args.yarn_original_len, args.yarn_beta_fast,
+                    args.yarn_beta_slow, args.yarn_attention_factor)
+    kinds = dict.fromkeys(kind for kind in pattern if kind in ("full", "window"))
+    return tuple((kind, Rotary(args.rope_theta, yarn if kind == "full" else None)) for kind in kinds)
 
 
 def _lm_block_config(args: argparse.Namespace) -> dict:
@@ -3097,6 +3138,19 @@ def _lm_block_config(args: argparse.Namespace) -> dict:
         block["layer_pattern"] = pattern
     if "mla" in block.get("layer_pattern", ()):
         block["latent_moe"] = _latent_moe_sizes(args, block["layer_pattern"])
+    if block.get("ffn") == "experts":
+        block["experts"] = _expert_sizes(args)
+    if block.get("positions") == "rotary":
+        block["rope"] = _rope_rules(args, pattern)
+    for size in ("kv_heads", "head_dim"):
+        if getattr(args, size):
+            block[size] = getattr(args, size)
+    if args.kv_heads and args.num_heads % args.kv_heads:
+        raise SystemExit(f"--kv-heads {args.kv_heads} does not divide --num-heads {args.num_heads}")
+    if "window" in pattern:
+        if args.window <= 0:
+            raise SystemExit("--layer-pattern with a window layer needs --window")
+        block["window"] = args.window
     if "linear" in pattern:
         if args.linear_key_dim <= 0 or args.linear_value_dim <= 0:
             raise SystemExit(
@@ -3116,11 +3170,13 @@ def _lm_block_config(args: argparse.Namespace) -> dict:
     if block and args.layout != "dp":
         flag = ("--block" if args.block != "gpt2" else
                 "--ffn-width" if args.ffn_width else
-                "--remat" if args.remat != "none" else "--layer-pattern")
+                "--remat" if args.remat != "none" else
+                "--kv-heads" if args.kv_heads else
+                "--head-dim" if args.head_dim else "--layer-pattern")
         raise SystemExit(
             f"{flag} needs --layout dp: --layout {args.layout} writes GPT-2's "
             "block by hand (tp, ep, pp) or rings the sequence (sp), which a "
-            "linear layer's state does not cross"
+            "linear layer's state, a window and grouped key/value heads do not cross"
         )
     return block
 
@@ -3865,7 +3921,7 @@ def build_parser() -> argparse.ArgumentParser:
     p_lm.add_argument("--depth", type=int, default=4)
     p_lm.add_argument("--num-heads", type=int, default=4)
     p_lm.add_argument("--block", type=str, default="gpt2",
-                      choices=["gpt2", "olmo", "glm"],
+                      choices=["gpt2", "olmo", "glm", "mellum"],
                       help="the block's recipe (models/transformer.py "
                            "BLOCK_RECIPES): gpt2 = pre-LayerNorm, learned "
                            "positions, GELU MLP; olmo = RMSNorm on each "
@@ -3874,14 +3930,41 @@ def build_parser() -> argparse.ArgumentParser:
                            "attention with rotary keys, a dense gated FFN in "
                            "the leading layers and sigmoid-routed experts "
                            "beside a shared one after (models/moe.py), sized "
-                           "by the flags from --q-rank to --mtp-weight. "
-                           "--layout dp only")
+                           "by the flags from --q-rank to --mtp-weight; mellum "
+                           "= pre-RMSNorm, rotary positions in every attention "
+                           "layer (--rope-theta, --yarn-*), routed experts "
+                           "alone in place of the FFN (--router softmax, "
+                           "--routed-experts ... --expert-width, --shared-experts "
+                           "0 --dense-layers 0). --layout dp only")
     p_lm.add_argument("--layer-pattern", type=str, default="full",
                       metavar="KIND[,KIND...]",
                       help="mixer of each layer, repeated over --depth: full "
-                           "(softmax attention) | linear (gated delta rule, "
+                           "(softmax attention) | window (the same over the last "
+                           "--window positions) | linear (gated delta rule, "
                            "models/linear_attention.py), e.g. "
                            "linear,linear,linear,full; mla is --block glm's")
+    p_lm.add_argument("--kv-heads", type=int, default=0, metavar="N",
+                      help="key/value heads of the full and window layers: "
+                           "query head i reads head i // (--num-heads / N) "
+                           "(0 = --num-heads)")
+    p_lm.add_argument("--head-dim", type=int, default=0, metavar="N",
+                      help="size of an attention head (0 = --width / --num-heads)")
+    p_lm.add_argument("--window", type=int, default=0, metavar="N",
+                      help="a window layer's query sees the N positions up to its own")
+    p_lm.add_argument("--router", type=str, default="sigmoid", choices=["sigmoid", "softmax"],
+                      help="the routed experts' scores: sigmoid = one a token and "
+                           "expert, a selection bias in the choice (--block glm's); "
+                           "softmax = over all the router's outputs, the chosen "
+                           "renormalised, no bias (--block mellum's)")
+    for flag, kind, default, text in (
+        ("--yarn-factor", float, 0.0, "YaRN's length factor on the full layers' rotation (0 = no scaling)"),
+        ("--yarn-original-len", int, 0, "positions the unscaled frequencies were trained at"),
+        ("--yarn-beta-fast", float, 32.0, "turns within that length above which a pair keeps its frequency"),
+        ("--yarn-beta-slow", float, 1.0, "turns below which a pair's frequency is divided by the factor"),
+        ("--yarn-attention-factor", float, 0.0, "factor on cos and sin (0 = 0.1 ln(factor) + 1)"),
+    ):
+        p_lm.add_argument(flag, type=kind, default=default, metavar="N",
+                          help=f"--block mellum: {text}")
     p_lm.add_argument("--ffn-width", type=int, default=0, metavar="N",
                       help="hidden width of the FFN (0 = 4 x --width)")
     p_lm.add_argument("--linear-key-dim", type=int, default=0, metavar="N",
@@ -3916,7 +3999,7 @@ def build_parser() -> argparse.ArgumentParser:
         ("--mtp-weight", float, 0.3, "weight of the prediction module's loss"),
     ):
         p_lm.add_argument(flag, type=kind, default=default, metavar="N",
-                          help=f"--block glm: {text}")
+                          help=f"--block glm (and mellum, its experts and --rope-theta): {text}")
     p_lm.add_argument("--num-experts", type=int, default=8,
                       help="--layout dp-ep: experts of parallel/moe.py's switch "
                            "top-1 layer, another model than --block glm's expert layer")

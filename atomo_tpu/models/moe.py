@@ -1,6 +1,9 @@
 """Latent attention and a routed expert layer: the block of the ``mla`` layers
 of a :class:`~atomo_tpu.models.transformer.TransformerLM`, and the
-multi-token-prediction module that follows the last of them.
+multi-token-prediction module that follows the last of them. The routed
+experts (:class:`RoutedExperts`, sized by :class:`ExpertSizes`) are also the
+``experts`` FFN of the plain :class:`~atomo_tpu.models.transformer.Block`,
+there with a softmax router and nothing beside them.
 
 Block, pre-norm, no biases (u the block's input after RMSNorm):
 
@@ -22,8 +25,11 @@ plus the routed experts (:class:`RoutedExperts`, scope ``moe``). The router
 scores every token over all ``experts`` with a sigmoid in float32, chooses the
 ``per_token`` largest of score + bias (the bias enters the choice alone and
 takes no gradient), and weights a chosen expert by ``route_scale`` times its
-score over the sum of the chosen scores. No capacity, no dropped token, no
-auxiliary loss.
+score over the sum of the chosen scores. That is the router's ``sigmoid``
+scoring; under ``softmax`` the scores are a softmax over all ``experts``
+outputs, the ``per_token`` largest are chosen with no bias (the layer then has
+no such leaf) and weighted by their score over the chosen scores' sum. No
+capacity, no dropped token, no auxiliary loss.
 
 **This chip's share.** The layer holds experts ``[first, first + held)`` of
 the router's ``experts``. It routes over all of them, computes its own, and
@@ -65,6 +71,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from atomo_tpu.models.rotary import rotary, rotary_angles
 from atomo_tpu.parallel.ring import full_attention, fused_layers, kept_score_bytes
 from atomo_tpu.utils.tracing import named_phase
 
@@ -72,10 +79,52 @@ INIT = nn.initializers.normal(0.02)
 FLOAT32_LEAVES = ("router", "route_bias")  # kept out of the bf16 cast: the gate computes in float32
 
 
+SCORINGS = ("sigmoid", "softmax")
+
+
+def _check_experts(z) -> None:
+    held = z.experts_held or z.experts
+    if not 0 <= z.first_expert <= z.experts - held:
+        raise ValueError(
+            f"experts [{z.first_expert}, {z.first_expert + held}) are not "
+            f"among the router's {z.experts}"
+        )
+    if z.per_token > z.experts:
+        raise ValueError(f"{z.per_token} experts per token of {z.experts}")
+    if z.scoring not in SCORINGS:
+        raise ValueError(f"unknown router scoring {z.scoring!r}; expected {' | '.join(SCORINGS)}")
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertSizes:
+    """What :class:`RoutedExperts` needs: the router's outputs, this chip's
+    share of them, and the router's rule. ``scoring`` is ``sigmoid`` (a
+    score a token and expert on its own, a selection bias in the choice, the
+    chosen scores over their sum times ``route_scale``) or ``softmax`` (over
+    all the router's outputs, the chosen ones renormalised over their sum; no
+    bias leaf)."""
+
+    expert_width: int
+    experts: int  # the router's outputs
+    experts_held: int = 0  # 0: all of them
+    first_expert: int = 0
+    per_token: int = 4
+    scoring: str = "sigmoid"
+    route_scale: float = 1.0
+
+    def __post_init__(self):
+        _check_experts(self)
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.experts
+
+
 @dataclasses.dataclass(frozen=True)
 class LatentMoeSizes:
     """What a configuration of this family carries beside width, depth, heads
-    and the dense FFN's width; one hashable field of the model."""
+    and the dense FFN's width; one hashable field of the model. Its expert
+    layer reads the fields it shares with :class:`ExpertSizes`."""
 
     q_rank: int
     kv_rank: int
@@ -94,16 +143,10 @@ class LatentMoeSizes:
     norm_eps: float = 1e-5
     mtp_depth: int = 0
     mtp_weight: float = 0.3
+    scoring: str = "sigmoid"
 
     def __post_init__(self):
-        held = self.experts_held or self.experts
-        if not 0 <= self.first_expert <= self.experts - held:
-            raise ValueError(
-                f"experts [{self.first_expert}, {self.first_expert + held}) are not "
-                f"among the router's {self.experts}"
-            )
-        if self.per_token > self.experts:
-            raise ValueError(f"{self.per_token} experts per token of {self.experts}")
+        _check_experts(self)
         if self.rope_dim % 2:
             raise ValueError(f"rotary pairs need an even rope_dim, not {self.rope_dim}")
         if self.mtp_depth not in (0, 1):
@@ -122,24 +165,6 @@ def gated_ffn(y: jax.Array, hidden: int, prefix: str = "") -> jax.Array:
         gate = nn.Dense(hidden, use_bias=False, name=f"{prefix}gate")(y)
         y = nn.silu(gate) * nn.Dense(hidden, use_bias=False, name=f"{prefix}up")(y)
         return nn.Dense(width, use_bias=False, name=f"{prefix}down")(y)
-
-
-def rotary_angles(positions: jax.Array, dim: int, theta: float):
-    """cos and sin, (S, dim / 2) in float32, of position times
-    theta^(-2j / dim) for the pair j."""
-    inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    return jnp.cos(angles), jnp.sin(angles)
-
-
-def rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate the pairs (j, j + dim / 2) of the last axis (the half-split
-    pairing) by their angle; ``cos`` and ``sin`` broadcast against the halves.
-    In float32, back in x's dtype."""
-    first, second = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [first * cos - second * sin, second * cos + first * sin], axis=-1
-    ).astype(x.dtype)
 
 
 class LatentAttention(nn.Module):
@@ -234,7 +259,7 @@ class RoutedExperts(nn.Module):
     """The routed part of the expert layer on this chip's share of the
     experts; the shared expert is its caller's."""
 
-    sizes: LatentMoeSizes
+    sizes: "ExpertSizes | LatentMoeSizes"
 
     @nn.compact
     def __call__(self, u: jax.Array) -> jax.Array:
@@ -242,19 +267,25 @@ class RoutedExperts(nn.Module):
         width, k, held = u.shape[-1], z.per_token, z.held
         x = u.reshape(-1, width)
         router = self.param("router", INIT, (width, z.experts), jnp.float32)
-        bias = self.param("route_bias", nn.initializers.zeros, (z.experts,), jnp.float32)
+        if z.scoring == "sigmoid":
+            bias = self.param("route_bias", nn.initializers.zeros, (z.experts,), jnp.float32)
         rows = lambda name, a, b: self.param(name, INIT, (held, a, b))  # noqa: E731
         gate, up = rows("gate", width, z.expert_width), rows("up", width, z.expert_width)
         down = rows("down", z.expert_width, width)
         with named_phase("moe"):
             with named_phase("moe_route"):
-                scores = jax.nn.sigmoid(jnp.dot(
+                logits = jnp.dot(
                     x.astype(jnp.float32), router.astype(jnp.float32),
                     precision=jax.lax.Precision.HIGHEST,
-                ))
-                _, chosen = jax.lax.top_k(
-                    jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), k
                 )
+                if z.scoring == "sigmoid":
+                    scores = jax.nn.sigmoid(logits)
+                    _, chosen = jax.lax.top_k(
+                        jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), k
+                    )
+                else:
+                    scores = jax.nn.softmax(logits, axis=-1)
+                    _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores), k)
                 self.sow("intermediates", "chosen", chosen)  # kept only where a caller asks for it
                 picked = jnp.take_along_axis(scores, chosen, axis=-1)
                 weights = z.route_scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)

@@ -9,8 +9,12 @@ attention in every layer. The block's other choices are fields of
 :class:`TransformerLM` (``BLOCK_RECIPES`` names the sets that go together):
 RMSNorm, the norm on each sublayer's output, no positional embedding, a
 SiLU-gated FFN of its own width, an RMSNorm over the projected queries and
-keys, a per-layer mixer from ``layer_pattern`` (``full`` attention or the
-``linear`` gated delta rule of models/linear_attention.py), and ``remat``,
+keys, a per-layer mixer from ``layer_pattern`` (``full`` attention, the same
+over a ``window`` of the last positions, or the ``linear`` gated delta rule
+of models/linear_attention.py), fewer key/value heads than query heads and a
+head size of its own, rotary positions with a rule a mixer kind (``rope``;
+models/rotary.py), the routed experts of models/moe.py in place of the FFN
+(``ffn="experts"``), and ``remat``,
 which has a block keep only its weight matmuls for the backward pass. A
 pattern of ``mla`` layers takes its block whole from models/moe.py (latent
 attention with rotary keys, a dense FFN in the leading layers and routed
@@ -35,11 +39,15 @@ import jax
 import jax.numpy as jnp
 
 from atomo_tpu.models.linear_attention import GatedDeltaNet
-from atomo_tpu.models.moe import LatentMoeBlock, LatentMoeSizes, gated_ffn, mtp_input
-from atomo_tpu.parallel.ring import full_attention, fused_layers, kept_score_bytes
+from atomo_tpu.models.moe import (
+    ExpertSizes, LatentMoeBlock, LatentMoeSizes, RoutedExperts, gated_ffn, mtp_input,
+)
+from atomo_tpu.models.rotary import Rotary, rotary, rotary_angles
+from atomo_tpu.parallel.ring import full_attention, fused_layers, kept_score_bytes, tile_score_bytes
+from atomo_tpu.utils.tracing import named_phase
 
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
-MIXERS = ("full", "linear", "mla")
+MIXERS = ("full", "window", "linear", "mla")
 # the block choices that go together, as `lm --block` names them; "olmo" is
 # the OLMo 2/3 family's: the norm reordered onto the sublayers' outputs, q/k
 # norm, SwiGLU, and (Olmo-Hybrid's `rope_theta: null`) no positions at all;
@@ -51,12 +59,18 @@ BLOCK_RECIPES = {
     "olmo": dict(norm="rmsnorm", norm_placement="post", positions="none",
                  ffn="swiglu", qk_norm=True),
     "glm": dict(norm="rmsnorm", positions="none", ffn="swiglu", layer_pattern=("mla",)),
+    # "mellum" is Mellum 2's: pre-norm RMSNorm, rotary positions in every
+    # attention layer (a rule a mixer kind, `rope`), grouped key/value heads
+    # of their own size, and in place of the FFN the routed experts of
+    # models/moe.py with a softmax router and no shared expert
+    "mellum": dict(norm="rmsnorm", positions="rotary", ffn="experts"),
 }
 
 
 # the choices a TransformerLM hands to every one of its blocks unchanged
 BLOCK_FIELDS = ("dropout", "attention_fn", "norm", "norm_placement", "ffn", "ffn_width",
-                "qk_norm", "linear_key_dim", "linear_value_dim", "linear_conv_width")
+                "qk_norm", "linear_key_dim", "linear_value_dim", "linear_conv_width",
+                "kv_heads", "experts")
 
 
 def _norm(kind: str, name: str) -> nn.Module:
@@ -68,31 +82,51 @@ def _norm(kind: str, name: str) -> nn.Module:
 
 
 class MultiHeadAttention(nn.Module):
+    """Softmax attention of ``num_heads`` query heads over ``kv_heads``
+    key/value heads (0: as many), all of ``head_dim``: query head i reads
+    key/value head i // (num_heads / kv_heads). ``window`` keeps a query to
+    the keys less than that many positions behind it (0: all before it).
+    ``rope`` rotates queries and keys over the whole head by position."""
+
     num_heads: int
     head_dim: int
     attention_fn: Optional[AttentionFn] = None
     qk_norm: bool = False
+    kv_heads: int = 0
+    window: int = 0
+    rope: Optional[Rotary] = None
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, pos_offset=0) -> jax.Array:
         b, s, _ = x.shape
         h, d = self.num_heads, self.head_dim
-        qkv = nn.Dense(3 * h * d, use_bias=False, name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        hk = self.kv_heads or h
+        qkv = nn.Dense((h + 2 * hk) * d, use_bias=False, name="qkv")(x)
+        q, k, v = jnp.split(qkv, [h * d, (h + hk) * d], axis=-1)
         if self.qk_norm:  # over the whole projection, before the split into heads
             q, k = nn.RMSNorm(name="q_norm")(q), nn.RMSNorm(name="k_norm")(k)
 
-        def heads(t):  # (B, S, H*D) -> (B, H, S, D)
-            return t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+        def heads(t, n):  # (B, S, n*D) -> (B, n, S, D)
+            return t.reshape(b, s, n, d).transpose(0, 2, 1, 3)
 
+        q, k, v = heads(q, h), heads(k, hk), heads(v, hk)
+        if self.rope is not None:
+            with named_phase("rope"):
+                cos, sin = rotary_angles(pos_offset + jnp.arange(s), d, self.rope.theta, self.rope.yarn)
+                q, k = rotary(q, cos, sin), rotary(k, cos, sin)
         fn = self.attention_fn or partial(full_attention, causal=True)
-        q, k, v = heads(q), heads(k), heads(v)
-        out = fn(q, k, v)  # (B, H, S, D)
+        if self.window:
+            fn = partial(fn, window=self.window)
+        out = fn(q, k, v)
         # read by the lm step into its metrics, summed over the full layers
         if kept := kept_score_bytes(fn, q):
             self.sow("counters", "attn_score_bytes", jnp.float32(kept))
         if fused := fused_layers(fn, q):
             self.sow("counters", "attn_fused_layers", jnp.float32(fused))
+        # of the layers that the band or the groups shape: the others' steps
+        # report what they reported
+        if (self.window or hk != h) and (tiles := tile_score_bytes(fn, q)):
+            self.sow("counters", "attn_tile_score_bytes", jnp.float32(tiles))
         out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
         return nn.Dense(x.shape[-1], use_bias=False, name="proj")(out)
 
@@ -112,6 +146,10 @@ class Block(nn.Module):
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv_width: int = 4
+    kv_heads: int = 0  # 0: num_heads
+    window: int = 0  # of a `window` mixer
+    rope: Optional[Rotary] = None  # this mixer kind's rule
+    experts: Optional[ExpertSizes] = None  # of the `experts` FFN
 
     def _ffn(self, y: jax.Array) -> jax.Array:
         width = y.shape[-1]
@@ -120,20 +158,30 @@ class Block(nn.Module):
             y = nn.Dense(hidden, use_bias=False, name="up")(y)
             y = nn.gelu(y)
             return nn.Dense(width, use_bias=False, name="down")(y)
+        if self.ffn == "experts":
+            if self.experts is None:
+                raise ValueError("the `experts` FFN needs its sizes: experts=ExpertSizes(...)")
+            return RoutedExperts(self.experts, name="moe")(y)
         if self.ffn != "swiglu":
-            raise ValueError(f"unknown ffn {self.ffn!r}; expected gelu | swiglu")
+            raise ValueError(f"unknown ffn {self.ffn!r}; expected gelu | swiglu | experts")
         return gated_ffn(y, hidden)
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    def __call__(self, x: jax.Array, train: bool = False, pos_offset=0) -> jax.Array:
         if self.norm_placement not in ("pre", "post"):
             raise ValueError(
                 f"unknown norm_placement {self.norm_placement!r}; expected pre | post"
             )
         pre = self.norm_placement == "pre"
-        if self.mixer == "full":
-            mixer = MultiHeadAttention(
-                self.num_heads, self.head_dim, self.attention_fn, self.qk_norm
+        if self.mixer in ("full", "window"):
+            if (self.mixer == "window") != (self.window > 0):
+                raise ValueError("a `window` layer, and no other, takes a window: window=N")
+            mixer = partial(
+                MultiHeadAttention(
+                    self.num_heads, self.head_dim, self.attention_fn, self.qk_norm,
+                    self.kv_heads, self.window, self.rope,
+                ),
+                pos_offset=pos_offset,
             )
         elif self.mixer == "linear":
             mixer = GatedDeltaNet(
@@ -165,8 +213,8 @@ class TransformerLM(nn.Module):
     attention_fn: Optional[AttentionFn] = None  # of the `full` layers
     norm: str = "layernorm"  # layernorm | rmsnorm
     norm_placement: str = "pre"  # pre | post
-    positions: str = "learned"  # learned | none
-    ffn: str = "gelu"  # gelu | swiglu
+    positions: str = "learned"  # learned | none | rotary: by `rope`, in the attention layers
+    ffn: str = "gelu"  # gelu | swiglu | experts
     ffn_width: int = 0  # 0: 4 x width
     qk_norm: bool = False
     layer_pattern: tuple = ("full",)  # mixer kinds, repeated over the depth
@@ -175,6 +223,11 @@ class TransformerLM(nn.Module):
     linear_conv_width: int = 4
     remat: str = "none"  # none | dots: what a block keeps for the backward pass of a training step
     latent_moe: Optional[LatentMoeSizes] = None  # of the `mla` layers, which then are all the layers
+    kv_heads: int = 0  # key/value heads of the `full` and `window` layers; 0: num_heads
+    head_dim: int = 0  # 0: width / num_heads
+    window: int = 0  # of the `window` layers
+    rope: tuple = ()  # ((mixer kind, Rotary), ...): the rule of each kind under positions="rotary"
+    experts: Optional[ExpertSizes] = None  # of the `experts` FFN
 
     @nn.compact
     def __call__(
@@ -184,7 +237,7 @@ class TransformerLM(nn.Module):
         axis_index(sp) * S_local when the sequence dim is sharded, so every
         shard embeds its true positions (not local 0..S/n)."""
         b, s = tokens.shape
-        head_dim = self.width // self.num_heads
+        head_dim = self.head_dim or self.width // self.num_heads
         embed = nn.Embed(self.vocab_size, self.width, name="tok_emb")
         x = embed(tokens)
         if self.positions == "learned":
@@ -192,10 +245,13 @@ class TransformerLM(nn.Module):
                 pos_offset + jnp.arange(s)
             )
             x = x + pos[None, :, :]
-        elif self.positions != "none":
+        elif self.positions not in ("none", "rotary"):
             raise ValueError(
-                f"unknown positions {self.positions!r}; expected learned | none"
+                f"unknown positions {self.positions!r}; expected learned | none | rotary"
             )
+        rope = dict(self.rope)
+        if (self.positions == "rotary") != bool(rope):
+            raise ValueError("positions='rotary' and `rope`, a rule a mixer kind, come together")
         if self.remat not in ("none", "dots"):
             raise ValueError(f"unknown remat {self.remat!r}; expected none | dots")
         if self.remat == "none" or not train:
@@ -216,13 +272,16 @@ class TransformerLM(nn.Module):
         block = remat(Block)
         shared = {f: getattr(self, f) for f in BLOCK_FIELDS}
         for i in range(self.depth):
-            x = block(
-                self.num_heads,
-                head_dim,
-                mixer=self.layer_pattern[i % len(self.layer_pattern)],
-                name=f"block{i}",
-                **shared,
-            )(x, train)
+            kind = self.layer_pattern[i % len(self.layer_pattern)]
+            if rope and kind in ("full", "window") and kind not in rope:
+                raise ValueError(f"rope has no rule for the `{kind}` layers: {sorted(rope)}")
+            # what only a layer with a window or a rotation takes; the others
+            # are built and called as they were
+            own = {"window": self.window} if kind == "window" else {}
+            if kind in rope:
+                own["rope"] = rope[kind]
+            layer = block(self.num_heads, head_dim, mixer=kind, name=f"block{i}", **shared, **own)
+            x = layer(x, train, pos_offset) if own else layer(x, train)
         x = _norm(self.norm, "ln_f")(x)
         return nn.Dense(self.vocab_size, use_bias=False, name="head")(x)
 

@@ -82,6 +82,7 @@ PHASE_OF_SCOPE = {
     "delayed_decode_mean": "decode",
     "forward_backward": "forward_backward",
     "attention": "attention",
+    "rope": "rope",
     "linear_attention": "linear_attention",
     "delta_chunk": "delta_chunk",
     "delta_scan": "delta_scan",
@@ -106,7 +107,7 @@ PHASE_OF_SCOPE = {
 # `moe_dispatch` and `moe_experts` with `moe` what is left outside the three.
 PHASES = ("encode", "exchange", "decode")
 MODEL_PHASES = (
-    "forward_backward", "attention", "linear_attention", "delta_chunk",
+    "forward_backward", "attention", "rope", "linear_attention", "delta_chunk",
     "delta_scan", "ffn", "mla", "moe", "moe_route", "moe_dispatch",
     "moe_experts", "mtp", "update",
 )

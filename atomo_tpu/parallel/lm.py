@@ -641,6 +641,19 @@ def make_lm_train_step(
             f"the `mla` layers' prediction module reads each token's successor "
             f"in its own shard: {sp_axis}={n_sp} needs latent_moe unset"
         )
+    experts = lm_config.get("experts")
+    if n_sp > 1 and experts is not None:
+        raise ValueError(
+            f"the `experts` FFN sorts all of a replica's tokens, which the {sp_axis!r} "
+            f"ring holds in shards: {sp_axis}={n_sp} needs experts unset"
+        )
+    if n_sp > 1 and (lm_config.get("kv_heads") or "window" in lm_config.get("layer_pattern", ())
+                     or lm_config.get("positions") == "rotary"):
+        raise ValueError(
+            f"a window, grouped key/value heads and rotary positions are the "
+            f"one-device attention core's: {sp_axis}={n_sp} needs kv_heads and rope "
+            "unset and no `window` layer in layer_pattern"
+        )
     if n_sp > 1 and "linear" in lm_config.get("layer_pattern", ()):
         raise ValueError(
             f"a linear-attention layer carries its state along the sequence, "
@@ -666,7 +679,7 @@ def make_lm_train_step(
                 # bf16 MXU compute, f32 master state; token ids are integer
                 # inputs, so only the params need the cast
                 master, params = params, cast_params(params, compute_dtype)
-                if latent_moe is not None:  # the gate computes in float32
+                if latent_moe is not None or experts is not None:  # the gate computes in float32
                     params = keep_float32(params, master, FLOAT32_LEAVES)
             s_local = tokens.shape[1]
             # what the layers count as they are traced or run: SOWN_COUNTS

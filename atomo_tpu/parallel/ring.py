@@ -43,6 +43,18 @@ blocks is capped whatever the sequence length: every block is a set of
 contractions of shapes no other block has, and the step program's set-up
 pays for each distinct shape (PERF.md, PR 30). ``kept_score_bytes`` counts
 what is left of the square, for the step's ``attn_score_bytes``.
+
+That one-device causal core also takes a **window** (a query sees the keys
+less than ``window`` positions behind it) and **grouped key/value heads** (k
+and v with H / group heads; query head i reads head i // group). A window is
+not a mask over the triangle: a query block of the jnp path runs against the
+keys from its window's start to its own end (``block_key_ranges``), the
+kernels' grids walk only the tiles the band touches, and
+``tile_score_bytes`` counts what either computes. A group's query heads are
+rows of one contraction against their shared head in the jnp path and share
+its blocks in the kernels, which sum dK and dV over the group themselves:
+neither broadcasts k or v. The multi-block paths (the ring over n > 1,
+``blockwise_attention``, ``ulysses_attention``) refuse both.
 """
 
 from __future__ import annotations
@@ -157,8 +169,13 @@ def _one_block_attention(q, k, v, bias, scale):
     return (o / l[..., None]).astype(q.dtype)
 
 
-def _causal_bias(q_pos, k_pos):
-    return jnp.where(q_pos[:, None] >= k_pos[None, :], 0.0, jnp.float32(-jnp.inf))
+def _causal_bias(q_pos, k_pos, window: int = 0):
+    """0 where a key is at or below its query and, under a window, less than
+    ``window`` positions behind it; -inf elsewhere."""
+    seen = q_pos[:, None] >= k_pos[None, :]
+    if window:
+        seen = seen & (q_pos[:, None] - k_pos[None, :] < window)
+    return jnp.where(seen, 0.0, jnp.float32(-jnp.inf))
 
 
 QUERY_BLOCK_MULTIPLE = 128  # a block's keys end on a lane boundary of the scores
@@ -179,53 +196,92 @@ def causal_query_blocks(sq: int, sk: int) -> int:
     )
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _causal_blocks(q, k, v, n, scale):
+def block_key_ranges(s: int, n: int, window: int = 0) -> list[tuple[int, int, int]]:
+    """(first query, first key, end) of each of the n query blocks of S
+    positions: a block's keys run to its own end, from 0 or, under a window,
+    from the lane boundary at or below the first key its first query sees."""
+    blk = s // n
+    out = []
+    for end in range(blk, s + 1, blk):
+        start = max(end - blk - window + 1, 0) if window else 0
+        out.append((end - blk, start - start % QUERY_BLOCK_MULTIPLE, end))
+    return out
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _causal_blocks(q, k, v, n, scale, window=0):
     """Causal softmax of S queries over the same S keys in n query blocks,
-    each against its own key prefix with the triangle on the prefix's last
-    columns: (l, o) as ``_softmax_block`` returns them, rows concatenated.
+    each against its own key prefix (under a window: the keys from its
+    window's start to its own end, ``block_key_ranges``) with the mask on
+    the columns the diagonal and the window's edge cross: (l, o) as
+    ``_softmax_block`` returns them, rows concatenated.
     A row's softmax is the one-block path's (the keys left out contributed
     exp(-inf) = 0). One differentiation rule over all blocks, so that dK and
     dV are summed over the blocks in float32 and rounded once, as the one
-    contraction over all queries rounds them."""
-    return _causal_blocks_fwd(q, k, v, n, scale)[0]
+    contraction over all queries rounds them.
+
+    q may have a whole number of times k's heads (grouped queries): the
+    group's heads are then rows of one contraction against their shared key
+    and value head, so dK and dV come out summed over the group."""
+    return _causal_blocks_fwd(q, k, v, n, scale, window)[0]
 
 
-def _causal_blocks_fwd(q, k, v, n, scale):
-    blk = q.shape[-2] // n
+def _group_rows(t, group: int):
+    """(B, H, rows, D) -> (B, H / group, group x rows, D): a group's query
+    heads as rows over their one key/value head; as it is for a group of 1."""
+    if group == 1:
+        return t
+    b, h, rows = t.shape[:3]
+    return t.reshape(b, h // group, group * rows, *t.shape[3:])
+
+
+def _ungroup_rows(t, group: int):
+    if group == 1:
+        return t
+    b, hk, rows = t.shape[:3]
+    return t.reshape(b, hk * group, rows // group, *t.shape[3:])
+
+
+def _block_bias(first, start, end, window, group):
+    bias = _causal_bias(jnp.arange(first, end), jnp.arange(start, end), window)
+    return bias if group == 1 else jnp.tile(bias, (group, 1))
+
+
+def _causal_blocks_fwd(q, k, v, n, scale, window=0):
+    group = q.shape[1] // k.shape[1]
     ls, os, ps = [], [], []
-    for end in range(blk, q.shape[-2] + 1, blk):
-        bias = _causal_bias(jnp.arange(end - blk, end), jnp.arange(end))
+    for first, start, end in block_key_ranges(q.shape[-2], n, window):
+        bias = _block_bias(first, start, end, window, group)
         (_, l, o), (_, _, _, p) = _softmax_block_fwd(
-            q[:, :, end - blk:end], k[:, :, :end], v[:, :, :end], bias, None, scale
+            _group_rows(q[:, :, first:end], group), k[:, :, start:end], v[:, :, start:end], bias, None, scale
         )
-        ls.append(l), os.append(o), ps.append(p)
+        ls.append(_ungroup_rows(l, group)), os.append(_ungroup_rows(o, group)), ps.append(p)
     return (jnp.concatenate(ls, axis=2), jnp.concatenate(os, axis=2)), (q, k, v, ps)
 
 
-def _causal_blocks_bwd(n, scale, res, cts):
+def _causal_blocks_bwd(n, scale, window, res, cts):
     q, k, v, ps = res
     dl, do = cts
-    blk = q.shape[-2] // n
+    group = q.shape[1] // k.shape[1]
     dqs = []
     dk, dv = jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)
-    for end, p in zip(range(blk, q.shape[-2] + 1, blk), ps, strict=True):
-        rows = slice(end - blk, end)
+    for (first, start, end), p in zip(block_key_ranges(q.shape[-2], n, window), ps, strict=True):
+        rows = slice(first, end)
         dq_blk, dk_blk, dv_blk = _softmax_block_grads(
-            scale, (q[:, :, rows], k[:, :, :end], v[:, :, :end], p),
-            dl[:, :, rows], do[:, :, rows],
+            scale, (_group_rows(q[:, :, rows], group), k[:, :, start:end], v[:, :, start:end], p),
+            _group_rows(dl[:, :, rows], group), _group_rows(do[:, :, rows], group),
         )
-        dqs.append(dq_blk.astype(q.dtype))
-        dk, dv = dk.at[:, :, :end].add(dk_blk), dv.at[:, :, :end].add(dv_blk)
+        dqs.append(_ungroup_rows(dq_blk, group).astype(q.dtype))
+        dk, dv = dk.at[:, :, start:end].add(dk_blk), dv.at[:, :, start:end].add(dv_blk)
     return jnp.concatenate(dqs, axis=2), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 _causal_blocks.defvjp(_causal_blocks_fwd, _causal_blocks_bwd)
 
 
-@partial(jax.jit, static_argnums=(3, 4))  # traced once per shape, not once per layer
-def _causal_blocks_attention(q, k, v, n, scale):
-    l, o = _causal_blocks(q, k, v, n, scale)
+@partial(jax.jit, static_argnums=(3, 4, 5))  # traced once per shape, not once per layer
+def _causal_blocks_attention(q, k, v, n, scale, window=0):
+    l, o = _causal_blocks(q, k, v, n, scale, window)
     return (o / l[..., None]).astype(q.dtype)
 
 
@@ -277,18 +333,44 @@ def fused_blocks(q_shape, k_shape, dtype, on_tpu: Optional[bool] = None) -> Opti
     return blocks
 
 
-def _cut_causal_attention(q, k, v, scale):
+def _cut_causal_attention(q, k, v, scale, window=0):
     """Causal attention of one K/V block on one device, nothing to rescale:
     through the fused kernels where ``fused_blocks`` says so (a TPU,
     bfloat16, a head size and a sequence they were measured at), else in
-    query blocks; None where the sequence is not cut (the one-block program)."""
+    query blocks; None where the sequence is not cut (the one-block program).
+    Both take a window and grouped queries as they are: neither computes a
+    tile or a block that the band does not touch."""
     if (blocks := fused_blocks(q.shape, k.shape, q.dtype)) is not None:
         from atomo_tpu.ops.attention_kernels import fused_attention, interpret_requested
 
-        return fused_attention(q, k, v, True, float(scale), blocks, interpret_requested())
+        return fused_attention(q, k, v, True, float(scale), blocks, interpret_requested(), window)
     if (n := causal_query_blocks(q.shape[-2], k.shape[-2])) > 1:
-        return _causal_blocks_attention(q, k, v, n, scale)
+        return _causal_blocks_attention(q, k, v, n, scale, window)
     return None
+
+
+def _one_block_grouped(q, k, v, bias, scale):
+    """The one-block program, a group's query heads as rows over their one
+    key/value head."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1 and bias is not None:
+        bias = jnp.tile(bias, (group, 1))
+    return _ungroup_rows(_one_block_attention(_group_rows(q, group), k, v, bias, scale), group)
+
+
+def _check_core(q, k, window: int, causal: bool, multi_block: str = "") -> None:
+    """What the one-device causal core takes and no other path does: a
+    window, and fewer key/value heads than query heads. ``multi_block``
+    names a path that runs the sequence in several key/value blocks."""
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads are no whole number of {k.shape[1]} key/value heads")
+    if window and not causal:
+        raise ValueError("a window is a causal band: causal=True")
+    if multi_block and (window or q.shape[1] != k.shape[1]):
+        raise ValueError(
+            f"{multi_block} runs the sequence in several key/value blocks: a window "
+            "and grouped key/value heads are the one-device causal core's alone"
+        )
 
 
 def _common_dtype(q, k, v):
@@ -306,23 +388,28 @@ def ring_attention(
     axis_size: int,
     causal: bool = False,
     scale: Optional[float] = None,
+    window: int = 0,
 ) -> jax.Array:
     """Exact multi-head attention with sequence sharded over ``axis_name``.
 
     Call inside shard_map with q/k/v of per-chip shape (B, H, S/n, D); the
     global sequence order is shard-major (chip r holds positions
     [r*S/n, (r+1)*S/n)). Returns the per-chip output block (B, H, S/n, D).
+    Over an axis of one, ``window`` keeps each query to the keys less than
+    that many positions behind it, and k and v may have fewer heads than q
+    (query head i reads key/value head i // group).
     """
     b, h, s_local, d = q.shape
     if scale is None:
         scale = 1.0 / (d**0.5)
+    _check_core(q, k, window, causal, f"the ring over {axis_name}={axis_size}" if axis_size > 1 else "")
     q, k, v = _common_dtype(q, k, v)
     if axis_size == 1:  # one K/V block: no rotation, nothing to rescale
-        if causal and (out := _cut_causal_attention(q, k, v, scale)) is not None:
+        if causal and (out := _cut_causal_attention(q, k, v, scale, window)) is not None:
             return out
         pos = jnp.arange(s_local)
-        return _one_block_attention(
-            q, k, v, _causal_bias(pos, pos) if causal else None, scale
+        return _one_block_grouped(
+            q, k, v, _causal_bias(pos, pos, window) if causal else None, scale
         )
     my = jax.lax.axis_index(axis_name)
 
@@ -357,20 +444,22 @@ def ring_attention(
 @named_phase("attention")
 def full_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
-    scale: Optional[float] = None,
+    scale: Optional[float] = None, window: int = 0,
 ) -> jax.Array:
     """Single-device exact attention (B, H, S, D) — the oracle ring_attention
-    must match, and the path used when no 'sp' axis is in play."""
+    must match, and the path used when no 'sp' axis is in play. ``window``
+    and fewer key/value heads as :func:`ring_attention` takes them."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
+    _check_core(q, k, window, causal)
     q, k, v = _common_dtype(q, k, v)
-    if causal and (out := _cut_causal_attention(q, k, v, scale)) is not None:
+    if causal and (out := _cut_causal_attention(q, k, v, scale, window)) is not None:
         return out
     bias = None
     if causal:
-        bias = _causal_bias(jnp.arange(q.shape[-2]), jnp.arange(k.shape[-2]))
-    return _one_block_attention(q, k, v, bias, scale)
+        bias = _causal_bias(jnp.arange(q.shape[-2]), jnp.arange(k.shape[-2]), window)
+    return _one_block_grouped(q, k, v, bias, scale)
 
 
 def _one_device_keywords(attention_fn) -> Optional[dict]:
@@ -394,6 +483,16 @@ def fused_layers(attention_fn, q: jax.Array) -> int:
     return int(fused_blocks(q.shape, q.shape, q.dtype) is not None)
 
 
+def _block_entries(given: dict, s: int) -> int:
+    """Score entries a (sequence, head) the jnp path computes: its query
+    blocks against the keys each sees."""
+    n = causal_query_blocks(s, s) if given.get("causal") else 1
+    return sum(
+        (end - first) * (end - start)
+        for first, start, end in block_key_ranges(s, n, given.get("window", 0))
+    )
+
+
 def kept_score_bytes(attention_fn, q: jax.Array) -> int:
     """Bytes of exponentials ``attention_fn(q, k, v)`` keeps for the backward
     pass, from shapes, where it is this module's one-device path over keys as
@@ -404,8 +503,26 @@ def kept_score_bytes(attention_fn, q: jax.Array) -> int:
     if given is None or fused_layers(attention_fn, q):
         return 0
     b, h, s, _ = q.shape
-    n = causal_query_blocks(s, s) if given.get("causal") else 1
-    return b * h * (s // n) ** 2 * (n * (n + 1) // 2) * q.dtype.itemsize
+    return b * h * _block_entries(given, s) * q.dtype.itemsize
+
+
+def tile_score_bytes(attention_fn, q: jax.Array) -> int:
+    """Bytes of the score entries ``attention_fn(q, k, v)`` computes in its
+    forward pass, at 4 B each, from shapes: the tiles the fused forward
+    kernel walks, or the jnp path's query blocks against the keys each sees.
+    A window that were a mask over the causal tiles would read as no window
+    here. 0 for a callable that is not this module's one-device path."""
+    given = _one_device_keywords(attention_fn)
+    if given is None:
+        return 0
+    b, h, s, _ = q.shape
+    entries = _block_entries(given, s)
+    if fused_layers(attention_fn, q):
+        from atomo_tpu.ops.attention_kernels import forward_tiles
+
+        bq, bk = fused_blocks(q.shape, q.shape, q.dtype).fwd
+        entries = forward_tiles(s, (bq, bk), given.get("window", 0)) * bq * bk
+    return b * h * entries * 4
 
 
 @named_phase("attention")
@@ -424,6 +541,7 @@ def blockwise_attention(
     b, h, s, d = q.shape
     if scale is None:
         scale = 1.0 / (d**0.5)
+    _check_core(q, k, 0, causal, "blockwise_attention")
     q, k, v = _common_dtype(q, k, v)
     blk = min(block_size, s)
     n_blocks = -(-s // blk)
@@ -488,6 +606,7 @@ def ulysses_attention(
     (tests/test_ring.py).
     """
     b, h, s_local, d = q.shape
+    _check_core(q, k, 0, causal, "ulysses_attention")
     if local_impl not in ("blockwise", "flash"):
         raise ValueError(
             f"unknown local_impl {local_impl!r}; expected blockwise|flash"

@@ -210,3 +210,91 @@ def test_blocked_core_is_faster_than_one_block_on_tpu(cell, monkeypatch):
     print(f"\n{cell} {(b, h, s, d)} core, ms a layer (first call with its compile, s): "
           + ", ".join(f"{name} {ms:.3f} ({first:.2f})" for name, (ms, first) in read.items()))
     assert read["8 blocks"][0] < 0.85 * read["one block"][0], read
+
+
+# --- a window and grouped key/value heads (PR 35): mellum2-1chip-dense's two kinds of layer
+
+MELLUM = (2, 32, 4, 8192, 128)  # batch, query heads, key/value heads, positions, head size
+
+
+def _grouped_qkv(key):
+    b, h, hk, s, d = MELLUM
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(key), 3)
+    return (jax.random.normal(kq, (b, h, s, d), jnp.bfloat16), jax.random.normal(kk, (b, hk, s, d), jnp.bfloat16),
+            jax.random.normal(kv, (b, hk, s, d), jnp.bfloat16))
+
+
+def _masked_oracle(window):
+    """Float32 at Precision.HIGHEST with an explicit mask, a sequence and a
+    key/value head at a time against its group of query heads (32 heads of
+    float32 scores over 8192 positions do not fit at once): forward and dq,
+    dk, dv, the last two summed over the group as the shared head's are."""
+
+    @jax.jit
+    def one(q, k, v, w):  # (group, S, D), (S, D), (S, D), (group, S, D)
+        s, d = q.shape[-2:]
+        behind = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+        seen = (behind >= 0) & ((behind < window) if window else True)
+
+        def fn(q, k, v):
+            scores = jnp.einsum("hqd,kd->hqk", q, k, precision="highest") / d**0.5
+            p = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+            return jnp.einsum("hqk,kd->hqd", p, v, precision="highest")
+
+        out, pull = jax.vjp(fn, q, k, v)
+        return (out, *pull(w))
+
+    def all_groups(q, k, v, w):
+        (b, h), hk = q.shape[:2], k.shape[1]
+        group, f32 = h // hk, jnp.float32
+        parts = [[one(q[i, g * group:(g + 1) * group].astype(f32), k[i, g].astype(f32), v[i, g].astype(f32),
+                      w[i, g * group:(g + 1) * group]) for g in range(hk)] for i in range(b)]
+        per_query_head = lambda j: jnp.stack([jnp.concatenate([p[j] for p in row], axis=0) for row in parts])  # noqa: E731
+        per_kv_head = lambda j: jnp.stack([jnp.stack([p[j] for p in row]) for row in parts])  # noqa: E731
+        return [per_query_head(0), per_query_head(1), per_kv_head(2), per_kv_head(3)]
+
+    return all_groups
+
+
+@pytest.mark.parametrize("window", [1024, 0], ids=["window1024", "full"])
+def test_window_and_grouped_heads_match_the_float32_oracle_at_the_cells_shape_on_tpu(window):
+    """(2, 32 over 4, 8192, 128) in bfloat16 through `full_attention`, which
+    takes the fused kernels here: forward and dq, dk, dv (dk and dv summed
+    over a group's 8 query heads in the kernel) against float32 with an
+    explicit mask and repeated heads, under the limits of the equal-headed
+    cells' test."""
+    from atomo_tpu.parallel.ring import full_attention, fused_blocks
+
+    q, k, v = _grouped_qkv(6)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+    assert fused_blocks(q.shape, k.shape, q.dtype) is not None
+    want = _masked_oracle(window)(q, k, v, w)
+    got = _forward_and_gradients(partial(full_attention, causal=True, window=window), w, q, k, v)
+    read = [_rel(g, ref) for g, ref in zip(got, want)]
+    print(f"\nwindow {window}: forward and dq, dk, dv against the float32 oracle: {read}")
+    for g, ref, gap, limit in zip(got, want, read, [4e-3, 6e-3, 6e-3, 6e-3]):
+        assert g.shape == ref.shape and g.dtype == jnp.bfloat16
+        assert gap < limit, (window, gap, limit)
+
+
+def test_a_window_layers_core_takes_under_two_thirds_of_a_full_layers_on_tpu():
+    """The core alone, forward and backward, ms a layer at the cell's shape
+    (printed for PERF.md): the pairs say 23%, the tiles at
+    `FUSED_BLOCKS[128]`'s sizes about 40%. And the same windowed layer
+    through the jnp blocks, which the kernels have to beat."""
+    from atomo_tpu.parallel import ring
+
+    q, k, v = _grouped_qkv(8)
+    w = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.bfloat16)
+    s = q.shape[-2]
+    blocked = lambda q, k, v: ring._causal_blocks_attention(  # noqa: E731
+        q, k, v, ring.causal_query_blocks(s, s), 1.0 / q.shape[-1] ** 0.5, 1024)
+    read = {
+        "full": _ms_a_layer(partial(ring.full_attention, causal=True), q, k, v, w),
+        "window 1024": _ms_a_layer(partial(ring.full_attention, causal=True, window=1024), q, k, v, w),
+        "window 1024, jnp blocks": _ms_a_layer(blocked, q, k, v, w, calls=10),
+    }
+    print(f"\nmellum {MELLUM} core, ms a layer (first call with its compile, s): "
+          + ", ".join(f"{name} {ms:.3f} ({first:.2f})" for name, (ms, first) in read.items()))
+    assert read["window 1024"][0] < 2 / 3 * read["full"][0], read
+    assert read["window 1024"][0] < read["window 1024, jnp blocks"][0], read
