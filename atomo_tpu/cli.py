@@ -3663,7 +3663,7 @@ def cmd_lm(args: argparse.Namespace) -> int:
         PROFILE_STEPS,
         STEP,
         ProfileWindow,
-        clear as clear_spans,
+        clear_iterations,
         span,
     )
 
@@ -3684,7 +3684,7 @@ def cmd_lm(args: argparse.Namespace) -> int:
     # the jits after it lowered a second slower on the v5e's host (PERF.md
     # §6, PR 32), and `setup_s` is an end-to-end metric.
     prof = ProfileWindow(args.profile_dir or None, print, recorder)
-    clear_spans()  # the ring holds this loop's iterations
+    clear_iterations()  # the ring holds set-up and this loop's iterations
     launched = start  # the last step handed to the device
     in_flight = collections.deque()  # metrics of the steps launched and not yet reported
     arrived = time.time()  # when the last loss came back
@@ -4152,7 +4152,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     from atomo_tpu.utils.compile_cache import enable_compile_cache
+    from atomo_tpu.utils.tracing import clear as clear_spans
 
+    clear_spans()  # the ring holds this call's set-up and its loop's iterations
     # jax.config only: nothing ahead of the sub-command body may initialise
     # a backend (a supervising parent must leave the chip to its child).
     # Logged to stderr so verbs with a machine-readable stdout (report

@@ -39,14 +39,15 @@ from atomo_tpu.utils.tracing import (
     FEED_START,
     FEED_TAKE,
     FETCH,
+    INIT_STATE,
     NEXT_BATCH,
     PROFILE_STEPS,
     STEP,
     ProfileWindow,
+    clear_iterations,
     named_phase,
     span,
 )
-from atomo_tpu.utils.tracing import clear as clear_spans
 
 
 @dataclasses.dataclass
@@ -94,17 +95,18 @@ def cast_compute_outputs(logits, new_stats):
 
 
 def create_state(model, optimizer, rng, sample_input) -> TrainState:
-    variables = model.init(
-        {"params": rng, "dropout": jax.random.PRNGKey(0)}, sample_input, train=False
-    )
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats", FrozenDict())
-    return TrainState(
-        step=jnp.zeros((), jnp.int32),
-        params=params,
-        batch_stats=batch_stats,
-        opt_state=optimizer.init(params),
-    )
+    with span(INIT_STATE):  # the initial state of both loops, eager compiles included
+        variables = model.init(
+            {"params": rng, "dropout": jax.random.PRNGKey(0)}, sample_input, train=False
+        )
+        params = variables["params"]
+        batch_stats = variables.get("batch_stats", FrozenDict())
+        return TrainState(
+            step=jnp.zeros((), jnp.int32),
+            params=params,
+            batch_stats=batch_stats,
+            opt_state=optimizer.init(params),
+        )
 
 
 def snapshot_state(state) -> "TrainState":
@@ -557,7 +559,7 @@ def train_loop(
         )
     n_train = len(train_iter.dataset)
     last_saved = start_step
-    clear_spans()  # the ring holds this loop's iterations
+    clear_iterations()  # the ring holds set-up and this loop's iterations
     if superstep > 1:
         # the watchdog beats once per BLOCK: scale its budget by K so a
         # --health-timeout tuned for per-step beats does not falsely fire

@@ -22,6 +22,8 @@ import os
 
 import jax
 
+from atomo_tpu.utils import tracing
+
 CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -32,11 +34,13 @@ _ENABLED_AT = None
 
 
 def enable_compile_cache(log_fn=print):
-    """Point the persistent cache at its directory (the module rule), drop
-    the size/time floors so every program caches, and report at exit what
-    the cache did: hits and misses as JAX's own monitoring events count
-    them, and the seconds spent in backend compilation (cache loads
-    included). Compile time is set-up time, never a speed number.
+    """Start the span ring's compile records (``tracing.listen``, cache or
+    no cache), point the persistent cache at its directory (the module
+    rule), drop the size/time floors so every program caches, and report at
+    exit what the cache did: hits and misses as JAX's own monitoring events
+    count them, and the seconds spent in backend compilation (cache loads
+    included), ``tracing.compile_totals``. Compile time is set-up time,
+    never a speed number.
 
     Touches ``jax.config`` only — no backend is initialised here, so a
     supervising parent may call it and still leave the chip to its child.
@@ -45,6 +49,7 @@ def enable_compile_cache(log_fn=print):
     otherwise stack one exit report per call).
     """
     global _ENABLED_AT
+    tracing.listen()
     if not jax.config.jax_enable_compilation_cache:
         return None
     if not os.environ.get(CACHE_DIR_ENV):
@@ -55,24 +60,10 @@ def enable_compile_cache(log_fn=print):
     _ENABLED_AT = path
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-
-    seen = {"hits": 0, "misses": 0, "compile_s": 0.0}
-
-    def _on_event(event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            seen["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            seen["misses"] += 1
-
-    def _on_duration(event, duration_secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            seen["compile_s"] += duration_secs
-
-    jax.monitoring.register_event_listener(_on_event)
-    jax.monitoring.register_event_duration_secs_listener(_on_duration)
     log_fn(f"XLA compilation cache: {path}")
 
     def _report():
+        seen = tracing.compile_totals()
         log_fn(
             f"XLA compilation cache: {seen['hits']} hits, "
             f"{seen['misses']} misses, {seen['compile_s']:.1f} s compiling "

@@ -15,6 +15,12 @@ program, so wall-clock phase spans are replaced by:
                             (block / step > feed_take, dispatch, feed_start
                             > stack, put, next_batch, fetch, boundary) is
                             the constants below.
+  * ``listen()``          — set-up on the same ring: JAX's own
+                            ``jax.monitoring`` reports of each trace,
+                            lowering and backend compile, and each program
+                            the persistent cache did not hold, become
+                            records beside the spans (``compile_records()``
+                            names the function of each).
   * ``named_phase(name)`` — jax.named_scope: the device-side half, labels
                             the ops traced under it inside the compiled
                             step.
@@ -35,6 +41,7 @@ import collections
 import contextlib
 import json
 import os
+import threading
 import time
 from typing import Iterator, Optional
 
@@ -68,14 +75,35 @@ BOUNDARY = "boundary"  # everything after the fetch: recorder, doctor, log, eval
 # the step they report, NEXT_BATCH and DISPATCH the step they launch, which is
 # the one after unless the iteration drained
 PARENT_SPANS = (BLOCK, STEP)
+# Set-up. A span around building a loop's initial state, and JAX's compile
+# phases as records with parent None (never an iteration's child): `step` is
+# that of the innermost span open when the phase ended, None before the loop
+INIT_STATE = "init_state"  # training.create_state: the initial state, its eager compiles included
+JAX_TRACE = "jax_trace"  # a function traced to a jaxpr (nested jits nest)
+JAX_LOWER = "jax_lower"  # a jaxpr lowered to an MLIR module
+JAX_COMPILE = "jax_compile"  # a backend compile, or a load from the persistent cache
+JAX_CACHE_MISS = "jax_cache_miss"  # zero length: a program the persistent cache did not hold
+SETUP_RECORDS = (INIT_STATE, JAX_TRACE, JAX_LOWER, JAX_COMPILE, JAX_CACHE_MISS)
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": JAX_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": JAX_LOWER,
+    "/jax/core/compile/backend_compile_duration": JAX_COMPILE,
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
 
 # (name, step, parent, t0, t1) on time.perf_counter; str/int/float only, so
 # the garbage collector untracks each record on its first pass and the ring
-# adds nothing to walk. A few thousand iterations of at most 8 spans.
+# adds nothing to walk. A few thousand iterations of at most 8 spans, and
+# set-up's few thousand compile phases.
 RING_RECORDS = 32768
 _ring: collections.deque = collections.deque(maxlen=RING_RECORDS)
+_fun_names: collections.deque = collections.deque(maxlen=RING_RECORDS)  # (t1, fun_name) of JAX's records
 _open: list = []  # (name, step) of the spans now open; the loops run on one host thread
 _profiler = None  # jax.profiler once a span has looked for it; False where there is none
+_listening = False
+_totals = {"hits": 0, "misses": 0, "compile_s": 0.0}  # the process's, whatever cleared the ring
+_totals_lock = threading.Lock()  # JAX may compile on several threads at once
 
 
 def _annotation(name: str, step):
@@ -141,8 +169,86 @@ def spans() -> list[tuple]:
 
 
 def clear() -> None:
-    """Empty the ring (a loop's start, tests)."""
+    """Empty the ring (``cli.main``'s entry, tests)."""
     _ring.clear()
+    _fun_names.clear()
+
+
+def clear_iterations() -> None:
+    """Drop every record but set-up's (:data:`SETUP_RECORDS`): a loop's
+    start, so that the ring holds this loop's iterations after the set-up
+    that led to them."""
+    kept = [rec for rec in _ring if rec[0] in SETUP_RECORDS]
+    _ring.clear()
+    _ring.extend(kept)
+
+
+def _innermost_step():
+    try:  # a compile on another thread may meet a span closing
+        return _open[-1][1]
+    except IndexError:
+        return None
+
+
+def _on_time_span(event, start_time, end_time, fun_name="", **_):
+    """JAX calls this as a phase exits, with its bounds on time.time():
+    the record keeps the length and takes its end from the ring's clock."""
+    name = _JAX_PHASES.get(event)
+    if name is None:
+        return
+    t1 = time.perf_counter()
+    if name == JAX_COMPILE:
+        with _totals_lock:
+            _totals["compile_s"] += end_time - start_time
+    _ring.append((name, _innermost_step(), None, t1 - (end_time - start_time), t1))
+    _fun_names.append((t1, fun_name))
+
+
+def _on_event(event, **_):
+    if event == _CACHE_MISS:  # reported inside the compile whose program it wrote
+        t = time.perf_counter()
+        _ring.append((JAX_CACHE_MISS, _innermost_step(), None, t, t))
+    elif event != _CACHE_HIT:
+        return
+    with _totals_lock:
+        _totals["misses" if event == _CACHE_MISS else "hits"] += 1
+
+
+def listen() -> None:
+    """Register the process's one pair of ``jax.monitoring`` listeners:
+    each trace, lowering and backend compile JAX reports becomes a ring
+    record (:data:`JAX_TRACE`, :data:`JAX_LOWER`, :data:`JAX_COMPILE`),
+    each persistent-cache miss a zero-length :data:`JAX_CACHE_MISS`, and
+    :func:`compile_totals` counts. Idempotent. They run only when JAX
+    compiles something, never on a call that finds its program."""
+    global _listening
+    if _listening:
+        return
+    import jax.monitoring
+
+    jax.monitoring.register_event_time_span_listener(_on_time_span)
+    jax.monitoring.register_event_listener(_on_event)
+    _listening = True
+
+
+def compile_records() -> list[tuple]:
+    """JAX's records in the ring, oldest first, with the function JAX named
+    for each: ``(name, step, t0, t1, fun_name)``. A miss has no name of its
+    own (None): it lies inside the ``jax_compile`` record of its program."""
+    names = dict(_fun_names)
+    return [
+        (name, step, t0, t1, names.get(t1) if name != JAX_CACHE_MISS else None)
+        for name, step, _, t0, t1 in spans()
+        if name in SETUP_RECORDS and name != INIT_STATE
+    ]
+
+
+def compile_totals() -> dict:
+    """Since :func:`listen`: persistent-cache ``hits`` and ``misses``, and
+    ``compile_s``, the seconds of backend compiles (cache loads
+    included)."""
+    with _totals_lock:
+        return dict(_totals)
 
 
 @contextlib.contextmanager

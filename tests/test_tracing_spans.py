@@ -1,9 +1,14 @@
 """The host-span primitive (utils.tracing.span), the vocabulary each loop
-emits, the device scopes planted in the step programs, and --profile-dir on
-the one-device loops. Tiny CPU runs; no time is asserted."""
+emits, set-up in the same ring (JAX's compile phases, init_state), the
+device scopes planted in the step programs, and --profile-dir on the
+one-device loops. Tiny CPU runs; no time is asserted."""
 
 import collections
 import os
+import subprocess
+import sys
+import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -17,13 +22,23 @@ from atomo_tpu.utils.tracing import (
     FEED_START,
     FEED_TAKE,
     FETCH,
+    INIT_STATE,
+    JAX_CACHE_MISS,
+    JAX_COMPILE,
+    JAX_LOWER,
+    JAX_TRACE,
     NEXT_BATCH,
     PUT,
+    SETUP_RECORDS,
     STACK,
     STEP,
     span,
     spans,
 )
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))  # benchmarks.trace, the reducer's union
 
 TRAIN = [
     "train", "--network", "LeNet", "--dataset", "MNIST", "--synthetic",
@@ -200,6 +215,141 @@ def test_lm_loop_emits_step_spans():
     parents = {r[1]: r for r in spans() if r[0] == STEP}
     for i in (2, 3, 4):  # the launch of step i+1 lies in the iteration that reports step i
         assert parents[i][3] <= launched[i + 1] < parents[i][4], i
+
+
+# ------------------------------------------------------------------ set-up
+
+
+JAX_PHASES = (JAX_TRACE, JAX_LOWER, JAX_COMPILE)
+
+
+def test_a_jitted_functions_first_call_leaves_one_record_of_each_phase():
+    """Inside the `dispatch` that made them, on perf_counter, with parent
+    None (never an iteration's child) and the step of the innermost span;
+    the second call finds its program and leaves nothing."""
+    def first_call(x):
+        return jax.lax.sin(x)
+
+    tracing.listen()
+    x = jnp.ones(16)
+    step_fn = jax.jit(first_call)
+    tracing.clear()
+    before = time.perf_counter()
+    with span(STEP, 7):
+        with span(DISPATCH):
+            step_fn(x).block_until_ready()
+    after = time.perf_counter()
+    recs = spans()
+    mine = [r for r in tracing.compile_records() if r[4] in ("first_call", "jit(first_call)")]
+    assert [(r[0], r[4]) for r in mine] == [
+        (JAX_TRACE, "first_call"), (JAX_LOWER, "jit(first_call)"), (JAX_COMPILE, "jit(first_call)"),
+    ]
+    dispatch = next(r for r in recs if r[0] == DISPATCH)
+    for rec in recs:
+        if rec[0] in JAX_PHASES:
+            assert rec[1] == 7 and rec[2] is None, rec
+            assert before <= dispatch[3] <= rec[3] <= rec[4] <= dispatch[4] <= after, rec
+    assert [r[:3] for r in recs[-2:]] == [(DISPATCH, 7, STEP), (STEP, 7, None)]
+    tracing.clear()
+    step_fn(x).block_until_ready()
+    assert spans() == []
+
+
+def test_a_nested_jit_nests_its_records_and_the_reducer_counts_them_once():
+    from benchmarks.trace import union_len
+
+    inner = jax.jit(lambda x: jax.lax.cos(x))
+
+    def outer_fn(x):
+        return inner(x) + jax.lax.sin(x)
+
+    tracing.listen()
+    x = jnp.ones(16)
+    outer = jax.jit(outer_fn)
+    tracing.clear()
+    outer(x).block_until_ready()
+    traces = {r[4]: r for r in tracing.compile_records() if r[0] == JAX_TRACE}
+    outer_rec, inner_rec = traces["outer_fn"], traces["<lambda>"]
+    assert outer_rec[2] <= inner_rec[2] <= inner_rec[3] <= outer_rec[3]
+    intervals = [(r[2], r[3]) for r in tracing.compile_records() if r[0] == JAX_TRACE]
+    assert union_len(intervals) == pytest.approx(outer_rec[3] - outer_rec[2], rel=1e-9)
+    assert sum(t1 - t0 for t0, t1 in intervals) > union_len(intervals)
+
+
+def test_create_state_leaves_an_init_state_span_around_its_compiles():
+    from atomo_tpu.models import get_model
+    from atomo_tpu.training import create_state, make_optimizer
+
+    tracing.listen()
+    rng, sample = jax.random.PRNGKey(0), jnp.zeros((1, 28, 28, 1))
+    tracing.clear()
+    create_state(get_model("LeNet", 10), make_optimizer("sgd", lr=0.01, momentum=0.0), rng, sample)
+    recs = spans()
+    (init,) = [r for r in recs if r[0] == INIT_STATE]
+    assert init[1:3] == (None, None)  # set-up: no step, no parent
+    assert [r for r in recs if r[0] in JAX_PHASES]
+    for rec in recs:
+        assert rec[0] in SETUP_RECORDS and init[3] <= rec[3] <= rec[4] <= init[4], rec
+
+
+@pytest.mark.parametrize("argv,parent", [
+    (LM + ["--max-steps", "3", "--log-interval", "1"], STEP),
+    (TRAIN + ["--superstep", "2", "--max-steps", "4", "--log-interval", "1"], BLOCK),
+])
+def test_after_cli_main_the_ring_holds_its_set_up_and_its_iterations_and_nothing_earlier(argv, parent):
+    from atomo_tpu.cli import main
+
+    assert main(LM + ["--max-steps", "2", "--log-interval", "1"]) == 0
+    started = time.perf_counter()
+    assert main(argv) == 0
+    recs = spans()
+    assert min(r[3] for r in recs) >= started  # nothing of the earlier call
+    names = collections.Counter(r[0] for r in recs)
+    assert names[INIT_STATE] == 1 and all(names[n] for n in JAX_PHASES)
+    (init,) = [r for r in recs if r[0] == INIT_STATE]
+    iterations = [r for r in recs if r[0] == parent and r[2] is None]
+    assert len(iterations) == 2 + (parent == STEP) and init[4] <= min(r[3] for r in iterations)
+    assert all(r[2] is None for r in recs if r[0] in SETUP_RECORDS)  # no iteration's child
+    assert len(recs) < tracing.RING_RECORDS  # nothing of set-up fell out
+
+
+def test_a_loop_entered_without_cli_main_keeps_set_up_and_drops_earlier_iterations():
+    from atomo_tpu.cli import main
+
+    assert main(LM + ["--max-steps", "2", "--log-interval", "1"]) == 0
+    ran = spans()
+    with span(STEP, 99):  # an earlier loop's iteration, left in the ring
+        pass
+    tracing.clear_iterations()
+    assert spans() == [r for r in ran if r[0] in SETUP_RECORDS] and spans()
+
+
+def _misses_in_a_fresh_process(cache_dir):
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from atomo_tpu.utils import tracing\n"
+        "from atomo_tpu.utils.compile_cache import enable_compile_cache\n"
+        "enable_compile_cache(log_fn=lambda m: None)\n"
+        "jax.jit(lambda a: jnp.cos(a) * 3)(jnp.arange(32.0)).block_until_ready()\n"
+        "names = [r[0] for r in tracing.spans()]\n"
+        "print(names.count('jax_cache_miss'), names.count('jax_compile'),"
+        " tracing.compile_totals()['misses'])\n"
+    )
+    env = {**os.environ, "JAX_COMPILATION_CACHE_DIR": str(cache_dir),
+           "JAX_ENABLE_COMPILATION_CACHE": "true", "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(ROOT)}
+    done = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return [int(n) for n in done.stdout.split()[-3:]]
+
+
+def test_a_cold_process_records_its_cache_misses_and_a_warm_one_none(tmp_path):
+    """In subprocesses: within one process JAX's in-memory cache would
+    hide the second compile from the persistent one."""
+    misses, compiles, counted = _misses_in_a_fresh_process(tmp_path / "cache")
+    assert misses >= 1 and compiles >= misses and counted == misses
+    misses, compiles, counted = _misses_in_a_fresh_process(tmp_path / "cache")
+    assert misses == 0 and counted == 0 and compiles >= 1  # every program loaded
 
 
 # ------------------------------------------------------------ device scopes
